@@ -61,13 +61,12 @@ class NodeConfig:
     # --- Service tunables (inherited by workers) ---
     # One-burst-in-flight serving overlap. None = "auto": each
     # inference worker measures its device->host sync latency at
-    # startup and pipelines only when there is latency worth hiding
-    # (a tunneled chip's 100ms+ flush window) — on a directly attached
-    # chip the handoff would COST a few percent for nothing to hide.
+    # startup and pipelines only when it exceeds pipeline_sync_min —
+    # below that the handoff would COST a few percent for nothing to
+    # hide.
     serving_pipeline: Optional[bool] = None
     checkpoint_trials: bool = False        # mid-trial epoch snapshots
     trace_dir: str = ""                    # per-trial profiler traces
-    probe_timeout: float = 60.0            # accelerator liveness probe
 
     # --- Serving frontend: continuous cross-request micro-batching ---
     # The predictor coalesces every /predict arriving within one fill
@@ -229,10 +228,9 @@ class NodeConfig:
 
     # InferenceWorker serving-pipeline auto-probe threshold, seconds:
     # with serving_pipeline=auto the worker pipelines only when the
-    # measured device->host sync latency exceeds this (tunneled chips
-    # ~0.1-0.7s win; directly attached ~1ms lose). Promoted from an
-    # env-only expert knob (r15): the tunneled-vs-direct mix is a
-    # per-deployment fact, not an incident override.
+    # measured device->host sync latency exceeds this. Promoted from an
+    # env-only expert knob (r15): the sync latency is a per-deployment
+    # fact, not an incident override.
     pipeline_sync_min: float = 0.02
 
     # --- Trial lifecycle / dataset residency (docs/training.md) ---
@@ -360,7 +358,6 @@ class NodeConfig:
         "serving_pipeline": "RAFIKI_TPU_SERVING_PIPELINE",
         "checkpoint_trials": "RAFIKI_TPU_CKPT",
         "trace_dir": "RAFIKI_TPU_TRACE_DIR",
-        "probe_timeout": "RAFIKI_TPU_PROBE_TIMEOUT",
     }
     _types_cache = None  # deliberately un-annotated: not fields
     _tristate_cache = None
@@ -451,8 +448,6 @@ class NodeConfig:
             raise ValueError("n_chips must be positive (or unset)")
         if self.supervise_interval < 0:
             raise ValueError("supervise_interval must be >= 0")
-        if self.probe_timeout <= 0:
-            raise ValueError("probe_timeout must be positive")
         if self.serving_fill_window < 0:
             raise ValueError("serving_fill_window must be >= 0")
         if self.serving_max_batch < 1 or self.serving_max_inflight < 1 \
@@ -636,7 +631,6 @@ class NodeConfig:
             os.environ.pop(self.env_name("checkpoint_trials"), None)
         if self.trace_dir:
             os.environ[self.env_name("trace_dir")] = self.trace_dir
-        os.environ[self.env_name("probe_timeout")] = str(self.probe_timeout)
         # Micro-batcher knobs: the PredictorService reads these at
         # construction (it may be built in a spawned child or an
         # in-process thread — env is the one transport both inherit).

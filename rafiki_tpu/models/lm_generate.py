@@ -188,13 +188,13 @@ class LMGenerator:
             lambda ids: jax.device_put(ids, self._rep))
         self._params = jax.device_put(model._params, self._rep)
         s = self._dims
-        slab = n_pages * page_size
-        self._k_pool = jax.device_put(
-            jnp.zeros((s["layers"], slab, s["d"]), jnp.bfloat16),
-            self._rep)
-        self._v_pool = jax.device_put(
-            jnp.zeros((s["layers"], slab, s["d"]), jnp.bfloat16),
-            self._rep)
+        slab = (s["layers"], n_pages * page_size, s["d"])
+        # Born on the mesh (a jitted fill with out_shardings): an eager
+        # jnp.zeros is made on the default device and copied over.
+        self._k_pool, self._v_pool = jax.jit(
+            lambda: (jnp.zeros(slab, jnp.bfloat16),
+                     jnp.zeros(slab, jnp.bfloat16)),
+            out_shardings=self._rep)()
         self._seqs: Dict[Any, _Seq] = {}
         self._lanes: List[Optional[Any]] = [None] * decode_batch
         self._order = 0
@@ -390,10 +390,10 @@ class LMGenerator:
             pos[i] = pages[i // self.page_size] * self.page_size \
                 + i % self.page_size
         fn = self._prefill_fn(bucket)
+        # Host arrays go straight to the params' devices with the call.
         logits, self._k_pool, self._v_pool = fn(
-            self._params, self._k_pool, self._v_pool,
-            jnp.asarray(ids), jnp.asarray(pos),
-            jnp.int32(n - 1))
+            self._params, self._k_pool, self._v_pool, ids, pos,
+            np.int32(n - 1))
         self.prefills_total += 1
         return pages, np.asarray(logits)
 
@@ -427,8 +427,7 @@ class LMGenerator:
         for p in pages[n_full:]:  # at most one partial tail page
             dst = self._alloc_page()
             self._k_pool, self._v_pool = self._copy_page_fn()(
-                self._k_pool, self._v_pool, jnp.int32(p),
-                jnp.int32(dst))
+                self._k_pool, self._v_pool, np.int32(p), np.int32(dst))
             out.append(dst)
         return out, logits
 

@@ -309,7 +309,7 @@ class JaxTransformerTagger(BaseModel):
             mode = str(self.knobs.get("sp_schedule", "ring"))
             return lambda q, k, v, kv_mask: sequence_sharded_attention(
                 q, k, v, mesh, causal=False, kv_mask=kv_mask, mode=mode)
-        return default_attention(causal=False)
+        return default_attention(mesh, causal=False)
 
     # --- pipeline-parallel layout -------------------------------------
     #
@@ -370,9 +370,9 @@ class JaxTransformerTagger(BaseModel):
         where ``aux`` is the mean MoE load-balance loss (0.0 for dense
         models).
         """
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
-        from ..jaxcompat import shard_map
         from ..ops import pipeline_apply, ring_attention, ulysses_attention
         from ..parallel import EP_AXIS, PP_AXIS
 
@@ -405,7 +405,9 @@ class JaxTransformerTagger(BaseModel):
             attn = (lambda q, k, v, kv_mask: inner(
                 q, k, v, causal=False, axis_size=sp, kv_mask=kv_mask))
         else:
-            attn = self._attn_fn()
+            # Already inside the pp shard_map (manual over the whole
+            # mesh): the bare per-device attention, no second wrapper.
+            attn = default_attention(causal=False)
 
         act_spec = P(DP_AXIS, SP_AXIS) if sp > 1 else P(DP_AXIS)
 

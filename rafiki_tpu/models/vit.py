@@ -42,10 +42,11 @@ class _ViT(nn.Module):
     n_tokens: int  # 1 + (H/patch)·(W/patch), fixed per dataset
     max_depth: int = MAX_DEPTH
     dtype: Any = jnp.bfloat16
+    mesh: Any = None  # the model's mesh: the TPU kernel shard_maps over it
 
     @nn.compact
     def __call__(self, x, train: bool = False, depth=None):
-        attn = default_attention(causal=False)
+        attn = default_attention(self.mesh, causal=False)
 
         x = nn.Conv(self.d_model, (self.patch, self.patch),
                     strides=(self.patch, self.patch),
@@ -104,7 +105,8 @@ class JaxViT(JaxModel):
                     d_model=int(self.knobs.get("d_model", 128)),
                     n_heads=int(self.knobs.get("n_heads", 4)),
                     patch=patch,
-                    n_tokens=1 + (h // patch) * (w // patch))
+                    n_tokens=1 + (h // patch) * (w // patch),
+                    mesh=self.mesh)
 
     def create_optimizer(self, steps_per_epoch: int, max_epochs: int):
         return self.traced_hyperparam_optimizer(
@@ -155,7 +157,7 @@ class JaxViT(JaxModel):
         cls = fvars["params/cls"].astype(jnp.float32)
         h = jnp.concatenate([jnp.tile(cls, (bsz, 1, 1)), h], axis=1)
         h = h + fvars["params/pos_embed"].astype(jnp.float32)
-        attn = default_attention(causal=False)
+        attn = default_attention(self.mesh, causal=False)
         depth = extra["depth"]
         for i in range(module.max_depth):
             y = quantized_encoder_block(

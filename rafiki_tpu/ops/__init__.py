@@ -8,7 +8,8 @@ parallelism over the ``sp`` mesh axis (SURVEY.md §5 — absent upstream,
 first-class here).
 """
 
-from .attention import (blockwise_attention, default_attention,
+from .attention import (batch_sharded_flash_attention,
+                        blockwise_attention, default_attention,
                         flash_attention,
                         naive_attention, ring_attention,
                         sequence_sharded_attention, ulysses_attention)
@@ -16,7 +17,8 @@ from .moe import switch_moe
 from .pipeline import pipeline_apply, pipelined
 
 __all__ = [
-    "blockwise_attention", "default_attention", "flash_attention",
+    "batch_sharded_flash_attention", "blockwise_attention",
+    "default_attention", "flash_attention",
     "naive_attention",
     "pipeline_apply", "pipelined", "ring_attention",
     "sequence_sharded_attention", "switch_moe", "ulysses_attention",

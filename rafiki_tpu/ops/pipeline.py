@@ -115,9 +115,8 @@ def pipelined(stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
     does this for names containing ``stage``). The batch's leading axis
     must divide into ``n_microbatches``.
     """
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from ..jaxcompat import shard_map
 
     s = mesh.shape[PP_AXIS]
 

@@ -1,0 +1,201 @@
+"""The main path's kernels and programs compile for a real v5e.
+
+The sandbox has no chip, but the TPU compiler is installed and compiles
+for a chip that is *described*, not attached. Interpret mode cannot see
+what these catch: BlockSpec tiling violations, VMEM overflows, programs
+that do not fit 16 GB of HBM, and Mosaic kernels called bare under a
+multi-device jit (never auto-partitioned — the dp-sharded cases fail
+with ``NotImplementedError`` without the ``shard_map`` wrapper). Nothing
+runs: a compile that passes is not a chip run (``chip_smoke.py`` is).
+
+This is the ONLY file that describes the chip. The topology, and every
+sharding and mesh built from it, is made inside module-scoped fixtures:
+only one process may load libtpu, so nothing here may touch it at import
+or collection time, and every compile happens in this test's process.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from rafiki_tpu.models import JaxTransformerLM
+from rafiki_tpu.models.lm import _jitted_param_init
+from rafiki_tpu.models.lm_generate import _build_decode, _build_prefill
+from rafiki_tpu.ops import batch_sharded_flash_attention, flash_attention
+from rafiki_tpu.parallel import build_mesh, replicated
+
+HBM_BYTES = 16e9  # one v5e chip
+
+#: The repo's flagship LM width (bench.py roofline config), depth 8.
+FLAGSHIP = {"d_model": 2048, "n_layers": 8, "seq_len": 2048,
+            "vocab_size": 32768}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep it off here.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def dp_mesh(topo):
+    return build_mesh(topo.devices)  # (dp=4, pp, ep, sp, tp = 1)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        "the Mosaic kernel is not in the compiled program"
+    return compiled
+
+
+def _sum_grad(fn):
+    """d(sum of fn)/d(q, k, v) — the backward kernels."""
+    return jax.grad(
+        lambda q, k, v, *rest: fn(q, k, v, *rest).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))
+
+
+# (shape, dtype, flash_attention kwargs, with kv_mask)
+_KERNEL_SHAPES = {
+    # flagship LM step: B4·H16·T2048·D128 bf16 causal
+    "flagship": ((4, 16, 2048, 128), jnp.bfloat16, {"causal": True},
+                 False),
+    # bench.py attention config: B2·H8·T8192·D128
+    "bench_t8192": ((2, 8, 8192, 128), jnp.bfloat16, {"causal": True},
+                    False),
+    # ViT-shaped: 197 tokens (not a block multiple), key-padding mask
+    "masked_197x64": ((8, 4, 197, 64), jnp.bfloat16, {}, True),
+    # Small explicit blocks with nq > 1: block_q is the backward
+    # kernels' LANE dim and must round up to 128 (_flash_blocking) —
+    # the regression the interpreter cannot catch.
+    "small_blocks": ((1, 1, 256, 64), jnp.float32,
+                     {"causal": True, "block_q": 32, "block_kv": 64},
+                     False),
+}
+
+
+@pytest.mark.parametrize("case", [
+    "flagship-fwd", "flagship-grad",
+    "bench_t8192-fwd",  # the bench config times the forward only
+    "masked_197x64-fwd", "masked_197x64-grad",
+    "small_blocks-fwd", "small_blocks-grad"])
+def test_flash_kernel_compiles_on_one_chip(one_chip, case):
+    shape_name, pass_ = case.split("-")
+    grad = pass_ == "grad"
+    shape, dtype, kwargs, masked = _KERNEL_SHAPES[shape_name]
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = [x, x, x]
+    if masked:
+        args.append(jax.ShapeDtypeStruct(
+            (shape[0], shape[2]), jnp.bool_, sharding=one_chip))
+
+    def attend(q, k, v, mask=None):
+        return flash_attention(q, k, v, kv_mask=mask, interpret=False,
+                               **kwargs)
+
+    _compile(_sum_grad(attend) if grad else attend, *args)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_flash_kernel_compiles_batch_sharded_over_dp4(dp_mesh, grad):
+    """The four-chip path: batch over a 4-device dp mesh. Bare, the
+    lowering refuses (Mosaic kernels cannot be partitioned); inside
+    the shard_map wrapper it compiles with one kernel per device."""
+    shape, dtype, kwargs, _ = _KERNEL_SHAPES["flagship"]
+    x = jax.ShapeDtypeStruct(
+        shape, dtype, sharding=NamedSharding(dp_mesh, P("dp")))
+
+    def attend(q, k, v):
+        return batch_sharded_flash_attention(q, k, v, dp_mesh,
+                                             interpret=False, **kwargs)
+
+    _compile(_sum_grad(attend) if grad else attend, x, x, x)
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _compile(lambda q, k, v: flash_attention(
+            q, k, v, interpret=False, **kwargs), x, x, x)
+
+
+@pytest.fixture(scope="module")
+def flagship_lm(topo):
+    """(model on a one-chip mesh of the described device, abstract
+    params placed there). The model resolves its mesh from
+    ``jax.devices()`` — the CPU here — so the test hands it the
+    described chip instead."""
+    model = JaxTransformerLM(**FLAGSHIP)
+    mesh = build_mesh(topo.devices[:1])
+    model._mesh = mesh
+    s = model._dims()
+    init = _jitted_param_init(s["v"], s["d"], s["layers"], mesh)
+    params = jax.eval_shape(init, jax.ShapeDtypeStruct((), jnp.int32))
+    rep = replicated(mesh)
+    return model, jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        params)
+
+
+def _hbm_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_generate_programs_compile_and_fit_one_chip(flagship_lm,
+                                                    monkeypatch, program):
+    """The generative engine's decode step and one prefill bucket at
+    flagship width, lowered from shapes: weights (1.9 GB f32) + both
+    K/V pools are arguments of the program, so its footprint is what
+    has to fit the chip's HBM."""
+    model, params = flagship_lm
+    rep = replicated(model.mesh)
+    s = model._dims()
+    page_size, n_pages, batch, max_new = 16, 640, 8, 128
+    pages_per_seq = -(-(s["t"] + max_new) // page_size)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    pool = sds((s["layers"], n_pages * page_size, s["d"]), jnp.bfloat16)
+    if program == "decode":
+        fn = _build_decode(s, page_size, pages_per_seq, batch)
+        compiled = fn.lower(
+            params, pool, pool, sds((batch,), jnp.int32),
+            sds((batch, pages_per_seq), jnp.int32),
+            sds((batch,), jnp.int32), sds((batch,), jnp.float32),
+            sds((batch,), jnp.int32)).compile()
+    else:
+        # The model picks interpret mode from the host's backend (the
+        # CPU here); steer the trace to the real kernel.
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        bucket = 256
+        fn = _build_prefill(s, bucket, model._block)
+        compiled = fn.lower(
+            params, pool, pool, sds((1, bucket), jnp.int32),
+            sds((bucket,), jnp.int32), sds((), jnp.int32)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+    assert _hbm_bytes(compiled) < HBM_BYTES, compiled.memory_analysis()

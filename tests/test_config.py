@@ -23,7 +23,6 @@ def test_env_parsing_and_types():
         "RAFIKI_TPU_SERVING_PIPELINE": "0",
         "RAFIKI_TPU_CKPT": "1",
         "RAFIKI_TPU_TRACE_DIR": "/tmp/traces",
-        "RAFIKI_TPU_PROBE_TIMEOUT": "15",
     })
     assert cfg.port == 8080 and cfg.n_chips == 4
     assert cfg.bus_uri == "tcp://10.0.0.1:6380"
@@ -31,7 +30,6 @@ def test_env_parsing_and_types():
     assert cfg.serving_pipeline is False
     assert cfg.checkpoint_trials is True
     assert cfg.trace_dir == "/tmp/traces"
-    assert cfg.probe_timeout == 15.0
 
 
 def test_cli_overrides_beat_env():

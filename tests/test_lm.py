@@ -6,6 +6,7 @@ citizen for the ≥90%-utilization north star. Tests run tiny shapes on
 the CPU mesh (the Pallas kernels run in interpreter mode there).
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -95,3 +96,62 @@ def test_lm_quick_train_cap(token_data):
     assert steps and max(steps) == 16, steps  # capped, not 5000
     assert m.dump_parameters()
     m.destroy()
+
+
+def _train_on_group(indices, train_path, val_path, knobs):
+    """One trial's life with its chip group bound to this thread;
+    returns (devices of the freshly initialised params, logged losses)."""
+    from rafiki_tpu.parallel import ChipGroup
+
+    ChipGroup(indices=indices).bind_to_thread()
+    records = []
+    prev = logger.current_sink()
+    logger.set_sink(records.append)
+    try:
+        # trial_steps / steps_per_dispatch are FixedKnobs (30 / 8); two
+        # dispatches of 4 steps keep the interpreted kernel inside the
+        # tier-1 budget.
+        m = JaxTransformerLM(**dict(
+            JaxTransformerLM.validate_knobs(knobs), trial_steps=8,
+            steps_per_dispatch=4))
+        born_on = {d.id for leaf in jax.tree.leaves(m._init_params())
+                   for d in leaf.devices()}
+        # Nothing may be staged through a chip outside the group (the
+        # first four-chip run found Adam's state born on device 0): any
+        # device-to-device copy in the trial's life — train, evaluate,
+        # the served copy's load and predict — raises.
+        with jax.transfer_guard_device_to_device("disallow_explicit"):
+            m.train(train_path)
+            acc = m.evaluate(val_path)
+            served = JaxTransformerLM(**m.knobs)
+            served.load_parameters(m.dump_parameters())
+            assert abs(served.evaluate(val_path) - acc) < 1e-6
+            score, = served.predict([list(range(1, 40))])
+            assert np.isfinite(score) and score < 0.0
+        for model in (m, served):
+            on = {d.id for leaf in jax.tree.leaves(model._params)
+                  for d in leaf.devices()}
+            assert on == set(indices), on
+            model.destroy()
+    finally:
+        logger.set_sink(prev)
+        ChipGroup.unbind_thread()
+    losses = [r["values"]["loss"] for r in records
+              if r.get("type") == "values" and "loss" in r["values"]]
+    return born_on, losses
+
+
+def test_lm_dp4_group_matches_dp1_and_stays_on_its_chips(token_data):
+    """The flash kernel runs inside shard_map over the group's dp axis
+    (Mosaic kernels are never auto-partitioned; interpret mode here),
+    and the parameters are born on the group's own devices: a dp=4
+    group (chips 4-7) and a one-chip group (chip 2) train the same
+    global batch to the same loss, and neither touches device 0."""
+    train_path, val_path = token_data
+    knobs = dict(TINY, quick_train=True)
+    born4, loss4 = _train_on_group((4, 5, 6, 7), train_path, val_path,
+                                   knobs)
+    born1, loss1 = _train_on_group((2,), train_path, val_path, knobs)
+    assert born4 == {4, 5, 6, 7} and born1 == {2}
+    assert len(loss4) == len(loss1) == 2
+    np.testing.assert_allclose(loss4, loss1, rtol=5e-3)

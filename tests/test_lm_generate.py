@@ -278,3 +278,34 @@ def test_generator_close_returns_all_pages(lm, gen):
     g.close()
     assert g.pool.used_pages == 0
     m.destroy()
+
+
+def test_engine_lives_on_its_own_chip():
+    """A replica's engine on chip 3 touches no other chip: pools born on
+    the model's mesh, prompt ids / page indices shipped from the host —
+    any device-to-device copy (staging through the default device)
+    raises."""
+    import jax
+
+    from rafiki_tpu.parallel import ChipGroup
+
+    ChipGroup(indices=(3,)).bind_to_thread()
+    try:
+        with jax.transfer_guard_device_to_device("disallow_explicit"):
+            model = JaxTransformerLM(
+                **JaxTransformerLM.validate_knobs(TINY))
+            model._params = model._init_params()
+            gen = model.make_generator(page_size=16, n_pages=32,
+                                       decode_batch=2, max_new_cap=8)
+            prompt = list(range(1, 20))  # partial tail page: copied
+            _, first = gen.admit(prompt, max_new=3)
+            gen.step()
+            _, again = gen.admit(prompt, max_new=3)  # prefix-cache hit
+            gen.step()
+        assert first == again and gen.prefill_skipped_total == 1
+        pools = (gen._k_pool, gen._v_pool, *jax.tree.leaves(gen._params))
+        assert {d.id for a in pools for d in a.devices()} == {3}
+        gen.close()
+        model.destroy()
+    finally:
+        ChipGroup.unbind_thread()

@@ -52,7 +52,6 @@ def test_one_job_split_across_two_nodes(tmp_path, synth_image_data,
         env = dict(os.environ)
         env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-        env.pop("RAFIKI_TPU_PLATFORM", None)
         proc = subprocess.Popen(
             [sys.executable, "-m", "rafiki_tpu", "join",
              "--workdir", shared, "--bus", broker.uri,
