@@ -475,9 +475,8 @@ class JaxModel(BaseModel):
 
     def train(self, dataset_path: str, *,
               shared_params: Optional[Params] = None, **kwargs: Any) -> None:
-        t_load = time.monotonic()
-        ds = load_image_dataset(dataset_path)
-        _phases.observe_phase("load", time.monotonic() - t_load)
+        with _phases.span("load"):
+            ds = load_image_dataset(dataset_path)
         self._ensure_module(ds.n_classes, ds.image_shape)
         mesh = self.mesh
         dp = mesh.shape["dp"]
@@ -639,11 +638,9 @@ class JaxModel(BaseModel):
                                          2 << 30))
         staged = ds.images.nbytes <= stage_bytes
         if staged:
-            t_stage = time.monotonic()
-            data_dev, labels_dev = staged_dataset_arrays(
-                dataset_path, ds, mesh)
-            _phases.observe_phase("stage",
-                                  time.monotonic() - t_stage)
+            with _phases.span("stage"):
+                data_dev, labels_dev = staged_dataset_arrays(
+                    dataset_path, ds, mesh)
         chunk_steps = max(1, min(steps_per_epoch, 128))
 
         # AOT-compile per chunk length (at most two: full K + epoch tail),
@@ -889,9 +886,8 @@ class JaxModel(BaseModel):
 
     def evaluate(self, dataset_path: str) -> float:
         assert self._variables is not None, "train() or load_parameters() first"
-        t_load = time.monotonic()
-        ds = load_image_dataset(dataset_path)
-        _phases.observe_phase("load", time.monotonic() - t_load)
+        with _phases.span("load"):
+            ds = load_image_dataset(dataset_path)
         self._ensure_module(ds.n_classes, ds.image_shape)
         mesh = self.mesh
         if self._sharded_vars is None:
@@ -956,11 +952,9 @@ class JaxModel(BaseModel):
             _step_cache_put(cache_key, {"step": eval_step})
 
         if staged:
-            t_stage = time.monotonic()
-            data_dev, labels_dev = staged_dataset_arrays(
-                dataset_path, ds, mesh)
-            _phases.observe_phase("stage",
-                                  time.monotonic() - t_stage)
+            with _phases.span("stage"):
+                data_dev, labels_dev = staged_dataset_arrays(
+                    dataset_path, ds, mesh)
         rep = replicated(mesh)
         x_shard = batch_sharding(mesh)
         correct = 0.0

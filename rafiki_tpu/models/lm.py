@@ -60,6 +60,7 @@ from ..model.jax_model import (_stage_cache_budget, _step_cache_get,
 from ..model.logger import logger
 from ..model.loop_ckpt import epoch_rng
 from ..observe import MfuMeter
+from ..observe import phases as _phases
 from ..ops import batch_sharded_flash_attention
 from ..parallel import DP_AXIS, batch_sharding, build_mesh, replicated
 from ..parallel.chips import ChipGroup
@@ -257,7 +258,11 @@ class JaxTransformerLM(BaseModel):
 
     # --- BaseModel ---
 
-    def train(self, dataset_path: str, **kwargs: Any) -> None:
+    def _train_setup(self, dataset_path: str):
+        """``train`` up to its loop (the ``step_setup`` span): the
+        dataset, the step geometry, the initial state on the mesh and
+        the chunk, from the step cache or freshly wrapped (a fresh one
+        compiles in its first call, inside ``step_dispatch``)."""
         ds = load_token_dataset(dataset_path)
         s = self._dims()
         assert ds.vocab_size <= s["v"], (
@@ -344,7 +349,13 @@ class JaxTransformerLM(BaseModel):
 
             _step_cache_put(cache_key, {"tx": tx, "step": train_chunk,
                                         "init_opt": init_opt})
+        return ds, steps, b, k_disp, train_chunk, params, opt_state
 
+    def train(self, dataset_path: str, **kwargs: Any) -> None:
+        with _phases.span("step_setup"):
+            ds, steps, b, k_disp, train_chunk, params, opt_state = \
+                self._train_setup(dataset_path)
+        mesh, t = self.mesh, self._dims()["t"]
         logger.define_plot("Training", ["loss", "token_acc", "chip_util"],
                            x_axis="step")
         meter = MfuMeter(self._flops_per_step(b), n_devices=mesh.size)
@@ -354,18 +365,22 @@ class JaxTransformerLM(BaseModel):
         first_dispatch = True
         while done < steps:
             k = min(k_disp, steps - done)
-            starts = rng.integers(0, hi, size=k * b)
-            wins = np.stack([ds.ids[s:s + t + 1] for s in starts])
-            params, opt_state, metrics = train_chunk(
-                params, opt_state,
-                jax.device_put(wins.reshape(k, b, t + 1),
-                               replicated(mesh)))
+            with _phases.span("step_dispatch"):
+                starts = rng.integers(0, hi, size=k * b)
+                wins = np.stack([ds.ids[s:s + t + 1] for s in starts])
+                params, opt_state, metrics = train_chunk(
+                    params, opt_state,
+                    jax.device_put(wins.reshape(k, b, t + 1),
+                                   replicated(mesh)))
             done += k
-            loss_acc = np.asarray(metrics)  # one D2H per chunk; this
+            # The host blocked on the device; its count is the trial's
+            # progress in dispatches. One D2H per chunk; this
             # sync must land BEFORE any meter.reset(): the dispatch
             # returns while the chunk is still executing, and a reset
             # taken then would start the fresh window mid-chunk with
             # zero steps credited (~4% systematic under-report).
+            with _phases.span("step_wait"):
+                loss_acc = np.asarray(metrics)
             meter.tick(k)
             if first_dispatch or k != k_disp:
                 # Dispatches that paid an XLA compile (first chunk, tail

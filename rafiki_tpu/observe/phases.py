@@ -6,14 +6,16 @@ showed it almost entirely host-bound (``chip_util ~ 0`` for the trials
 config). These series make the breakdown measurable the same way the
 serving stage histogram did for the frontend:
 
-- ``rafiki_tpu_trial_phase_seconds{phase=}`` — wall time per phase.
-  ``propose``/``train``/``eval``/``persist`` are recorded by the
-  TrialRunner around the whole lifecycle step; ``load`` (dataset parse
-  from disk) and ``stage`` (full-dataset host->device transfer) are
-  SUB-SPANS recorded inside ``model.train()``/``model.evaluate()`` —
-  they are contained in the train/eval phases, not additive with them.
-  With the residency caches warm, load+stage collapse to ~0 for trial
-  2..N of a sub-train-job.
+- ``rafiki_tpu_trial_phase_seconds{phase=}`` — wall time per phase,
+  every one timed by ``span`` below (``PHASES`` lists them with their
+  nesting). The TrialRunner records the lifecycle steps of ``trial``;
+  ``load`` (dataset parse from disk) and ``stage`` (full-dataset
+  host->device transfer) are SUB-SPANS recorded inside
+  ``model.train()``/``model.evaluate()`` — they are contained in the
+  train/eval phases, not additive with them. With the residency caches
+  warm, load+stage collapse to ~0 for trial 2..N of a sub-train-job.
+  The count of ``step_wait`` is a trial's progress from outside:
+  dispatches completed (times ``steps_per_dispatch`` = steps).
 - ``rafiki_tpu_trial_dataset_cache_total{event=hit|miss|evict}`` and
   ``rafiki_tpu_trial_stage_cache_total{event=hit|miss|evict}`` — the
   host dataset cache (``model/dataset.py``) and device staging cache
@@ -34,11 +36,39 @@ sums across a whole window.
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+import sys
+import time
+from typing import Any, Dict, Optional
 
 from . import metrics
+from . import trace as _trace
 
-PHASES = ("propose", "load", "stage", "train", "eval", "persist")
+#: Every phase ``span`` times. The helper records no parent: nesting is
+#: by time on one thread, as indented here. Direct children of
+#: ``trial`` never overlap, so ``trial`` minus their sum is what no span
+#: covers. A ``propose`` that ends the search (it returns None) still
+#: closes one ``trial`` around itself.
+#:
+#:   trial            the whole of TrialRunner.run_one       trial thread
+#:     propose        advisor.propose()
+#:     open           shared-params retrieve, validate_knobs, trial row
+#:     init           model_class(**knobs)
+#:     train          model.train
+#:       load, stage    dataset parse / H2D (also inside eval)
+#:       step_setup     LM: entry to the loop (params, step cache, opt)
+#:       step_dispatch  LM, each chunk: windows, device_put, train_chunk
+#:                      (its trace and compile on a step-cache miss)
+#:       step_wait      LM, each chunk: host blocked on the device
+#:     eval           model.evaluate
+#:     dump           model.dump_parameters()
+#:     feedback       advisor.feedback
+#:     handover       wait for the previous trial's tail to leave the
+#:                    persist stage (pipeline off: contains persist)
+#:   persist          the tail: log flush, param save, meta commit
+#:                    (persist thread; overlaps the NEXT trial)
+PHASES = ("trial", "propose", "open", "init", "train", "load", "stage",
+          "step_setup", "step_dispatch", "step_wait", "eval", "dump",
+          "feedback", "handover", "persist")
 
 #: Trial phases span four orders of magnitude more than a bus push:
 #: a warm load/stage is sub-millisecond, a real train phase minutes.
@@ -55,9 +85,9 @@ def _reg() -> Dict[str, object]:
         _m = {
             "phase": r.histogram(
                 "rafiki_tpu_trial_phase_seconds",
-                "Wall time of one trial-lifecycle phase (phase="
-                "propose|load|stage|train|eval|persist; load/stage are "
-                "sub-spans of train/eval)", buckets=PHASE_BUCKETS),
+                "Wall time of one trial-lifecycle phase (phase=" +
+                "|".join(PHASES) + "; nesting: observe/phases.py "
+                "PHASES)", buckets=PHASE_BUCKETS),
             "dataset_cache": r.counter(
                 "rafiki_tpu_trial_dataset_cache_total",
                 "Host dataset cache events (event=hit|miss|evict)"),
@@ -82,6 +112,63 @@ def observe_phase(phase: str, seconds: float) -> None:
         _reg()["phase"].observe(seconds, phase=phase)
 
 
+class span:
+    """``with phases.span("eval"): ...`` — the one way a trial phase is
+    timed. On exit, normal or by exception, the duration goes to the
+    phase histogram (``observe_phase``). For its duration the block
+    holds a ``jax.profiler.TraceAnnotation("rafiki.trial.<phase>")``,
+    so a profiler session (``RAFIKI_TPU_TRACE_DIR``, an on-demand
+    profile) shows the phase on the device trace's own clock; with no
+    session the annotation is inert. Taken only when jax is already
+    imported: this module stays stdlib-only.
+
+    ``attrs`` become the annotation's stats. Every span of one trial
+    carries ``trial=<first 12 of the id>``: given, or else the label
+    the TrialRunner bound on this thread (``metrics.label_context``),
+    which is how a model's spans name a trial they know nothing of.
+
+    ``ctx`` (a ``TraceContext``) also appends a ``trial.<phase>`` event
+    to the span store under that trace, with ``self.attrs`` as they
+    stand at exit, so a block can add what it learned
+    (``sp.attrs["params_save_ms"] = ...``)."""
+
+    __slots__ = ("phase", "attrs", "_ctx", "_service", "_annotation",
+                 "_wall", "_t0")
+
+    def __init__(self, phase: str, *, ctx: Optional[Any] = None,
+                 service: str = "", **attrs: Any):
+        self.phase = phase
+        self.attrs = attrs
+        self._ctx = ctx
+        self._service = service
+
+    def __enter__(self) -> "span":
+        if "trial" not in self.attrs:
+            trial = metrics.bound_labels().get("trial")
+            if trial:
+                self.attrs["trial"] = trial
+        profiler = sys.modules.get("jax.profiler")
+        self._annotation = None if profiler is None else \
+            profiler.TraceAnnotation(f"rafiki.trial.{self.phase}",
+                                     **self.attrs)
+        self._wall = time.time()
+        self._t0 = time.monotonic()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        seconds = time.monotonic() - self._t0
+        observe_phase(self.phase, seconds)
+        if self._ctx is not None:
+            _trace.record_event(f"trial.{self.phase}", self._service,
+                                [self._ctx], self._wall, seconds,
+                                attrs=self.attrs or None)
+        return False
+
+
 def cache_event(cache: str, event: str, n: int = 1) -> None:
     """``cache`` is ``"dataset"`` or ``"stage"``; ``event`` one of
     hit/miss/evict."""
@@ -104,8 +191,8 @@ def cache_counts(cache: str) -> Dict[str, int]:
 
 def phase_totals() -> Dict[str, Dict[str, float]]:
     """{phase: {"sum": seconds, "count": n}} — snapshot-diffable, which
-    is how ``bench.py --config trials`` derives its per-trial phase
-    breakdown."""
+    is how the benchmark's readers (``benchmarks/metrics/``) and
+    ``bench.py --config trials`` derive a per-trial phase breakdown."""
     h = _reg()["phase"]
     return {p: {"sum": h.sum(phase=p), "count": h.count(phase=p)}
             for p in PHASES}

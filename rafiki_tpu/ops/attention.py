@@ -329,6 +329,7 @@ def _flash_forward(q, k, v, bias, causal, block_q, block_kv, interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "flash_fwd"},
     )(*inputs)
     out = out.reshape(b, h, nq * block_q, dp)[:, :, :tq, :d]
     if return_lse:
@@ -536,6 +537,7 @@ def _flash_backward(q, k, v, bias, out, lse, g, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "flash_dq"},
     )(*inputs)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, **common),
@@ -554,6 +556,7 @@ def _flash_backward(q, k, v, bias, out, lse, g, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "flash_dkv"},
     )(*inputs)
     dq = dq.reshape(b, h, nq * block_q, dp)[:, :, :tq, :d]
     dk = dk.reshape(b, h, nk * block_kv, dp)[:, :, :tkv, :d]

@@ -413,9 +413,13 @@ class MetaStore:
     def create_trial(self, sub_train_job_id: str, model_id: str, no: int,
                      status: str, worker_id: Optional[str] = None,
                      knobs: Optional[Dict[str, Any]] = None,
-                     proposal: Optional[Dict[str, Any]] = None) -> Row:
+                     proposal: Optional[Dict[str, Any]] = None,
+                     trial_id: Optional[str] = None) -> Row:
+        """``trial_id``: an id the caller minted (the TrialRunner names
+        a trial's spans before the row exists); else a fresh one."""
         return self._insert("trials", {
-            "id": _new_id(), "no": no, "sub_train_job_id": sub_train_job_id,
+            "id": trial_id or _new_id(), "no": no,
+            "sub_train_job_id": sub_train_job_id,
             "model_id": model_id, "worker_id": worker_id, "status": status,
             "knobs": knobs, "score": None, "params_id": None,
             "proposal": proposal, "error": None, "started_at": _now(),

@@ -10,6 +10,7 @@ a bus-backed remote proxy).
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import logging
@@ -18,6 +19,7 @@ import shutil
 import threading
 import time
 import traceback
+import uuid
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional, Type
 
@@ -326,80 +328,108 @@ class TrialRunner:
 
     def run_one(self, proposal: Optional[Proposal] = None,
                 ) -> Optional[Dict[str, Any]]:
-        if proposal is None:
-            t_prop = time.monotonic()
-            proposal = self.advisor.propose()
-            _phases.observe_phase("propose",
-                                  time.monotonic() - t_prop)
-        if proposal is None:  # advisor side says: search is over
-            return None
-        # Warm-start params are resolved BEFORE knob validation: a
-        # proposal may carry reduced knobs that are only valid with the
-        # warm start (PBT rounds train delta epochs) plus
-        # ``cold_start_knobs`` overrides to apply when the shared params
-        # are legitimately absent (expired store, fresh node). A
-        # retrieval ERROR is different from absence: silently cold-
-        # starting would feed an artificially poor score back into the
-        # search (e.g. the ENAS controller), so it errs the trial and
-        # refunds the proposal like any other trial failure.
-        params_scope = proposal.meta.get("params_scope") or self.worker_id
-        try:
-            shared = self.params.retrieve(
-                proposal.params_type, session_id=self.sub_train_job_id,
-                worker_id=params_scope)
-        except Exception:
-            err = traceback.format_exc()
-            trial = self.meta.create_trial(
+        # The id is minted here, not by the meta store, so that every
+        # span of the trial, ``propose`` included, can carry it; the
+        # label context hands it to the spans the model opens.
+        trial_id = uuid.uuid4().hex
+        # Span-store context, resolved on THIS (trial) thread: the
+        # ambient context when one exists (an admin-triggered run),
+        # else a context whose trace id IS the trial id — so
+        # ``GET /trace/<trial_id>`` shows the trial's timeline, the
+        # persist tail included (the thread-local is lost across the
+        # persist-stage hop, hence the capture here).
+        ctx = _trace.current() or _trace.TraceContext(trial_id)
+        span = functools.partial(_phases.span, ctx=ctx,
+                                 service=self.worker_id)
+        with metrics.label_context(trial=trial_id[:12]), span("trial"):
+            if proposal is None:
+                with span("propose"):
+                    proposal = self.advisor.propose()
+            if proposal is None:  # advisor side says: search is over
+                return None
+            return self._run_trial(trial_id, proposal, span)
+
+    def _run_trial(self, trial_id: str, proposal: Proposal,
+                   span: Callable[..., Any]) -> Dict[str, Any]:
+        """One proposed trial, inside ``run_one``'s ``trial`` span;
+        ``span`` is ``phases.span`` bound to the trial's span-store
+        context."""
+        with span("open"):
+            # Warm-start params are resolved BEFORE knob validation: a
+            # proposal may carry reduced knobs that are only valid with
+            # the warm start (PBT rounds train delta epochs) plus
+            # ``cold_start_knobs`` overrides to apply when the shared
+            # params are legitimately absent (expired store, fresh
+            # node). A retrieval ERROR is different from absence:
+            # silently cold-starting would feed an artificially poor
+            # score back into the search (e.g. the ENAS controller), so
+            # it errs the trial and refunds the proposal like any other
+            # trial failure.
+            params_scope = proposal.meta.get("params_scope") \
+                or self.worker_id
+            try:
+                shared = self.params.retrieve(
+                    proposal.params_type,
+                    session_id=self.sub_train_job_id,
+                    worker_id=params_scope)
+            except Exception:
+                err = traceback.format_exc()
+                self.meta.create_trial(
+                    self.sub_train_job_id, self.model_id,
+                    no=proposal.trial_no, status=TrialStatus.RUNNING,
+                    worker_id=self.worker_id,
+                    knobs=_jsonable_knobs(proposal.knobs),
+                    proposal=proposal.to_json(), trial_id=trial_id)
+                self.meta.mark_trial_errored(trial_id, err)
+                forget = getattr(self.advisor, "forget", None)
+                if forget is not None:
+                    forget(proposal)
+                _log.warning("trial #%d: shared-params retrieval "
+                             "failed:\n%s", proposal.trial_no, err)
+                return self.meta.get_trial(trial_id)
+            raw_knobs = dict(proposal.knobs)
+            if shared is None:
+                raw_knobs.update(
+                    proposal.meta.get("cold_start_knobs") or {})
+            knobs = self.model_class.validate_knobs(raw_knobs)
+            # The RECORDED knobs are the reproducible configuration
+            # (``record_knobs`` overlays e.g. ASHA's cumulative budget
+            # over the executed delta).
+            recorded = {**knobs,
+                        **(proposal.meta.get("record_knobs") or {})}
+            self.meta.create_trial(
                 self.sub_train_job_id, self.model_id,
                 no=proposal.trial_no, status=TrialStatus.RUNNING,
                 worker_id=self.worker_id,
-                knobs=_jsonable_knobs(proposal.knobs),
-                proposal=proposal.to_json())
-            self.meta.mark_trial_errored(trial["id"], err)
-            forget = getattr(self.advisor, "forget", None)
-            if forget is not None:
-                forget(proposal)
-            _log.warning("trial #%d: shared-params retrieval failed:\n%s",
-                         proposal.trial_no, err)
-            return self.meta.get_trial(trial["id"])
-        raw_knobs = dict(proposal.knobs)
-        if shared is None:
-            raw_knobs.update(proposal.meta.get("cold_start_knobs") or {})
-        knobs = self.model_class.validate_knobs(raw_knobs)
-        # The RECORDED knobs are the reproducible configuration
-        # (``record_knobs`` overlays e.g. ASHA's cumulative budget over
-        # the executed delta).
-        recorded = {**knobs, **(proposal.meta.get("record_knobs") or {})}
-        trial = self.meta.create_trial(
-            self.sub_train_job_id, self.model_id, no=proposal.trial_no,
-            status=TrialStatus.RUNNING, worker_id=self.worker_id,
-            knobs=_jsonable_knobs(recorded), proposal=proposal.to_json())
-        trial_id = trial["id"]
+                knobs=_jsonable_knobs(recorded),
+                proposal=proposal.to_json(), trial_id=trial_id)
 
-        # Save + chain whatever sink this thread already had (a bench
-        # harness's utilization probe, a test capture): the trial's
-        # records go to the meta store AND keep flowing outward, and the
-        # prior binding is restored afterwards instead of nulled.
-        # With the persist pipeline on, the meta-store writes are
-        # BUFFERED and flushed by the trial's persist tail (one less
-        # sqlite insert interleaved with device dispatch); the chained
-        # outward flow stays live either way.
-        prior_sink = logger.current_sink()
-        buffering = self._persist is not None
-        log_buffer: List[Any] = []
+            # Save + chain whatever sink this thread already had (a
+            # bench harness's utilization probe, a test capture): the
+            # trial's records go to the meta store AND keep flowing
+            # outward, and the prior binding is restored afterwards
+            # instead of nulled. With the persist pipeline on, the
+            # meta-store writes are BUFFERED and flushed by the trial's
+            # persist tail (one less sqlite insert interleaved with
+            # device dispatch); the chained outward flow stays live
+            # either way.
+            prior_sink = logger.current_sink()
+            buffering = self._persist is not None
+            log_buffer: List[Any] = []
 
-        def _trial_sink(rec, _tid=trial_id, _prior=prior_sink):
-            if buffering:
-                log_buffer.append(rec)
-            else:
-                self.meta.add_trial_log(_tid, rec)
-            if _prior is not None:
-                _prior(rec)
+            def _trial_sink(rec, _tid=trial_id, _prior=prior_sink):
+                if buffering:
+                    log_buffer.append(rec)
+                else:
+                    self.meta.add_trial_log(_tid, rec)
+                if _prior is not None:
+                    _prior(rec)
 
         logger.set_sink(_trial_sink)
         t0 = time.time()
         try:
-            model = self.model_class(**knobs)
+            with span("init"):
+                model = self.model_class(**knobs)
             # Opt-in mid-trial checkpointing (RAFIKI_TPU_CKPT=1): the dir
             # is keyed by (sub_train_job, knobs), not trial id, so the
             # re-proposed trial after a worker crash resumes the crashed
@@ -435,28 +465,27 @@ class TrialRunner:
                 # stage_owner marks the residency-cache entries this
                 # trial stages as THIS sub-train-job's, so evictions
                 # under budget pressure prefer other jobs' datasets
-                # (model/dataset.py ByteBudgetLRU).
-                t_train = time.monotonic()
-                with metrics.label_context(trial=trial_id[:12]), \
-                        stage_owner(self.sub_train_job_id), \
-                        trace_session(trial_trace_dir(trial_id)):
+                # (model/dataset.py ByteBudgetLRU). The span opens
+                # inside the session, so the trial's own trace holds it.
+                with stage_owner(self.sub_train_job_id), \
+                        trace_session(trial_trace_dir(trial_id)), \
+                        span("train"):
                     model.train(self.train_dataset_path,
                                 shared_params=shared, **train_kwargs)
-                _phases.observe_phase("train",
-                                      time.monotonic() - t_train)
-                t_eval = time.monotonic()
-                with stage_owner(self.sub_train_job_id):
+                with stage_owner(self.sub_train_job_id), span("eval"):
                     score = float(model.evaluate(self.val_dataset_path))
-                _phases.observe_phase("eval",
-                                      time.monotonic() - t_eval)
                 # A proposal may retrieve from one scope and save under
                 # another (PBT exploitation inherits the winner's
                 # weights but keeps writing its own lineage).
                 save_scope = proposal.meta.get("params_save_scope") \
                     or params_scope
-                # Device arrays pass through un-pulled (the ParamStore
-                # write-behind does the packed D2H in the background).
-                dumped = model.dump_parameters()
+                # What ``dump`` costs is the model's choice: the LM
+                # pulls every leaf to the host here, synchronously
+                # (np.asarray, 1.6 GB at the benchmark's widths); a
+                # model that returns device arrays leaves the D2H to
+                # the ParamStore write-behind on the persist thread.
+                with span("dump"):
+                    dumped = model.dump_parameters()
             finally:
                 model.destroy()
             # Spend the unscoped crash-resume checkpoint dir NOW, by a
@@ -482,9 +511,14 @@ class TrialRunner:
             # tail owns the trial's log buffer and terminal status, no
             # later exception on this thread may touch them (the except
             # below would race the persist thread's writes).
-            self.advisor.feedback(proposal, score)
-            self._finish_trial(trial_id, score, dumped, save_scope,
-                               log_buffer, ckpt_tomb)
+            with span("feedback"):
+                self.advisor.feedback(proposal, score)
+            # As the trial thread sees it: the wait for the previous
+            # trial's tail to leave the single-slot persist stage (with
+            # the pipeline off, the whole tail).
+            with span("handover"):
+                self._finish_trial(trial_id, score, dumped, save_scope,
+                                   log_buffer, ckpt_tomb, span)
             _log.info("trial %s #%d done: score=%.4f (%.1fs)", trial_id[:8],
                       proposal.trial_no, score, time.time() - t0)
         except Exception:
@@ -514,7 +548,8 @@ class TrialRunner:
 
     def _finish_trial(self, trial_id: str, score: float, dumped: Any,
                       save_scope: str, log_buffer: List[Any],
-                      ckpt_tomb: Optional[str]) -> None:
+                      ckpt_tomb: Optional[str],
+                      span: Callable[..., Any]) -> None:
         """The completed-trial persist tail: flush the buffered trial
         logs, hand the dumped parameters to the ParamStore, mark the
         trial COMPLETED, sweep the spent (already tombstone-renamed)
@@ -525,33 +560,33 @@ class TrialRunner:
         then overlaps trial N's persistence. A tail failure
         retroactively marks the trial ERRORED (the advisor's feedback
         stands — the score was real; only persistence failed)."""
-        # Span context for the tail, resolved on THIS (trial) thread:
-        # the ambient context when one exists (an admin-triggered run),
-        # else a context whose trace id IS the trial id — so
-        # ``GET /trace/<trial_id>`` shows the persist tail's timeline
-        # (the carried r9 item: where does post-train time go). The
-        # thread-local is lost across the persist-stage hop, hence the
-        # capture here, not inside ``tail``.
-        ctx = _trace.current() or _trace.TraceContext(str(trial_id))
-
         def tail(commit: Callable[[Callable], None]) -> None:
-            t_persist = time.monotonic()
-            wall0 = time.time()
-            flush_s = save_s = commit_s = 0.0
+            # The label context that names the trial is thread-local
+            # and does not cross the persist-stage hop: this span is
+            # GIVEN the id, so that a span on another thread names the
+            # trial that caused it. Its attrs carry the tail's stages.
+            with span("persist", trial=trial_id[:12]) as sp:
+                persist(commit, sp.attrs)
+
+        def persist(commit: Callable[[Callable], None],
+                    took: Dict[str, Any]) -> None:
+            def ms_since(t: float) -> float:
+                return round((time.monotonic() - t) * 1e3, 3)
+
             try:
                 t = time.monotonic()
                 for rec in log_buffer:
                     self.meta.add_trial_log(trial_id, rec)
-                flush_s = time.monotonic() - t
+                took["log_flush_ms"] = ms_since(t)
                 t = time.monotonic()
                 params_id = self.params.save(
                     dumped, session_id=self.sub_train_job_id,
                     worker_id=save_scope, score=score)
-                save_s = time.monotonic() - t
+                took["params_save_ms"] = ms_since(t)
                 t = time.monotonic()
                 commit(lambda: self.meta.mark_trial_completed(
                     trial_id, score, params_id))
-                commit_s = time.monotonic() - t
+                took["meta_commit_ms"] = ms_since(t)
                 # Scoped checkpoints outlive the trial — the
                 # configuration's next rung resumes them;
                 # cleanup_scoped_checkpoints() runs when the sub-job is
@@ -570,17 +605,6 @@ class TrialRunner:
                 except Exception:
                     _log.exception("trial %s: could not record persist "
                                    "failure", trial_id[:8])
-            finally:
-                dur = time.monotonic() - t_persist
-                _phases.observe_phase("persist", dur)
-                # One span with the stage breakdown in attrs (no-op
-                # without a configured span sink).
-                _trace.record_event(
-                    "trial.persist", self.worker_id, [ctx], wall0, dur,
-                    attrs={"trial_id": str(trial_id)[:12],
-                           "log_flush_ms": round(flush_s * 1e3, 3),
-                           "params_save_ms": round(save_s * 1e3, 3),
-                           "meta_commit_ms": round(commit_s * 1e3, 3)})
 
         if self._persist is not None:
             self._persist.submit(tail)

@@ -121,6 +121,25 @@ def test_flash_kernel_compiles_on_one_chip(one_chip, case):
     _compile(_sum_grad(attend) if grad else attend, *args)
 
 
+def test_flash_kernels_carry_their_names_into_the_compiled_program(
+        one_chip):
+    """``pallas_call(metadata={"kernel": ...})`` is what survives into
+    the HLO text a profiler's device op event carries (a
+    ``jax.named_scope`` does not): each of the three kernels of a
+    training step is told from the others by name."""
+    import re
+
+    shape, dtype, kwargs, _ = _KERNEL_SHAPES["flagship"]
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, interpret=False, **kwargs)
+
+    text = _compile(_sum_grad(attend), x, x, x).as_text()
+    named = re.findall(r'kernel_metadata=\{\s*"kernel":"(\w+)"', text)
+    assert set(named) == {"flash_fwd", "flash_dq", "flash_dkv"}
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
 def test_flash_kernel_compiles_batch_sharded_over_dp4(dp_mesh, grad):
     """The four-chip path: batch over a 4-device dp mesh. Bare, the

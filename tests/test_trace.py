@@ -615,12 +615,19 @@ def test_admin_trace_route_and_metrics(tmp_path):
                               timeout=10).json()
         assert "mfu" in status
         # /trial_phases feeds the dashboard's phase-breakdown panel:
-        # all six phases present (zero-count here — no resident trials)
-        # and authenticated like every other admin read.
+        # every phase present (zero-count here — no resident trials),
+        # in PHASES' order (parents before their children, which is the
+        # order the panel's rows come out in), and authenticated like
+        # every other admin read.
+        from rafiki_tpu.observe import phases
+
         tp = requests.get(base + "/trial_phases", headers=hdr,
                           timeout=10).json()
-        assert set(tp["phases"]) == {"propose", "load", "stage",
-                                     "train", "eval", "persist"}
+        assert list(tp["phases"]) == list(phases.PHASES)
+        assert set(tp["phases"]) == {
+            "trial", "propose", "open", "init", "train", "load",
+            "stage", "step_setup", "step_dispatch", "step_wait", "eval",
+            "dump", "feedback", "handover", "persist"}
         assert set(tp["caches"]) == {"dataset", "stage"}
         assert "resident" in tp and "enabled" in tp
         assert requests.get(base + "/trial_phases",
