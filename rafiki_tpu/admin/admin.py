@@ -839,7 +839,7 @@ class Admin:
                 if v["count"] else 0.0}
             for p, v in totals.items()}
         caches = {c: obs_phases.cache_counts(c)
-                  for c in ("dataset", "stage")}
+                  for c in obs_phases.CACHES}
         return {"enabled": obs_metrics.metrics_enabled(),
                 "resident": resident, "phases": phases,
                 "caches": caches}

@@ -80,6 +80,7 @@ def _step_cache_get(key: Any) -> Optional[Dict[str, Any]]:
     entry = _STEP_CACHE.get(key)
     if entry is not None:
         _STEP_CACHE.move_to_end(key)
+    _phases.cache_event("step", "miss" if entry is None else "hit")
     return entry
 
 

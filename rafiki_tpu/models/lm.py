@@ -126,6 +126,58 @@ def _layer_norm(x, g):
     return (xf - m) * jax.lax.rsqrt(v + 1e-6) * g
 
 
+def _lm_block(x, lp, h_heads, mesh):
+    d = x.shape[-1]
+    h = _layer_norm(x, lp["ln1"]).astype(jnp.bfloat16)
+    qkv = h @ lp["qkv"].astype(jnp.bfloat16)
+    q, k, v = jnp.split(qkv, 3, axis=-1)
+
+    def heads(a):
+        b, t, _ = a.shape
+        return a.reshape(b, t, h_heads,
+                         d // h_heads).transpose(0, 2, 1, 3)
+
+    o = batch_sharded_flash_attention(
+        heads(q), heads(k), heads(v), mesh, causal=True)
+    b, nh, t, dh = o.shape
+    o = o.transpose(0, 2, 1, 3).reshape(b, t, nh * dh)
+    x = x + (o @ lp["proj"].astype(jnp.bfloat16)).astype(x.dtype)
+    h = _layer_norm(x, lp["ln2"]).astype(jnp.bfloat16)
+    h = jax.nn.gelu(h @ lp["w1"].astype(jnp.bfloat16))
+    return x + (h @ lp["w2"].astype(jnp.bfloat16)).astype(x.dtype)
+
+
+def _lm_forward(params, ids, s, remat, mesh):
+    """Logits (float32) of ``ids`` under ``params``: a function of its
+    arguments alone (``s`` is ``_dims()``), so a program built on it
+    holds no model instance."""
+    # ×√d (Vaswani et al. §3.4): 0.02-scale embedding rows against
+    # unit-scale sinusoidal PE would leave the token signal at ~2%
+    # of the stream — below useful bf16 resolution after the first
+    # residual add.
+    x = params["embed"].astype(jnp.bfloat16)[ids] \
+        * jnp.bfloat16(math.sqrt(s["d"]))
+    pos = _sinusoidal(s["t"], s["d"])
+    x = x + jnp.asarray(pos)[None, :ids.shape[1]].astype(x.dtype)
+
+    body = functools.partial(_lm_block, h_heads=s["h"], mesh=mesh)
+    if remat == "full":
+        body = jax.checkpoint(body)
+    elif remat == "dots":
+        body = jax.checkpoint(
+            body, policy=jax.checkpoint_policies
+            .dots_with_no_batch_dims_saveable)
+
+    def scan_body(x, lp):
+        return body(x, lp), None
+
+    x, _ = jax.lax.scan(scan_body, x, params["layers"])
+    x = _layer_norm(x, params["lnf"]).astype(jnp.bfloat16)
+    # Tied unembedding: logits in f32 for a stable softmax.
+    return (x @ params["embed"].astype(jnp.bfloat16).T
+            ).astype(jnp.float32)
+
+
 class JaxTransformerLM(BaseModel):
     """Decoder-only causal transformer LM on the flash kernels."""
 
@@ -194,53 +246,17 @@ class JaxTransformerLM(BaseModel):
         return init(int(self.knobs.get("seed", 0)))
 
     def _block(self, x, lp, h_heads):
-        d = x.shape[-1]
-        h = _layer_norm(x, lp["ln1"]).astype(jnp.bfloat16)
-        qkv = h @ lp["qkv"].astype(jnp.bfloat16)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        return _lm_block(x, lp, h_heads, self.mesh)
 
-        def heads(a):
-            b, t, _ = a.shape
-            return a.reshape(b, t, h_heads,
-                             d // h_heads).transpose(0, 2, 1, 3)
-
-        o = batch_sharded_flash_attention(
-            heads(q), heads(k), heads(v), self.mesh, causal=True)
-        b, nh, t, dh = o.shape
-        o = o.transpose(0, 2, 1, 3).reshape(b, t, nh * dh)
-        x = x + (o @ lp["proj"].astype(jnp.bfloat16)).astype(x.dtype)
-        h = _layer_norm(x, lp["ln2"]).astype(jnp.bfloat16)
-        h = jax.nn.gelu(h @ lp["w1"].astype(jnp.bfloat16))
-        return x + (h @ lp["w2"].astype(jnp.bfloat16)).astype(x.dtype)
+    def _forward_spec(self):
+        """What ``_forward`` reads of the instance, and all it reads:
+        the dims, the remat policy and the mesh. A compiled program may
+        close over this (and be keyed on it); never over the instance,
+        whose parameters would then live as long as the program."""
+        return self._dims(), str(self.knobs.get("remat", "dots")), self.mesh
 
     def _forward(self, params, ids):
-        s = self._dims()
-        # ×√d (Vaswani et al. §3.4): 0.02-scale embedding rows against
-        # unit-scale sinusoidal PE would leave the token signal at ~2%
-        # of the stream — below useful bf16 resolution after the first
-        # residual add.
-        x = params["embed"].astype(jnp.bfloat16)[ids] \
-            * jnp.bfloat16(math.sqrt(s["d"]))
-        pos = _sinusoidal(s["t"], s["d"])
-        x = x + jnp.asarray(pos)[None, :ids.shape[1]].astype(x.dtype)
-
-        body = functools.partial(self._block, h_heads=s["h"])
-        remat = str(self.knobs.get("remat", "dots"))
-        if remat == "full":
-            body = jax.checkpoint(body)
-        elif remat == "dots":
-            body = jax.checkpoint(
-                body, policy=jax.checkpoint_policies
-                .dots_with_no_batch_dims_saveable)
-
-        def scan_body(x, lp):
-            return body(x, lp), None
-
-        x, _ = jax.lax.scan(scan_body, x, params["layers"])
-        x = _layer_norm(x, params["lnf"]).astype(jnp.bfloat16)
-        # Tied unembedding: logits in f32 for a stable softmax.
-        return (x @ params["embed"].astype(jnp.bfloat16).T
-                ).astype(jnp.float32)
+        return _lm_forward(params, ids, *self._forward_spec())
 
     def _flops_per_step(self, b: int) -> float:
         """Analytic train-step FLOPs (fwd+bwd): 6·N·tokens for matmul
@@ -406,23 +422,64 @@ class JaxTransformerLM(BaseModel):
         self._params = params
         self._invalidate_compiled()
 
+    def _eval_count_step(self, n_win: int):
+        """The evaluation's one program, from the step cache:
+        ``(params, inputs, targets) -> int32`` count of positions whose
+        arg-max logit is the target. Keyed on what the forward reads
+        (class, dims, remat, mesh) and the number of windows, and on no
+        other knob: trials that differ in ``learning_rate``,
+        ``train_steps`` or ``seed`` share it."""
+        s, remat, mesh = self._forward_spec()
+        key = (type(self), "eval", tuple(sorted(s.items())), remat, mesh,
+               n_win)
+        cached = _step_cache_get(key)
+        if cached is not None:
+            return cached["step"]
+
+        @jax.jit
+        def eval_count(params, inputs, targets):
+            # The barrier keeps the logits the ones ``jit(_forward)``
+            # hands ``predict`` (and handed the host arg-max this
+            # replaces). Without it the TPU compiler fuses the arg-max
+            # into the unembedding and reduces over that matmul's bf16
+            # output, where the matmul alone keeps its float32
+            # accumulator: the arg-max then parts in 2 % of positions
+            # (179 of 8192, chip run, PR 28). It costs the 1.65 GB of
+            # logits one trip through HBM, a few milliseconds.
+            logits = jax.lax.optimization_barrier(
+                _lm_forward(params, inputs, s, remat, mesh))
+            return (logits.argmax(-1) == targets).sum(dtype=jnp.int32)
+
+        _step_cache_put(key, {"step": eval_count})
+        return eval_count
+
     def evaluate(self, dataset_path: str) -> float:
         """Mean next-token accuracy over contiguous validation
-        windows.
+        windows, reduced on the device: one step-cached program
+        (``_eval_count_step``) takes the parameters, the input windows
+        and the target windows and returns the NUMBER of positions
+        where the arg-max of the logits is the target; that int32
+        scalar is all that comes back, and the host divides it by the
+        number of positions. No logits leave the device (at the
+        flagship shape they are 1.65 GB, and a host arg-max over them
+        took 2 s). ``jnp.argmax`` takes the first index of a tie, as
+        ``np.argmax`` does, and the division is in Python floats, so
+        the score is the one a host arg-max over ``jit(_forward)``'s
+        logits gives (``_eval_count_step`` says what keeps them the
+        same logits on the TPU).
 
-        The token stream rides the cross-trial device staging cache
-        (``staged_token_ids``): eval windows are gathered in-graph from
-        the resident int32 stream by DEVICE-COMPUTED iota indices, so
-        eval 2..N of a sub-train-job ships zero token bytes host->
-        device (the r9 zero-H2D contract, extended to the LM path —
-        shipping an index matrix from the host would be pointless here:
-        int32 indices are exactly as many bytes as the int32 windows
-        themselves). Streams over the staging budget keep the legacy
-        host ``np.stack`` path."""
+        Where the windows come from is the only branch. The token
+        stream rides the cross-trial device staging cache
+        (``staged_token_ids``) and the windows are gathered from the
+        resident int32 stream by DEVICE-COMPUTED iota indices, so eval
+        2..N of a sub-train-job ships zero token bytes host->device
+        (int32 indices from the host would be exactly as many bytes as
+        the windows themselves). A stream over the staging budget has
+        its windows stacked on the host and put on the mesh."""
         ds = load_token_dataset(dataset_path)
         t = self._dims()["t"]
         n_win = max(1, min(16, (ds.size - 1) // t))
-        fn = self._ensure_predict_fn()
+        params = self._ensure_params_dev()
         stage_bytes = int(os.environ.get("RAFIKI_TPU_STAGE_BYTES",
                                          2 << 30))
         # Gated on the stream being CACHEABLE, not just stageable: with
@@ -434,15 +491,13 @@ class JaxTransformerLM(BaseModel):
                 and ds.size >= n_win * t + 1:
             ids_dev = staged_token_ids(dataset_path, ds, self.mesh)
             inputs, targets = _jitted_eval_windows(n_win, t)(ids_dev)
-            logits = np.asarray(fn(self._params_dev, inputs))
-            targets = np.asarray(targets)
         else:
             ids = np.stack([ds.ids[i * t:i * t + t + 1]
-                            for i in range(n_win)])
-            logits = np.asarray(fn(self._params_dev,
-                                   jnp.asarray(ids[:, :-1], jnp.int32)))
-            targets = ids[:, 1:]
-        return float((logits.argmax(-1) == targets).mean())
+                            for i in range(n_win)]).astype(np.int32)
+            inputs, targets = jax.device_put(
+                (ids[:, :-1], ids[:, 1:]), replicated(self.mesh))
+        correct = self._eval_count_step(n_win)(params, inputs, targets)
+        return int(jax.device_get(correct)) / (n_win * t)
 
     def predict(self, queries: List[Any]) -> List[Any]:
         """Scores token-id sequences: mean next-token log-probability
@@ -483,11 +538,17 @@ class JaxTransformerLM(BaseModel):
             "train() or load_parameters() first"
         return LMGenerator(self, **cfg)
 
-    def _ensure_predict_fn(self):
+    def _ensure_params_dev(self):
         assert self._params is not None, "train() or load_parameters() first"
         if self._params_dev is None:
             self._params_dev = jax.device_put(self._params,
                                               replicated(self.mesh))
+        return self._params_dev
+
+    def _ensure_predict_fn(self):
+        """The logits program of ``predict`` and the generator's
+        reference: per instance (``evaluate`` does not use it)."""
+        self._ensure_params_dev()
         if self._predict_fn is None:
             self._predict_fn = jax.jit(self._forward)
         return self._predict_fn

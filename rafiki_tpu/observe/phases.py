@@ -22,6 +22,12 @@ serving stage histogram did for the frontend:
   (``model/jax_model.py``) hit/miss/eviction counters. Trial 2..N of a
   job performing ZERO disk loads and ZERO full-dataset H2D shows up as
   misses staying flat while hits grow (the bench's regression check).
+- ``rafiki_tpu_trial_step_cache_total{event=hit|miss}`` — lookups of
+  the compiled-step cache (``model/jax_model.py:_step_cache_get``), one
+  per train / init / eval program a trial asks for, whatever the model
+  class. A job of congruent trials shows its misses staying flat after
+  the first trial; a miss per trial is a program built (and, where its
+  constants differ, compiled) per trial.
 - ``rafiki_tpu_trial_dataset_cache_bytes`` /
   ``rafiki_tpu_trial_stage_cache_bytes`` — current cache occupancy
   against the ``RAFIKI_TPU_DATASET_CACHE_BYTES`` /
@@ -70,6 +76,10 @@ PHASES = ("trial", "propose", "open", "init", "train", "load", "stage",
           "step_setup", "step_dispatch", "step_wait", "eval", "dump",
           "feedback", "handover", "persist")
 
+#: The counted caches (``cache_event`` / ``cache_counts``): the host
+#: dataset cache, the device staging cache, the compiled-step cache.
+CACHES = ("dataset", "stage", "step")
+
 #: Trial phases span four orders of magnitude more than a bus push:
 #: a warm load/stage is sub-millisecond, a real train phase minutes.
 PHASE_BUCKETS = (0.001, 0.005, 0.025, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
@@ -94,6 +104,9 @@ def _reg() -> Dict[str, object]:
             "stage_cache": r.counter(
                 "rafiki_tpu_trial_stage_cache_total",
                 "Device staging cache events (event=hit|miss|evict)"),
+            "step_cache": r.counter(
+                "rafiki_tpu_trial_step_cache_total",
+                "Compiled-step cache lookups (event=hit|miss)"),
             "dataset_cache_bytes": r.gauge(
                 "rafiki_tpu_trial_dataset_cache_bytes",
                 "Bytes held by the host dataset cache"),
@@ -170,8 +183,8 @@ class span:
 
 
 def cache_event(cache: str, event: str, n: int = 1) -> None:
-    """``cache`` is ``"dataset"`` or ``"stage"``; ``event`` one of
-    hit/miss/evict."""
+    """``cache`` is one of ``CACHES`` (``"dataset"``, ``"stage"`` or
+    ``"step"``); ``event`` one of hit/miss/evict (``"step"``: hit/miss)."""
     if metrics.metrics_enabled():
         # rta: disable=RTA301 event is hit|miss|evict; deliberately immortal (module docstring)
         _reg()[f"{cache}_cache"].inc(n, event=event)

@@ -218,3 +218,24 @@ def test_generate_programs_compile_and_fit_one_chip(flagship_lm,
             sds((bucket,), jnp.int32), sds((), jnp.int32)).compile()
         assert "tpu_custom_call" in compiled.as_text()
     assert _hbm_bytes(compiled) < HBM_BYTES, compiled.memory_analysis()
+
+
+def test_lm_evaluation_program_returns_a_scalar_and_fits_one_chip(
+        flagship_lm, monkeypatch):
+    """The evaluation's one program (``_eval_count_step``) at flagship
+    width over four windows: the flash kernel is in it, and what it
+    hands back is the count, four bytes, where the logits it reduces
+    are 4 x 2048 x 32768 float32 = 1.07 GB of temporaries."""
+    model, params = flagship_lm
+    rep = replicated(model.mesh)
+    s = model._dims()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    windows = jax.ShapeDtypeStruct((4, s["t"]), jnp.int32, sharding=rep)
+    compiled = model._eval_count_step(4).lower(
+        params, windows, windows).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    (out,) = jax.tree.leaves(compiled.out_info)
+    assert out.shape == () and out.dtype == jnp.int32
+    # (the device pads the scalar to one 512-byte tile)
+    assert compiled.memory_analysis().output_size_in_bytes <= 512
+    assert _hbm_bytes(compiled) < HBM_BYTES, compiled.memory_analysis()

@@ -628,7 +628,7 @@ def test_admin_trace_route_and_metrics(tmp_path):
             "trial", "propose", "open", "init", "train", "load",
             "stage", "step_setup", "step_dispatch", "step_wait", "eval",
             "dump", "feedback", "handover", "persist"}
-        assert set(tp["caches"]) == {"dataset", "stage"}
+        assert set(tp["caches"]) == {"dataset", "stage", "step"}
         assert "resident" in tp and "enabled" in tp
         assert requests.get(base + "/trial_phases",
                             timeout=10).status_code == 401

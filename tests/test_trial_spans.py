@@ -86,6 +86,7 @@ def traced_run(tmp_path_factory):
     options.host_tracer_level = 1
     trace.configure(str(tmp / "logs"))
     before = phases.phase_totals()
+    steps_before = phases.cache_counts("step")
     jax.profiler.start_trace(str(tmp / "trace"), profiler_options=options)
     try:
         rows = runner.run()
@@ -94,6 +95,7 @@ def traced_run(tmp_path_factory):
         jax.profiler.stop_trace()
         trace.configure(None)
     grown = _grown(before, phases.phase_totals())
+    steps = phases.cache_counts("step")
     meta.close()
     params.close()
     (path,) = glob.glob(str(tmp / "trace" / "**" / "*.xplane.pb"),
@@ -109,6 +111,8 @@ def traced_run(tmp_path_factory):
                                    e.start_ns, e.start_ns + e.duration_ns,
                                    dict(e.stats), index))
     return {"rows": rows, "events": events, "grown": grown,
+            "step_lookups": {e: steps.get(e, 0) - steps_before.get(e, 0)
+                             for e in ("hit", "miss")},
             "log_dir": str(tmp / "logs")}
 
 
@@ -161,6 +165,14 @@ def test_phase_totals_hold_every_phase_and_count_dispatches(traced_run):
     # ... and little of a trial lies outside them
     assert grown["trial"]["sum"] - children < 0.05 * grown["trial"]["sum"]
     assert grown["train"]["sum"] >= sum(grown[n]["sum"] for n in OF_TRAIN)
+
+
+def test_step_cache_counts_two_lookups_a_trial(traced_run):
+    """A job of N congruent trials (TinyLM is this file's own class, so
+    nothing had its programs before): the train chunk and the
+    evaluation's program are built in the first trial and found by
+    every later one — 2 misses, 2N - 2 hits."""
+    assert traced_run["step_lookups"] == {"miss": 2, "hit": 2}
 
 
 def test_span_store_holds_the_per_trial_phases_under_the_trial_id(
