@@ -422,6 +422,18 @@ class Admin:
         if not best:
             raise ValueError(
                 f"train job {train_job_id} has no completed trials")
+        from ..observe import lm as obs_lm
+
+        if obs_lm.generate_enabled():
+            # A model class that says it cannot generate fails the
+            # deploy here, with its reason, not in a worker thread.
+            for model_id in {trial["model_id"] for trial in best}:
+                row = self.meta.get_model(model_id)
+                refusal = getattr(load_model_class(
+                    row["model_class"], row.get("model_source")),
+                    "GENERATE_REFUSAL", None)
+                if refusal:
+                    raise ValueError(refusal)
         inf = self.meta.create_inference_job(user_id, train_job_id,
                                              InferenceJobStatus.STARTED)
         try:
@@ -842,7 +854,7 @@ class Admin:
                   for c in obs_phases.CACHES}
         return {"enabled": obs_metrics.metrics_enabled(),
                 "resident": resident, "phases": phases,
-                "caches": caches}
+                "caches": caches, "moe": obs_phases.moe_counts()}
 
     def get_autoscale(self) -> Dict[str, Any]:
         """The autoscaler's decision ring + per-bin targets (the

@@ -50,7 +50,7 @@ PREFIX = "rafiki_tpu_"
 
 SUBSYSTEMS = {"bus", "serving", "http", "train", "trial", "trace",
               "node", "fault", "autoscale", "profile", "slo",
-              "workload", "capacity", "lm", "relay"}
+              "workload", "capacity", "lm", "relay", "moe"}
 
 # _total marks counters (Prometheus convention); everything else is the
 # physical unit of a gauge/histogram. "rate" is the SLO plane's burn

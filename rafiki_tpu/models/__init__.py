@@ -5,6 +5,7 @@ from .densenet import JaxDenseNet
 from .enas import JaxEnas
 from .feedforward import JaxFeedForward
 from .lm import JaxTransformerLM
+from .lm_moe import JaxLatentMoELM
 from .pos_tagger import JaxPosTagger
 from .sk import SkDt, SkSvm
 from .tabular import JaxTabMlpClf, JaxTabMlpReg
@@ -13,4 +14,5 @@ from .vit import JaxViT
 
 __all__ = ["JaxFeedForward", "JaxCnn", "JaxDenseNet", "JaxEnas", "JaxViT",
            "JaxPosTagger", "SkDt", "SkSvm", "JaxTabMlpClf",
-           "JaxTabMlpReg", "JaxTransformerTagger", "JaxTransformerLM"]
+           "JaxTabMlpReg", "JaxTransformerTagger", "JaxTransformerLM",
+           "JaxLatentMoELM"]

@@ -147,6 +147,17 @@ def _lm_block(x, lp, h_heads, mesh):
     return x + (h @ lp["w2"].astype(jnp.bfloat16)).astype(x.dtype)
 
 
+def _remat(body, remat):
+    """``body`` under the ``remat`` knob's backward-pass memory policy."""
+    if remat == "full":
+        return jax.checkpoint(body)
+    if remat == "dots":
+        return jax.checkpoint(
+            body, policy=jax.checkpoint_policies
+            .dots_with_no_batch_dims_saveable)
+    return body
+
+
 def _lm_forward(params, ids, s, remat, mesh):
     """Logits (float32) of ``ids`` under ``params``: a function of its
     arguments alone (``s`` is ``_dims()``), so a program built on it
@@ -160,13 +171,8 @@ def _lm_forward(params, ids, s, remat, mesh):
     pos = _sinusoidal(s["t"], s["d"])
     x = x + jnp.asarray(pos)[None, :ids.shape[1]].astype(x.dtype)
 
-    body = functools.partial(_lm_block, h_heads=s["h"], mesh=mesh)
-    if remat == "full":
-        body = jax.checkpoint(body)
-    elif remat == "dots":
-        body = jax.checkpoint(
-            body, policy=jax.checkpoint_policies
-            .dots_with_no_batch_dims_saveable)
+    body = _remat(functools.partial(_lm_block, h_heads=s["h"], mesh=mesh),
+                  remat)
 
     def scan_body(x, lp):
         return body(x, lp), None
@@ -178,8 +184,58 @@ def _lm_forward(params, ids, s, remat, mesh):
             ).astype(jnp.float32)
 
 
+def _lm_loss(weights, state, win, s, remat, mesh):
+    """Next-token loss of one (B, t+1) window batch; input and target
+    are shifted views. Returns ``(loss, (accuracy, counts, state))`` as
+    every ``_loss_fn`` of this trainer does: ``counts`` is a vector the
+    dispatch sums and hands the host beside loss and accuracy, ``state``
+    what the step carries on without a gradient. The dense block has
+    neither."""
+    del state
+    logits = _lm_forward(weights, win[:, :-1], s, remat, mesh)
+    loss = optax.softmax_cross_entropy_with_integer_labels(
+        logits, win[:, 1:]).mean()
+    acc = (logits.argmax(-1) == win[:, 1:]).mean()
+    return loss, (acc, None, None)
+
+
+#: The top-level entry of a parameter tree that the optimizer never
+#: sees: carried through the step, replaced by what the loss returns.
+STATE = "state"
+
+
+def _weights(params):
+    """``params`` without its ``STATE`` entry: what takes gradients."""
+    return {k: v for k, v in params.items() if k != STATE}
+
+
+def _flat_names(tree, prefix=""):
+    """A nested dict of arrays as {"a/b": leaf}, in the dict's order."""
+    out = {}
+    for key, value in tree.items():
+        name = f"{prefix}{key}"
+        if isinstance(value, dict):
+            out.update(_flat_names(value, name + "/"))
+        else:
+            out[name] = value
+    return out
+
+
 class JaxTransformerLM(BaseModel):
-    """Decoder-only causal transformer LM on the flash kernels."""
+    """Decoder-only causal transformer LM on the flash kernels.
+
+    The trainer (``_train_setup`` / ``train`` / ``evaluate`` /
+    ``predict`` / ``dump_parameters``) knows no block: a subclass with
+    another architecture names its own module-level ``_forward_fn`` and
+    ``_loss_fn`` (functions of (params, ids | window, dims, remat,
+    mesh)) and overrides ``_dims``, ``_init_params`` and
+    ``_flops_per_step`` (``models/lm_moe.py``)."""
+
+    _forward_fn = staticmethod(_lm_forward)
+    _loss_fn = staticmethod(_lm_loss)
+    #: Why a deploy with generative serving on must refuse this class
+    #: (``Admin.create_inference_job``); None: ``make_generator`` works.
+    GENERATE_REFUSAL: Optional[str] = None
 
     @staticmethod
     def get_knob_config():
@@ -256,7 +312,11 @@ class JaxTransformerLM(BaseModel):
         return self._dims(), str(self.knobs.get("remat", "dots")), self.mesh
 
     def _forward(self, params, ids):
-        return _lm_forward(params, ids, *self._forward_spec())
+        return self._forward_fn(params, ids, *self._forward_spec())
+
+    def _count_dispatch(self, counts) -> None:
+        """What ``_loss_fn``'s counts, summed over one dispatch, tell
+        the host's metrics. The dense block counts nothing."""
 
     def _flops_per_step(self, b: int) -> float:
         """Analytic train-step FLOPs (fwd+bwd): 6·N·tokens for matmul
@@ -322,50 +382,47 @@ class JaxTransformerLM(BaseModel):
             # the mesh: its zeros depend on no input, so without
             # out_shardings jit would place 3.8 GB on the default device.
             init_opt = jax.jit(tx.init, out_shardings=replicated(mesh))
-        opt_state = init_opt(params)
-
-        # Windows are cut on the HOST and shipped per dispatch:
-        # (K, B, t+1) int32 is ~¼ MB at flagship shape — negligible
-        # next to the step's compute — whereas gathering the windows
-        # in-graph from a device-resident stream lowers to a scalar
-        # gather that runs ~35× slower than the whole train step on
-        # TPU (measured: 8.1 s/step vs 0.23). The image zoo's
-        # device-resident staging exists to avoid shipping megabytes of
-        # pixels; a token stream has no such problem.
-        x_shard = batch_sharding(mesh)
-        forward = self._forward
-
-        if cached is None:
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
-            def train_chunk(params, opt_state, wins):
-                def one(carry, win):
-                    params, opt_state = carry
-                    # win (B, t+1): input/target are shifted views.
-                    win = jax.lax.with_sharding_constraint(win, x_shard)
-
-                    def loss_fn(p):
-                        logits = forward(p, win[:, :-1])
-                        loss = \
-                            optax.softmax_cross_entropy_with_integer_labels(
-                                logits, win[:, 1:]).mean()
-                        acc = (logits.argmax(-1) == win[:, 1:]).mean()
-                        return loss, acc
-
-                    (loss, acc), grads = jax.value_and_grad(
-                        loss_fn, has_aux=True)(params)
-                    updates, opt_state = tx.update(grads, opt_state,
-                                                   params)
-                    return (optax.apply_updates(params, updates),
-                            opt_state), (loss, acc)
-
-                (params, opt_state), (losses, accs) = jax.lax.scan(
-                    one, (params, opt_state), wins)
-                return params, opt_state, jnp.stack(
-                    [losses.mean(), accs.mean()])
-
+            train_chunk = self._make_train_chunk(tx)
             _step_cache_put(cache_key, {"tx": tx, "step": train_chunk,
                                         "init_opt": init_opt})
+        opt_state = init_opt(_weights(params))
         return ds, steps, b, k_disp, train_chunk, params, opt_state
+
+    def _make_train_chunk(self, tx):
+        """The jitted train program: ``(params, opt_state, wins (K, B,
+        t+1)) -> (params, opt_state, [mean loss, mean accuracy, summed
+        counts...])``, K optimizer steps of ``_loss_fn`` under ``tx``,
+        the carry donated. It closes over the forward's spec (dims,
+        remat, mesh), never over the instance."""
+        s, remat, mesh = self._forward_spec()
+        x_shard = batch_sharding(mesh)
+        loss_fn = functools.partial(self._loss_fn, s=s, remat=remat,
+                                    mesh=mesh)
+
+        @functools.partial(jax.jit, donate_argnums=(0, 1))
+        def train_chunk(params, opt_state, wins):
+            def one(carry, win):
+                params, opt_state = carry
+                # win (B, t+1): input/target are shifted views.
+                win = jax.lax.with_sharding_constraint(win, x_shard)
+                weights = _weights(params)
+                (loss, (acc, counts, state)), grads = \
+                    jax.value_and_grad(loss_fn, has_aux=True)(
+                        weights, params.get(STATE), win)
+                updates, opt_state = tx.update(grads, opt_state, weights)
+                params = optax.apply_updates(weights, updates)
+                if state is not None:
+                    params[STATE] = state
+                return (params, opt_state), (loss, acc, counts)
+
+            (params, opt_state), (losses, accs, counts) = jax.lax.scan(
+                one, (params, opt_state), wins)
+            metrics = jnp.stack([losses.mean(), accs.mean()])
+            if counts is not None:
+                metrics = jnp.concatenate([metrics, counts.sum(0)])
+            return params, opt_state, metrics
+
+        return train_chunk
 
     def train(self, dataset_path: str, **kwargs: Any) -> None:
         with _phases.span("step_setup"):
@@ -379,6 +436,14 @@ class JaxTransformerLM(BaseModel):
         hi = max(1, ds.size - (t + 1))
         done = 0
         first_dispatch = True
+        # Windows are cut on the HOST and shipped per dispatch:
+        # (K, B, t+1) int32 is ~¼ MB at flagship shape — negligible
+        # next to the step's compute — whereas gathering the windows
+        # in-graph from a device-resident stream lowers to a scalar
+        # gather that runs ~35× slower than the whole train step on
+        # TPU (measured: 8.1 s/step vs 0.23). The image zoo's
+        # device-resident staging exists to avoid shipping megabytes of
+        # pixels; a token stream has no such problem.
         while done < steps:
             k = min(k_disp, steps - done)
             with _phases.span("step_dispatch"):
@@ -397,6 +462,7 @@ class JaxTransformerLM(BaseModel):
             # zero steps credited (~4% systematic under-report).
             with _phases.span("step_wait"):
                 loss_acc = np.asarray(metrics)
+            self._count_dispatch(loss_acc[2:])
             meter.tick(k)
             if first_dispatch or k != k_disp:
                 # Dispatches that paid an XLA compile (first chunk, tail
@@ -435,6 +501,7 @@ class JaxTransformerLM(BaseModel):
         cached = _step_cache_get(key)
         if cached is not None:
             return cached["step"]
+        forward = self._forward_fn
 
         @jax.jit
         def eval_count(params, inputs, targets):
@@ -447,7 +514,7 @@ class JaxTransformerLM(BaseModel):
             # (179 of 8192, chip run, PR 28). It costs the 1.65 GB of
             # logits one trip through HBM, a few milliseconds.
             logits = jax.lax.optimization_barrier(
-                _lm_forward(params, inputs, s, remat, mesh))
+                forward(params, inputs, s, remat, mesh))
             return (logits.argmax(-1) == targets).sum(dtype=jnp.int32)
 
         _step_cache_put(key, {"step": eval_count})
@@ -554,25 +621,24 @@ class JaxTransformerLM(BaseModel):
         return self._predict_fn
 
     def dump_parameters(self) -> Params:
+        """The parameter tree, nested to any depth, under flat
+        ``a/b`` names (``layers/qkv``)."""
         assert self._params is not None
-        out: Params = {}
-        out["embed"] = np.asarray(self._params["embed"])
-        out["lnf"] = np.asarray(self._params["lnf"])
-        for kk, vv in self._params["layers"].items():
-            out[f"layers/{kk}"] = np.asarray(vv)
-        return out
+        return {name: np.asarray(leaf)
+                for name, leaf in _flat_names(self._params).items()}
 
     def load_parameters(self, params: Params) -> None:
         # Straight onto this model's chip group (never via the default
         # device: four one-chip replicas would all stage through chip 0).
-        put = functools.partial(jax.device_put,
-                                device=replicated(self.mesh))
-        layers = {kk.split("/", 1)[1]: put(vv)
-                  for kk, vv in params.items()
-                  if kk.startswith("layers/")}
-        self._params = {"embed": put(params["embed"]),
-                        "lnf": put(params["lnf"]),
-                        "layers": layers}
+        rep = replicated(self.mesh)
+        tree: Dict[str, Any] = {}
+        for name, value in params.items():
+            *parents, leaf = name.split("/")
+            node = tree
+            for key in parents:
+                node = node.setdefault(key, {})
+            node[leaf] = jax.device_put(value, rep)
+        self._params = tree
         self._invalidate_compiled()
 
     def _invalidate_compiled(self) -> None:

@@ -28,6 +28,15 @@ serving stage histogram did for the frontend:
   class. A job of congruent trials shows its misses staying flat after
   the first trial; a miss per trial is a program built (and, where its
   constants differ, compiled) per trial.
+- ``rafiki_tpu_moe_assignments_total{where=held|absent}`` and
+  ``rafiki_tpu_moe_busiest_expert_total`` — what a sparse-expert
+  model's train steps routed (``moe_routed``, once a dispatch, from the
+  sums the step returns beside loss and accuracy): token-to-expert
+  assignments to experts this rank holds and computes, to experts it
+  does not, and the busiest held expert's assignments summed over
+  steps and sparse blocks. held + absent = tokens x experts a token x
+  sparse blocks, exactly; busiest x experts held / held = the held
+  experts' load imbalance.
 - ``rafiki_tpu_trial_dataset_cache_bytes`` /
   ``rafiki_tpu_trial_stage_cache_bytes`` — current cache occupancy
   against the ``RAFIKI_TPU_DATASET_CACHE_BYTES`` /
@@ -107,6 +116,16 @@ def _reg() -> Dict[str, object]:
             "step_cache": r.counter(
                 "rafiki_tpu_trial_step_cache_total",
                 "Compiled-step cache lookups (event=hit|miss)"),
+            "moe_assignments": r.counter(
+                "rafiki_tpu_moe_assignments_total",
+                "Token-to-expert assignments a sparse-expert model's "
+                "train steps routed (where=held: to an expert this "
+                "rank holds and computes; absent: to one it does not)"),
+            "moe_busiest": r.counter(
+                "rafiki_tpu_moe_busiest_expert_total",
+                "Sum over train steps and sparse blocks of the busiest "
+                "held expert's assignments (x experts held / held "
+                "assignments = load imbalance)"),
             "dataset_cache_bytes": r.gauge(
                 "rafiki_tpu_trial_dataset_cache_bytes",
                 "Bytes held by the host dataset cache"),
@@ -200,6 +219,25 @@ def cache_counts(cache: str) -> Dict[str, int]:
     zero-disk-load / zero-H2D regression check reads."""
     m = _reg()[f"{cache}_cache"]
     return {labels.get("event", ""): int(v) for labels, v in m.samples()}
+
+
+def moe_routed(held: float, absent: float, busiest: float) -> None:
+    """One train dispatch's sums of a sparse-expert model."""
+    if metrics.metrics_enabled():
+        m = _reg()
+        m["moe_assignments"].inc(held, where="held")
+        m["moe_assignments"].inc(absent, where="absent")
+        m["moe_busiest"].inc(busiest)
+
+
+def moe_counts() -> Dict[str, int]:
+    """{"held", "absent", "busiest"}: this process's cumulative totals."""
+    m = _reg()
+    out = {"held": 0, "absent": 0,
+           "busiest": int(sum(v for _, v in m["moe_busiest"].samples()))}
+    for labels, value in m["moe_assignments"].samples():
+        out[labels.get("where", "")] = int(value)
+    return out
 
 
 def phase_totals() -> Dict[str, Dict[str, float]]:

@@ -13,13 +13,14 @@ from .attention import (batch_sharded_flash_attention,
                         flash_attention,
                         naive_attention, ring_attention,
                         sequence_sharded_attention, ulysses_attention)
-from .moe import switch_moe
+from .moe import held_experts_swiglu, sigmoid_topk_gates, switch_moe
 from .pipeline import pipeline_apply, pipelined
 
 __all__ = [
     "batch_sharded_flash_attention", "blockwise_attention",
-    "default_attention", "flash_attention",
+    "default_attention", "flash_attention", "held_experts_swiglu",
     "naive_attention",
     "pipeline_apply", "pipelined", "ring_attention",
-    "sequence_sharded_attention", "switch_moe", "ulysses_attention",
+    "sequence_sharded_attention", "sigmoid_topk_gates", "switch_moe",
+    "ulysses_attention",
 ]

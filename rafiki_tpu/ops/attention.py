@@ -613,7 +613,8 @@ def flash_attention(q, k, v, *, causal: bool = False, block_q: int = 1024,
 
 def batch_sharded_flash_attention(q, k, v, mesh, *, causal: bool = False,
                                   kv_mask=None,
-                                  interpret: Optional[bool] = None):
+                                  interpret: Optional[bool] = None,
+                                  **blocks: int):
     """:func:`flash_attention` under a jit whose ``mesh`` spans chips.
 
     Mosaic kernels are never partitioned automatically: called bare
@@ -623,17 +624,18 @@ def batch_sharded_flash_attention(q, k, v, mesh, *, causal: bool = False,
     axis); every other axis sees q/k/v replicated. A batch ``dp`` does
     not divide (a lone serving query on a chip group) is attended whole
     on every device. ``mesh=None`` or a one-device mesh is the bare
-    call.
+    call. ``blocks`` (``block_q``, ``block_kv``) pass through to the
+    kernels; without them their defaults hold.
     """
     if mesh is None or mesh.size == 1:
         return flash_attention(q, k, v, causal=causal, kv_mask=kv_mask,
-                               interpret=interpret)
+                               interpret=interpret, **blocks)
     spec = P(DP_AXIS if q.shape[0] % mesh.shape[DP_AXIS] == 0 else None)
     args = (q, k, v) if kv_mask is None else (q, k, v, kv_mask)
 
     def run(q_, k_, v_, mask_=None):
         return flash_attention(q_, k_, v_, causal=causal, kv_mask=mask_,
-                               interpret=interpret)
+                               interpret=interpret, **blocks)
 
     return shard_map(run, mesh=mesh, in_specs=(spec,) * len(args),
                      out_specs=spec, check_vma=False)(*args)

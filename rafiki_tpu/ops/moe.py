@@ -144,3 +144,181 @@ def switch_moe(x, gate_w, w1, b1, w2, b2, *,
     if expert_axis is not None:
         out = jax.lax.psum(out, expert_axis)
     return out.reshape(n_groups * g, d)[:n], aux.mean()
+
+
+# ---------------------------------------------------------------------------
+# Dropless top-k experts, of which this rank holds a share
+# ---------------------------------------------------------------------------
+#
+# The DeepSeek-V3 family's expert layer (sigmoid scores, bias-corrected
+# top-k selection, normalised and scaled gates, no capacity) for a rank
+# that is TOLD which experts it holds: selection and the gates'
+# normalisation run over all E experts, the products only over the
+# held ones, and what the absent experts would have added is left out.
+# On one chip the layer runs without its exchange; nothing here stands
+# in for the other ranks.
+
+
+def sigmoid_topk_gates(x, gate_w, bias, *, k: int, scale: float):
+    """Router of a dropless top-k layer, float32 throughout.
+
+    ``x`` (N, D), ``gate_w`` (D, E), ``bias`` (E,). Scores are
+    ``sigmoid(x @ gate_w)``; the ``k`` experts of a token are the top-k
+    of ``scores + bias`` (the bias steers selection only and takes no
+    gradient: ``noaux_tc``); its gates are the chosen experts' scores
+    WITHOUT the bias, normalised to sum 1 and multiplied by ``scale``.
+
+    Returns ``(gates, chosen)``, both (N, E) and dense over ALL experts:
+    ``gates`` is zero off a token's chosen experts. A rank slices its
+    held columns out; no index is gathered (a per-element gather is the
+    slow path on a TPU).
+    """
+    e = gate_w.shape[1]
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), gate_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
+    chosen = (idx[..., None] == jnp.arange(e, dtype=idx.dtype)).any(-2)
+    picked = jnp.where(chosen, scores, 0.0)
+    gates = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
+    return gates, chosen
+
+
+#: Rows of one block of sorted assignments, each of one expert
+#: (``benchmarks/metrics/moe_expert_roofline.py`` finds the blocks by it).
+BLOCK = 128
+
+
+def _held_layout(chosen, gates):
+    """Sort the (token, held expert) assignments by expert, payloads
+    carried by the sort itself. Returns the sorted token ids and gates
+    (each padded by one block, so a block may be sliced at any start),
+    the sorted flat assignment ids (to un-sort a cotangent), and per
+    held expert its count, first sorted row and last block (cumulative).
+    """
+    n, h = chosen.shape
+    a = n * h
+    key = jnp.where(chosen, jnp.arange(h, dtype=jnp.int32), h).reshape(a)
+    flat = jnp.arange(a, dtype=jnp.int32)
+    _, flat_s, gate_s = jax.lax.sort(
+        (key, flat, gates.astype(jnp.float32).reshape(a)), num_keys=1,
+        is_stable=True)
+    counts = chosen.sum(0, dtype=jnp.int32)
+    starts = jnp.cumsum(counts) - counts
+    blk_end = jnp.cumsum(-(-counts // BLOCK))
+    return (jnp.pad(flat_s // h, (0, BLOCK)), jnp.pad(gate_s, (0, BLOCK)),
+            flat_s, counts, starts, blk_end)
+
+
+def _held_block(b, layout):
+    """Block ``b`` of the sorted assignments: (held expert, token rows,
+    gates with the rows past the expert's last zeroed, first row)."""
+    tok_s, gate_s, _, counts, starts, blk_end = layout
+    e = jnp.sum(b >= blk_end).astype(jnp.int32)
+    e = jnp.minimum(e, counts.shape[0] - 1)
+    j = b - (blk_end[e] - -(-counts[e] // BLOCK))
+    start = starts[e] + j * BLOCK
+    live = jnp.arange(BLOCK, dtype=jnp.int32) < counts[e] - j * BLOCK
+    rows = jax.lax.dynamic_slice(tok_s, (start,), (BLOCK,))
+    gate = jnp.where(live, jax.lax.dynamic_slice(gate_s, (start,),
+                                                 (BLOCK,)), 0.0)
+    return e, rows, gate, live, start
+
+
+def _expert_hidden(xb, wg, wu):
+    """SwiGLU's hidden of one block under one expert, float32 out."""
+    hg = jnp.dot(xb, wg, preferred_element_type=jnp.float32)
+    hu = jnp.dot(xb, wu, preferred_element_type=jnp.float32)
+    return hg, hu, jax.nn.silu(hg) * hu
+
+
+@jax.custom_vjp
+def held_experts_swiglu(x, gates, chosen, w_gate, w_up, w_down):
+    """Σ over the HELD experts a token chose of gate · SwiGLU_e(x).
+
+    ``x`` (N, D) in the compute type (bfloat16); ``gates`` (N, H)
+    float32 and ``chosen`` (N, H) bool, the held columns of
+    :func:`sigmoid_topk_gates`; ``w_gate``, ``w_up`` (H, D, F) and
+    ``w_down`` (H, F, D) float32 masters (the products run on operands
+    of ``x``'s type, accumulated in float32). Returns (N, D) float32.
+    No token is dropped.
+
+    The assignments are sorted by expert and cut into blocks of
+    ``BLOCK`` rows, each of one expert; a ``while_loop`` runs exactly
+    the blocks that hold a routed row (gather the rows, three products,
+    scatter-add with the gates), so the WORK follows the rows routed
+    here and not tokens x experts held, while every shape stays static:
+    the only arrays sized for the worst case (every token choosing
+    every held expert) are the int32 / float32 sort buffers of N·H
+    elements. The backward pass is a second such loop that recomputes
+    each block's hidden (nothing is saved per block).
+    """
+    return _held_fwd(x, gates, chosen, w_gate, w_up, w_down)[0]
+
+
+def _held_fwd(x, gates, chosen, w_gate, w_up, w_down):
+    layout = _held_layout(chosen, gates)
+    wg, wu, wd = (w.astype(x.dtype) for w in (w_gate, w_up, w_down))
+
+    def body(carry):
+        b, out = carry
+        e, rows, gate, _, _ = _held_block(b, layout)
+        _, _, hid = _expert_hidden(x[rows], wg[e], wu[e])
+        y = jnp.dot(hid.astype(x.dtype), wd[e],
+                    preferred_element_type=jnp.float32)
+        return b + 1, out.at[rows].add(y * gate[:, None])
+
+    n_blocks = layout[-1][-1]
+    _, out = jax.lax.while_loop(
+        lambda c: c[0] < n_blocks, body,
+        (jnp.int32(0), jnp.zeros(x.shape, jnp.float32)))
+    return out, (x, gates, chosen, w_gate, w_up, w_down)
+
+
+def _held_bwd(res, dout):
+    x, gates, chosen, w_gate, w_up, w_down = res
+    layout = _held_layout(chosen, gates)
+    wg, wu, wd = (w.astype(x.dtype) for w in (w_gate, w_up, w_down))
+    a = gates.size
+
+    def body(carry):
+        b, dx, dgate_s, dwg, dwu, dwd = carry
+        e, rows, gate, live, start = _held_block(b, layout)
+        xb = x[rows]
+        hg, hu, hid = _expert_hidden(xb, wg[e], wu[e])
+        dy = dout[rows]
+        dhid0 = jnp.dot(dy.astype(x.dtype), wd[e].T,
+                        preferred_element_type=jnp.float32)
+        dgate_s = jax.lax.dynamic_update_slice(
+            dgate_s, jnp.where(live, (dhid0 * hid).sum(-1), 0.0), (start,))
+        dwd = dwd.at[e].add(jnp.dot(
+            hid.astype(x.dtype).T, (dy * gate[:, None]).astype(x.dtype),
+            preferred_element_type=jnp.float32))
+        dhid = dhid0 * gate[:, None]
+        sig = jax.nn.sigmoid(hg)
+        dhg = (dhid * hu * sig * (1.0 + hg * (1.0 - sig))).astype(x.dtype)
+        dhu = (dhid * hg * sig).astype(x.dtype)
+        dwg = dwg.at[e].add(jnp.dot(xb.T, dhg,
+                                    preferred_element_type=jnp.float32))
+        dwu = dwu.at[e].add(jnp.dot(xb.T, dhu,
+                                    preferred_element_type=jnp.float32))
+        dxb = jnp.dot(dhg, wg[e].T, preferred_element_type=jnp.float32) \
+            + jnp.dot(dhu, wu[e].T, preferred_element_type=jnp.float32)
+        return b + 1, dx.at[rows].add(dxb), dgate_s, dwg, dwu, dwd
+
+    n_blocks = layout[-1][-1]
+    _, dx, dgate_s, dwg, dwu, dwd = jax.lax.while_loop(
+        lambda c: c[0] < n_blocks, body,
+        (jnp.int32(0), jnp.zeros(x.shape, jnp.float32),
+         jnp.zeros((a + BLOCK,), jnp.float32),
+         jnp.zeros(w_gate.shape, jnp.float32),
+         jnp.zeros(w_up.shape, jnp.float32),
+         jnp.zeros(w_down.shape, jnp.float32)))
+    # Back to (token, held expert) order by sorting on the flat ids the
+    # forward sort carried along.
+    _, dgates = jax.lax.sort((layout[2], dgate_s[:a]), num_keys=1)
+    return (dx.astype(x.dtype), dgates.reshape(gates.shape), None,
+            dwg, dwu, dwd)
+
+
+held_experts_swiglu.defvjp(_held_fwd, _held_bwd)

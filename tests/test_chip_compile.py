@@ -239,3 +239,57 @@ def test_lm_evaluation_program_returns_a_scalar_and_fits_one_chip(
     # (the device pads the scalar to one 512-byte tile)
     assert compiled.memory_analysis().output_size_in_bytes <= 512
     assert _hbm_bytes(compiled) < HBM_BYTES, compiled.memory_analysis()
+
+
+def test_joyai_cell_step_compiles_and_fits_one_chip(topo, monkeypatch):
+    """The train program of the benchmark's ``joyai-flash-final`` cell
+    (JaxLatentMoELM at JoyAI-LLM-Flash's widths, 8 of 256 experts, 5 + 1
+    blocks, 1 x 8192 tokens, 8 steps a dispatch, remat dots), lowered
+    from shapes through the trainer's own ``_make_train_chunk``: the
+    three flash kernels are in it by name (the dkv kernel at 256 padded
+    lanes needs the halved kv block: 1024 x 1024 overflows the scoped
+    VMEM), and arguments + temporaries stay under the 14.5 GB that
+    leave a job room for the previous trial's parameters."""
+    import json
+    import os
+    import re
+
+    import optax
+
+    from rafiki_tpu.models import JaxLatentMoELM
+    from rafiki_tpu.models.lm import _weights
+    from rafiki_tpu.models.lm_moe import _jitted_moe_init
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "joyai-llm-flash-L5-E8.json")) as f:
+        config = json.load(f)
+    knobs = {knob: config[key] for knob, key in config["knob_of"].items()}
+    knobs.update(config["knobs"])
+    model = JaxLatentMoELM(**knobs)
+    mesh = build_mesh(topo.devices[:1])
+    model._mesh = mesh
+    rep = replicated(mesh)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=rep), tree)
+
+    s = model._dims()
+    params = on_chip(jax.eval_shape(
+        _jitted_moe_init(tuple(sorted(s.items())), mesh),
+        jax.ShapeDtypeStruct((), jnp.int32)))
+    assert sum(a.size for a in jax.tree.leaves(params)) > 490e6
+    tx = optax.adamw(2.2e-4)
+    opt_state = on_chip(jax.eval_shape(tx.init, _weights(params)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    wins = jax.ShapeDtypeStruct((8, 1, s["t"] + 1), jnp.int32,
+                                sharding=rep)
+    compiled = model._make_train_chunk(tx).lower(
+        params, opt_state, wins).compile()
+    named = re.findall(r'kernel_metadata=\{\s*"kernel":"(\w+)"',
+                       compiled.as_text())
+    assert set(named) == {"flash_fwd", "flash_dq", "flash_dkv"}
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes > 5.8e9  # params + Adam, resident
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 14.5e9, m

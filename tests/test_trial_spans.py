@@ -346,20 +346,24 @@ def test_reader_gives_none_where_there_is_nothing_to_read(load_reader,
                      window={"trials": 0, "seconds": 64.0})) is None
 
 
-def test_benchmark_lists_each_reader_twice():
+def test_benchmark_lists_each_reader_for_its_cells():
+    """Bare for ``lm14-final``, ``search.`` for ``lm14-search``, ``moe.``
+    for the sparse-expert cell (PR 29)."""
     import json
 
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    cells = {"": "lm14-final", "search.": "lm14-search",
+             "moe.": "joyai-flash-final"}
     mine = [m for m in bench["per_layer"]
             if m["name"].rsplit(".", 1)[-1] in READERS]
     assert sorted(m["name"] for m in mine) == sorted(
-        [n for n in READERS] + [f"search.{n}" for n in READERS])
+        [f"{prefix}{n}" for prefix in cells for n in READERS])
     for m in mine:
-        search = m["name"].startswith("search.")
-        assert m["moves"] == ("search." if search else "") \
+        prefix = m["name"][:m["name"].rfind(".") + 1]
+        assert m["moves"] == ("search." if prefix == "search." else "") \
             + "trials_per_hour"
-        assert m["workloads"] == ["lm14-search" if search else "lm14-final"]
+        assert m["workloads"] == [cells[prefix]]
         assert (m["unit"], m["better"], m["source"]) == \
             ("ms", "lower", "program_counter")
 
