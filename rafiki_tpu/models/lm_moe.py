@@ -173,9 +173,6 @@ def _mla(u, p, s, mesh):
     q = jnp.concatenate([q[..., :nope], q_r], -1)
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_r, (b, t, h, rope))], -1)
-    # The flash kernels take one head_dim: v rides zero-padded to q's
-    # width (they pad 192 to 256 lanes anyway and scale by q's width).
-    v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, nope + rope - vd)))
     # Past 128 lanes of (padded) head the dkv kernel's 1024 x 1024
     # blocks overflow the 16 MiB of scoped VMEM (18.8 MiB at 256 lanes:
     # the chip's compiler refuses it), so the kv block is halved.
@@ -183,7 +180,7 @@ def _mla(u, p, s, mesh):
     o = batch_sharded_flash_attention(
         *(a.transpose(0, 2, 1, 3) for a in (q, k, v)), mesh, causal=True,
         **blocks)
-    o = o[..., :vd].transpose(0, 2, 1, 3).reshape(b, t, h * vd)
+    o = o.transpose(0, 2, 1, 3).reshape(b, t, h * vd)
     return _mm(o, p["o"])
 
 

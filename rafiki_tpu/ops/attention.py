@@ -27,7 +27,9 @@ Three tiers, one numerical scheme (the online-softmax merge):
   locally per head subset, a second ``all_to_all`` restores sequence
   sharding (needs ``heads % sp == 0``).
 
-All take ``(batch, heads, seq, head_dim)`` arrays.
+All take ``(batch, heads, seq, head_dim)`` arrays. ``naive_attention``
+and ``flash_attention`` also take a ``v`` of another width than q and k
+(latent attention: 192 / 128) and return v's width.
 """
 
 from __future__ import annotations
@@ -231,19 +233,28 @@ def _flash_kernel(*refs, scale, causal, block_q, block_kv, seq_q, seq_kv,
     @pl.when(j == nk - 1)
     def _():
         l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
+        acc = acc_scr[:]
+        if acc.shape[1] != o_ref.shape[2]:  # v narrower than q
+            acc = acc[:, :o_ref.shape[2]]
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
         lse_ref[0] = jnp.broadcast_to(m_scr[:, :1] + jnp.log(l),
                                       lse_ref.shape[1:])
 
 
 
-def _flash_blocking(q, k, bias, block_q, block_kv):
+def _flash_blocking(q, k, v, bias, block_q, block_kv):
     """The ONE block-clamping computation the forward and backward
     kernels must agree on: the saved lse residual's layout is
     ``nq * block_q`` as computed HERE, so a divergent copy in the
-    backward would misalign its BlockSpecs against the saved array."""
+    backward would misalign its BlockSpecs against the saved array.
+
+    Two lane widths, each its own multiple of 128: ``dp`` for q, k, dq,
+    dk and ``dvp`` for v, o, do, dv. The MXU is 128 lanes wide, so a
+    v of 128 under a q of 192 halves the passes of v·doᵀ and pᵀ·do in
+    the backward kernels; at equal widths ``dvp == dp``."""
     b, h, tq, d = q.shape
     tkv = k.shape[2]
+    d_v = v.shape[3]
     block_q = min(block_q, max(tq, 8))
     block_kv = min(block_kv, max(tkv, 8))
     if tq > block_q and block_q % 128 != 0:
@@ -261,7 +272,8 @@ def _flash_blocking(q, k, bias, block_q, block_kv):
         block_kv = min(-(-block_kv // 128) * 128, -(-tkv // 128) * 128)
     nq, nk = -(-tq // block_q), -(-tkv // block_kv)
     dp = d + (-d % 128)
-    return block_q, block_kv, nq, nk, dp
+    dvp = d_v + (-d_v % 128)
+    return block_q, block_kv, nq, nk, dp, dvp
 
 
 def _pad_to_blocks(a, t_to, d_to):
@@ -269,24 +281,43 @@ def _pad_to_blocks(a, t_to, d_to):
                        (0, d_to - a.shape[3])))
 
 
+def _pad_v(v, t_to, dp, dvp):
+    """v as the kernels' operand: (batch·heads, t_to, lanes). A v
+    narrower than q still rides in an array of q's ``dp`` lanes, zeros
+    past its own. The forward kernel reads them all (below); the
+    backward kernels' (1, block_kv, dvp) blocks read lane block 0 and
+    the DMA moves no other. The benchmark's roofline readers know the
+    three kernels by three leading operands of one shape
+    (``benchmarks/metrics/mla_attn_fwd_roofline.py``)."""
+    b, h = v.shape[:2]
+    lanes = max(dp, dvp)
+    return _pad_to_blocks(v, t_to, lanes).reshape(b * h, t_to, lanes)
+
+
 def _flash_forward(q, k, v, bias, causal, block_q, block_kv, interpret,
                    return_lse=False):
     b, h, tq, d = q.shape
-    tkv = k.shape[2]
+    tkv, d_v = k.shape[2], v.shape[3]
     scale = 1.0 / math.sqrt(d)
-    block_q, block_kv, nq, nk, dp = _flash_blocking(q, k, bias, block_q,
-                                                    block_kv)
+    block_q, block_kv, nq, nk, dp, dvp = _flash_blocking(
+        q, k, v, bias, block_q, block_kv)
     qp = _pad_to_blocks(q, nq * block_q, dp).reshape(
         b * h, nq * block_q, dp)
     kp = _pad_to_blocks(k, nk * block_kv, dp).reshape(
         b * h, nk * block_kv, dp)
-    vp = _pad_to_blocks(v, nk * block_kv, dp).reshape(
-        b * h, nk * block_kv, dp)
+    vp = _pad_v(v, nk * block_kv, dp, dvp)
+    # p·v runs over all of vp's lanes and only o is cut to dvp: with
+    # v's block and acc at 128 lanes under a q of 256 the kernel has
+    # less to do and takes a fifth LONGER on a v5e (12.5 ms against
+    # 10.4 at 32 x 8192 x 8192, 1024 x 512 blocks; PERF.md, PR 31): its
+    # time is the softmax's per-row vector work, not the MXU's, and the
+    # compiler schedules the narrower step worse.
+    lanes = vp.shape[2]
 
     in_specs = [
         pl.BlockSpec((1, block_q, dp), lambda bh, i, j: (bh, i, 0)),
         pl.BlockSpec((1, block_kv, dp), lambda bh, i, j: (bh, j, 0)),
-        pl.BlockSpec((1, block_kv, dp), lambda bh, i, j: (bh, j, 0)),
+        pl.BlockSpec((1, block_kv, lanes), lambda bh, i, j: (bh, j, 0)),
     ]
     inputs = [qp, kp, vp]
     if bias is not None:
@@ -311,27 +342,27 @@ def _flash_forward(q, k, v, bias, causal, block_q, block_kv, interpret,
         grid=(b * h, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, dp), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, block_q, dvp), lambda bh, i, j: (bh, i, 0)),
             # Row log-sum-exp, lane-8 broadcast (a full 128-lane copy
             # would 16x the residual bytes the train loop saves per
             # layer for the backward kernels).
             pl.BlockSpec((1, block_q, 8), lambda bh, i, j: (bh, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, nq * block_q, dp), q.dtype),
+            jax.ShapeDtypeStruct((b * h, nq * block_q, dvp), q.dtype),
             jax.ShapeDtypeStruct((b * h, nq * block_q, 8), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, dp), jnp.float32),
+            pltpu.VMEM((block_q, lanes), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         metadata={"kernel": "flash_fwd"},
     )(*inputs)
-    out = out.reshape(b, h, nq * block_q, dp)[:, :, :tq, :d]
+    out = out.reshape(b, h, nq * block_q, dvp)[:, :, :tq, :d_v]
     if return_lse:
         return out, lse
     return out
@@ -450,7 +481,7 @@ def _flash_dkv_kernel(*refs, scale, causal, block_q, block_kv, seq_q,
         pt = jnp.where(valid, jnp.exp(st - lse_ref[0]), 0.0)
         dv_scr[:] += jax.lax.dot_general(
             pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (bkv, dp)
+            preferred_element_type=jnp.float32)          # (bkv, dvp)
         dpt = jax.lax.dot_general(
             v_ref[0], do, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -469,18 +500,24 @@ def _flash_backward(q, k, v, bias, out, lse, g, causal, block_q,
                     block_kv, interpret):
     """Assemble dq/dk/dv from the two Pallas backward kernels."""
     b, h, tq, d = q.shape
-    tkv = k.shape[2]
+    tkv, d_v = k.shape[2], v.shape[3]
     scale = 1.0 / math.sqrt(d)
-    block_q, block_kv, nq, nk, dp = _flash_blocking(q, k, bias, block_q,
-                                                    block_kv)
+    block_q, block_kv, nq, nk, dp, dvp = _flash_blocking(
+        q, k, v, bias, block_q, block_kv)
+    if d_v != d:
+        # Found on the chip (PR 31): with no pad between them, the
+        # compiler fuses delta's row sum into the product that makes
+        # ``g`` and its last bits change. Behind the barrier dq, dk, dv
+        # are bit for bit what v zero-padded to q's width gave. At
+        # equal widths the program is left as it always was.
+        out, g = jax.lax.optimization_barrier((out, g))
     qp = _pad_to_blocks(q, nq * block_q, dp).reshape(
         b * h, nq * block_q, dp)
     kp = _pad_to_blocks(k, nk * block_kv, dp).reshape(
         b * h, nk * block_kv, dp)
-    vp = _pad_to_blocks(v, nk * block_kv, dp).reshape(
-        b * h, nk * block_kv, dp)
-    dop = _pad_to_blocks(g, nq * block_q, dp).reshape(
-        b * h, nq * block_q, dp)
+    vp = _pad_v(v, nk * block_kv, dp, dvp)
+    dop = _pad_to_blocks(g, nq * block_q, dvp).reshape(
+        b * h, nq * block_q, dvp)
     # Per-q-row residuals as (bh, 1, T) ROW arrays — the kernels read
     # (1, 1, block_q) blocks (the bias trick: a unit middle axis keeps
     # the block's sublane dim equal to the array's) whose ref[0] is a
@@ -493,20 +530,23 @@ def _flash_backward(q, k, v, bias, out, lse, g, causal, block_q,
     delta = jnp.pad(delta.reshape(b * h, tq),
                     ((0, 0), (0, nq * block_q - tq)))[:, None, :]
 
-    q_spec = pl.BlockSpec((1, block_q, dp), lambda bh, x, y: (bh, x, 0))
-    kv_spec = pl.BlockSpec((1, block_kv, dp), lambda bh, x, y: (bh, y, 0))
+    def by_x(rows, lanes):
+        return pl.BlockSpec((1, rows, lanes), lambda bh, x, y: (bh, x, 0))
+
+    def by_y(rows, lanes):
+        return pl.BlockSpec((1, rows, lanes), lambda bh, x, y: (bh, y, 0))
+
     row_spec = pl.BlockSpec((1, 1, block_q), lambda bh, x, y: (bh, 0, x))
-    # dkv grid order is (bh, kv, q): swap which grid axis feeds which
-    # block index.
-    q_spec_t = pl.BlockSpec((1, block_q, dp), lambda bh, x, y: (bh, y, 0))
-    kv_spec_t = pl.BlockSpec((1, block_kv, dp),
-                             lambda bh, x, y: (bh, x, 0))
     row_spec_t = pl.BlockSpec((1, 1, block_q),
                               lambda bh, x, y: (bh, 0, y))
 
+    # k, v, q, do: the dq grid is (bh, q, kv); the dkv grid (bh, kv, q)
+    # swaps which grid axis feeds which block index.
     inputs = [kp, vp, qp, dop, lse_row, delta]
-    in_specs = [kv_spec, kv_spec, q_spec, q_spec, row_spec, row_spec]
-    in_specs_t = [kv_spec_t, kv_spec_t, q_spec_t, q_spec_t, row_spec_t,
+    in_specs = [by_y(block_kv, dp), by_y(block_kv, dvp),
+                by_x(block_q, dp), by_x(block_q, dvp), row_spec, row_spec]
+    in_specs_t = [by_x(block_kv, dp), by_x(block_kv, dvp),
+                  by_y(block_q, dp), by_y(block_q, dvp), row_spec_t,
                   row_spec_t]
     if bias is not None:
         # kv-side padding mask as a lane-8 COLUMN (the transposed-score
@@ -529,8 +569,7 @@ def _flash_backward(q, k, v, bias, out, lse, g, causal, block_q,
         functools.partial(_flash_dq_kernel, **common),
         grid=(b * h, nq, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_q, dp),
-                               lambda bh, x, y: (bh, x, 0)),
+        out_specs=by_x(block_q, dp),
         out_shape=jax.ShapeDtypeStruct((b * h, nq * block_q, dp),
                                        q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32)],
@@ -543,16 +582,13 @@ def _flash_backward(q, k, v, bias, out, lse, g, causal, block_q,
         functools.partial(_flash_dkv_kernel, **common),
         grid=(b * h, nk, nq),
         in_specs=in_specs_t,
-        out_specs=[
-            pl.BlockSpec((1, block_kv, dp), lambda bh, x, y: (bh, x, 0)),
-            pl.BlockSpec((1, block_kv, dp), lambda bh, x, y: (bh, x, 0)),
-        ],
+        out_specs=[by_x(block_kv, dp), by_x(block_kv, dvp)],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, nk * block_kv, dp), k.dtype),
-            jax.ShapeDtypeStruct((b * h, nk * block_kv, dp), v.dtype),
+            jax.ShapeDtypeStruct((b * h, nk * block_kv, dvp), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((block_kv, dp), jnp.float32),
-                        pltpu.VMEM((block_kv, dp), jnp.float32)],
+                        pltpu.VMEM((block_kv, dvp), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -560,7 +596,7 @@ def _flash_backward(q, k, v, bias, out, lse, g, causal, block_q,
     )(*inputs)
     dq = dq.reshape(b, h, nq * block_q, dp)[:, :, :tq, :d]
     dk = dk.reshape(b, h, nk * block_kv, dp)[:, :, :tkv, :d]
-    dv = dv.reshape(b, h, nk * block_kv, dp)[:, :, :tkv, :d]
+    dv = dv.reshape(b, h, nk * block_kv, dvp)[:, :, :tkv, :d_v]
     return dq, dk, dv
 
 
@@ -602,7 +638,9 @@ def flash_attention(q, k, v, *, causal: bool = False, block_q: int = 1024,
     early in the project; the last recorded figure is 92.8 TFLOP/s bf16
     on causal T=8192 (``BENCH_r05.json``, 2026-07-31) and the kernel
     has not been measured on today's code (``PERF.md``).
-    ``kv_mask`` (B, Tkv) bool, True = real token.
+    ``kv_mask`` (B, Tkv) bool, True = real token. ``v`` may have
+    another width than q and k: the result has v's, the scale is
+    1/sqrt(q's).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"

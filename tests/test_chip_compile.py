@@ -80,7 +80,8 @@ def _sum_grad(fn):
             jnp.float32).sum(), argnums=(0, 1, 2))
 
 
-# (shape, dtype, flash_attention kwargs, with kv_mask)
+# (q's shape, dtype, flash_attention kwargs, with kv_mask); k and v have
+# q's shape but for v's lanes in _V_LANES
 _KERNEL_SHAPES = {
     # flagship LM step: B4·H16·T2048·D128 bf16 causal
     "flagship": ((4, 16, 2048, 128), jnp.bfloat16, {"causal": True},
@@ -96,20 +97,34 @@ _KERNEL_SHAPES = {
     "small_blocks": ((1, 1, 256, 64), jnp.float32,
                      {"causal": True, "block_q": 32, "block_kv": 64},
                      False),
+    # joyai-flash-final's latent attention: q, k 192 lanes (two MXU
+    # passes), v 128 (one); kv blocks of 512 as models/lm_moe.py asks
+    "mla": ((1, 32, 8192, 192), jnp.bfloat16,
+            {"causal": True, "block_kv": 512}, False),
 }
+_V_LANES = {"mla": 128}
+
+
+def _qkv_shapes(shape_name, sharding):
+    shape, dtype, _, _ = _KERNEL_SHAPES[shape_name]
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    v = jax.ShapeDtypeStruct(
+        shape[:3] + (_V_LANES.get(shape_name, shape[3]),), dtype,
+        sharding=sharding)
+    return [x, x, v]
 
 
 @pytest.mark.parametrize("case", [
     "flagship-fwd", "flagship-grad",
     "bench_t8192-fwd",  # the bench config times the forward only
     "masked_197x64-fwd", "masked_197x64-grad",
-    "small_blocks-fwd", "small_blocks-grad"])
+    "small_blocks-fwd", "small_blocks-grad",
+    "mla-fwd", "mla-grad"])
 def test_flash_kernel_compiles_on_one_chip(one_chip, case):
     shape_name, pass_ = case.split("-")
     grad = pass_ == "grad"
-    shape, dtype, kwargs, masked = _KERNEL_SHAPES[shape_name]
-    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    args = [x, x, x]
+    shape, _, kwargs, masked = _KERNEL_SHAPES[shape_name]
+    args = _qkv_shapes(shape_name, one_chip)
     if masked:
         args.append(jax.ShapeDtypeStruct(
             (shape[0], shape[2]), jnp.bool_, sharding=one_chip))
@@ -138,6 +153,86 @@ def test_flash_kernels_carry_their_names_into_the_compiled_program(
     text = _compile(_sum_grad(attend), x, x, x).as_text()
     named = re.findall(r'kernel_metadata=\{\s*"kernel":"(\w+)"', text)
     assert set(named) == {"flash_fwd", "flash_dq", "flash_dkv"}
+
+
+def _joyai_knobs():
+    """The knobs of the benchmark's ``joyai-flash-final`` cell."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "joyai-llm-flash-L5-E8.json")) as f:
+        config = json.load(f)
+    knobs = {knob: config[key] for knob, key in config["knob_of"].items()}
+    knobs.update(config["knobs"])
+    return knobs
+
+
+def _kernels_the_benchmark_reads(text):
+    """``benchmarks/metrics/mla_attn_fwd_roofline.py:kernels`` over the
+    Mosaic calls of a compiled program: {kernel: calls found}, once with
+    the kernels' names in the text and once by signature alone, and the
+    results' shapes of each call. The reader takes a profiler's op
+    events, whose text states every operand's shape; the compiled text
+    names operands only, so each gets its defining instruction's."""
+    import os
+    import re
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        import harness
+        reader = harness.load_module("metrics", "mla_attn_fwd_roofline")
+    finally:
+        sys.path.remove(bench)
+    shape_of = dict(re.findall(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+\[[\d,]*\]\S*) ", text, re.M))
+    named, bare, results = {}, {}, {}
+    for head, operands, attrs in re.findall(
+            r"^\s*(?:ROOT )?(%[^\n]*? custom-call\()([^)\n]*)(\), "
+            r'custom_call_target="tpu_custom_call".*?\n\}\})', text,
+            re.M | re.S):
+        operands = ", ".join(
+            f"{shape_of[name]} {name}" for name in re.findall(
+                r"%[\w.\-]+", operands))
+        op = {"n": 1, "seconds": 1.0}
+        named[head + operands + attrs] = op
+        bare[head + operands + attrs.partition(", operand_layout")[0]] = op
+        results[re.search(r'"kernel":"(\w+)"', attrs).group(1)] = \
+            re.findall(r"\w+\[[\d,]*\]", head.partition(" custom-call")[0])
+    counts = []
+    for ops in (named, bare):
+        found = reader.kernels({"trace": {"ops": ops},
+                                "knobs": _joyai_knobs()})
+        counts.append({name: k["n"] for name, k in found.items()})
+    assert counts[0] == counts[1], counts
+    return counts[0], results
+
+
+def test_benchmark_readers_find_the_mla_kernels_in_the_compiled_program(
+        one_chip):
+    """The benchmark's roofline readers, which no ``perf_opt`` PR may
+    edit, count a Mosaic call only if its first three operands are all
+    (batch x heads, T, q's lanes padded): (q, k, v) in the forward,
+    (k, v, q) in dq and dkv. So v's array keeps q's 256 lanes while its
+    blocks, o, do and dv are 128 wide. A change of operand order or
+    shape fails here and not in a chip check."""
+    _, _, kwargs, _ = _KERNEL_SHAPES["mla"]
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, interpret=False, **kwargs)
+
+    text = _compile(_sum_grad(attend),
+                    *_qkv_shapes("mla", one_chip)).as_text()
+    counts, results = _kernels_the_benchmark_reads(text)
+    assert counts == {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}
+    assert results == {
+        "flash_fwd": ["bf16[32,8192,128]", "f32[32,8192,8]"],
+        "flash_dq": ["bf16[32,8192,256]"],
+        "flash_dkv": ["bf16[32,8192,256]", "bf16[32,8192,128]"]}
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
@@ -248,10 +343,10 @@ def test_joyai_cell_step_compiles_and_fits_one_chip(topo, monkeypatch):
     from shapes through the trainer's own ``_make_train_chunk``: the
     three flash kernels are in it by name (the dkv kernel at 256 padded
     lanes needs the halved kv block: 1024 x 1024 overflows the scoped
-    VMEM), and arguments + temporaries stay under the 14.5 GB that
+    VMEM) and as the benchmark's readers know them, in each of the
+    dense, sparse and multi-token blocks (the forward once more under
+    remat), and arguments + temporaries stay under the 14.5 GB that
     leave a job room for the previous trial's parameters."""
-    import json
-    import os
     import re
 
     import optax
@@ -260,13 +355,7 @@ def test_joyai_cell_step_compiles_and_fits_one_chip(topo, monkeypatch):
     from rafiki_tpu.models.lm import _weights
     from rafiki_tpu.models.lm_moe import _jitted_moe_init
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "configs",
-                           "joyai-llm-flash-L5-E8.json")) as f:
-        config = json.load(f)
-    knobs = {knob: config[key] for knob, key in config["knob_of"].items()}
-    knobs.update(config["knobs"])
-    model = JaxLatentMoELM(**knobs)
+    model = JaxLatentMoELM(**_joyai_knobs())
     mesh = build_mesh(topo.devices[:1])
     model._mesh = mesh
     rep = replicated(mesh)
@@ -287,9 +376,12 @@ def test_joyai_cell_step_compiles_and_fits_one_chip(topo, monkeypatch):
                                 sharding=rep)
     compiled = model._make_train_chunk(tx).lower(
         params, opt_state, wins).compile()
-    named = re.findall(r'kernel_metadata=\{\s*"kernel":"(\w+)"',
-                       compiled.as_text())
+    text = compiled.as_text()
+    named = re.findall(r'kernel_metadata=\{\s*"kernel":"(\w+)"', text)
     assert set(named) == {"flash_fwd", "flash_dq", "flash_dkv"}
+    counts, results = _kernels_the_benchmark_reads(text)
+    assert counts == {"flash_fwd": 6, "flash_dq": 3, "flash_dkv": 3}
+    assert results["flash_fwd"][0] == "bf16[32,8192,128]"  # v's lanes
     m = compiled.memory_analysis()
     assert m.argument_size_in_bytes > 5.8e9  # params + Adam, resident
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 14.5e9, m

@@ -186,7 +186,14 @@ def test_remote_scatter_pays_one_relay_hop_per_leg():
         replies = cache_a.gather_prediction_batches(bid, 1, timeout=10.0)
         assert len(replies) == 1
         assert replies[0]["predictions"] == [[1.0]]
+        # A broker counts "out" when its forward RETURNS: the reply can
+        # reach the gatherer before broker b has counted its leg.
+        deadline = time.monotonic() + 5.0
         after = _relay_counts()
+        while (after.get("out", 0) - base.get("out", 0) < 2
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+            after = _relay_counts()
         assert after.get("out", 0) - base.get("out", 0) == 2, (base, after)
         assert after.get("in", 0) - base.get("in", 0) == 2, (base, after)
         assert after.get("fallback", 0) == base.get("fallback", 0)

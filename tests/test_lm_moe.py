@@ -307,16 +307,15 @@ def test_held_experts_gradients_match_a_masked_sum(monkeypatch):
 @pytest.mark.parametrize("grad", [False, True], ids=["values", "grads"])
 def test_flash_path_with_v_narrower_than_q(grad):
     """MLA's attention through the flash kernels (the interpreter
-    here): v is 2/3 of q's width, rides zero-padded to it and o is
-    sliced back; the scale is 1/sqrt(q's width)."""
+    here): v is 2/3 of q's width and goes in at its own, o comes back
+    at it; the scale is 1/sqrt(q's width)."""
     rng = np.random.default_rng(11)
     q, k = (jnp.asarray(rng.standard_normal((1, 2, 40, 24)), jnp.float32)
             for _ in range(2))
     v = jnp.asarray(rng.standard_normal((1, 2, 40, 16)), jnp.float32)
 
     def flash(q, k, v):
-        padded = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, 8)))
-        return flash_attention(q, k, padded, causal=True)[..., :16]
+        return flash_attention(q, k, v, causal=True)
 
     def naive(q, k, v):
         s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(24)
@@ -326,11 +325,12 @@ def test_flash_path_with_v_narrower_than_q(grad):
     if not grad:
         np.testing.assert_allclose(flash(q, k, v), naive(q, k, v),
                                    atol=2e-5, rtol=2e-5)
-        # the repo's naive attention agrees on the padded problem
+        # the repo's naive attention agrees, and on the padded problem
         padded = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, 8)))
-        np.testing.assert_allclose(
-            naive_attention(q, k, padded, causal=True)[..., :16],
-            naive(q, k, v), atol=2e-5, rtol=2e-5)
+        for got in (naive_attention(q, k, v, causal=True),
+                    naive_attention(q, k, padded, causal=True)[..., :16]):
+            np.testing.assert_allclose(got, naive(q, k, v), atol=2e-5,
+                                       rtol=2e-5)
         return
     ct = jnp.asarray(rng.standard_normal((1, 2, 40, 16)), jnp.float32)
     got = jax.grad(lambda *a: (flash(*a) * ct).sum(), (0, 1, 2))(q, k, v)

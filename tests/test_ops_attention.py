@@ -11,11 +11,12 @@ from rafiki_tpu.ops import (blockwise_attention, flash_attention,
 from rafiki_tpu.parallel import build_mesh
 
 
-def _qkv(rng, b=2, h=2, t=64, d=32, dtype=np.float32, tkv=None):
+def _qkv(rng, b=2, h=2, t=64, d=32, dtype=np.float32, tkv=None, d_v=None):
+    """q, k of ``d`` lanes and v of ``d_v`` (``d`` unless given)."""
     tkv = t if tkv is None else tkv
     q = rng.standard_normal((b, h, t, d)).astype(dtype)
     k = rng.standard_normal((b, h, tkv, d)).astype(dtype)
-    v = rng.standard_normal((b, h, tkv, d)).astype(dtype)
+    v = rng.standard_normal((b, h, tkv, d_v or d)).astype(dtype)
     return jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
 
 
@@ -119,12 +120,12 @@ def test_flash_blocking_rounds_block_q_for_backward(rng):
     q = jnp.zeros((1, 1, 256, 64))
     k = jnp.zeros((1, 1, 256, 64))
     for req_bq in (8, 32, 96, 100, 128, 256):
-        bq, _, nq, _, _ = _flash_blocking(q, k, None, req_bq, 64)
+        bq, _, nq, _, _, _ = _flash_blocking(q, k, k, None, req_bq, 64)
         assert nq == 1 or bq % 128 == 0, (req_bq, bq, nq)
         assert nq * bq >= 256
     # under one whole-q block the size is unconstrained
     q8 = jnp.zeros((1, 1, 48, 64))
-    bq, _, nq, _, _ = _flash_blocking(q8, q8, None, 64, 64)
+    bq, _, nq, _, _, _ = _flash_blocking(q8, q8, q8, None, 64, 64)
     assert nq == 1 and bq == 48
 
     # numerics (fwd + bwd) survive the rounding, at the shape that failed
@@ -137,6 +138,167 @@ def test_flash_blocking_rounds_block_q_for_backward(rng):
     g2 = jax.grad(lambda q: naive_attention(
         q, k, v, causal=True).sum())(q)
     np.testing.assert_allclose(g1, g2, atol=1e-4, rtol=1e-4)
+
+
+# (t, tkv, causal, kv_mask, flash_attention's blocks)
+_NARROW_V_CASES = {
+    "one_block": (40, 40, False, False, {}),
+    "causal": (40, 40, True, False, {}),
+    "masked": (40, 40, False, True, {}),
+    # T not a block multiple, nq = 3 and nk = 3
+    "blocked": (300, 300, True, False, {"block_q": 128, "block_kv": 128}),
+    "blocked_masked_cross": (200, 330, True, True,
+                             {"block_q": 128, "block_kv": 128}),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("widths", [(24, 16), (192, 128), (64, 192)],
+                         ids=lambda w: f"{w[0]}/{w[1]}")
+@pytest.mark.parametrize("case", list(_NARROW_V_CASES))
+def test_flash_takes_v_at_its_own_width(rng, case, widths, dtype):
+    """Latent attention's shapes (q, k 192 lanes, v 128) and a v wider
+    than q: values and the gradients of q, k, v against the naive
+    reference, which is two einsums and never knew one head_dim. The
+    result has v's width; the scale is 1/sqrt(q's)."""
+    t, tkv, causal, masked, blocks = _NARROW_V_CASES[case]
+    d, d_v = widths
+    q, k, v = _qkv(rng, b=1, t=t, d=d, dtype=dtype, tkv=tkv, d_v=d_v)
+    mask = None
+    if masked:
+        mask = jnp.asarray(np.arange(tkv)[None, :] < tkv - 13)
+    ct = jnp.asarray(rng.standard_normal((1, 2, t, d_v)), jnp.float32)
+
+    def loss(fn, **kw):
+        def f(q, k, v):
+            out = fn(q, k, v, causal=causal, kv_mask=mask, **kw)
+            return (out.astype(jnp.float32) * ct).sum(), out
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    (_, out), got = loss(flash_attention, **blocks)(q, k, v)
+    (_, ref), want = loss(naive_attention)(q, k, v)
+    assert out.shape == (1, 2, t, d_v) and out.dtype == dtype
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=tol, rtol=tol)
+    gtol = 1e-4 if dtype == jnp.float32 else 6e-2
+    for name, a, b in zip("qkv", got, want):
+        assert a.shape == b.shape and a.dtype == dtype, name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, atol=gtol * max(1.0, np.abs(b).max()),
+                                   rtol=gtol, err_msg=name)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_flash_narrow_v_equals_the_zero_padded_path_bit_for_bit(rng, dtype):
+    """What the narrow path stops computing are lanes of zeros: o, dq,
+    dk and dv are the very bits of the path it replaces (v zero-padded
+    to q's width, o sliced back), at a shape with nq = nk = 3, a
+    key-padding mask and T not a block multiple."""
+    q, k, v = _qkv(rng, b=1, t=300, d=192, dtype=dtype, d_v=128)
+    mask = jnp.asarray(np.arange(300)[None, :] < 290)
+    ct = jnp.asarray(rng.standard_normal((1, 2, 300, 128)), jnp.float32)
+    blocks = {"block_q": 128, "block_kv": 128}
+
+    def narrow(q, k, v):
+        return flash_attention(q, k, v, causal=True, kv_mask=mask, **blocks)
+
+    def padded(q, k, v):
+        wide = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, 64)))
+        return flash_attention(q, k, wide, causal=True, kv_mask=mask,
+                               **blocks)[..., :128]
+
+    def run(fn):
+        def f(q, k, v):
+            out = fn(q, k, v)
+            return (out.astype(jnp.float32) * ct).sum(), out
+        (_, out), grads = jax.value_and_grad(
+            f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (out,) + grads
+
+    for name, a, b in zip(("o", "dq", "dk", "dv"), run(narrow),
+                          run(padded)):
+        assert np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32)), name
+
+
+@pytest.mark.parametrize("d_v,v_lanes", [(192, 256), (128, 128)],
+                         ids=["equal", "narrow_v"])
+def test_flash_block_shapes_handed_to_pallas_call(monkeypatch, d_v,
+                                                  v_lanes):
+    """At equal widths every block, result and scratch of the three
+    ``pallas_call``s is what it was before v had a width of its own
+    (all 256 lanes for a 192-lane head); with v at 128, o, do, dv, dv's
+    accumulator and the backward kernels' v blocks narrow, while the
+    forward still multiplies all of v's padded lanes (faster on a v5e
+    than the narrower step: ``_flash_forward``). v's ARRAY keeps q's
+    padded lanes either way: the benchmark's roofline readers know the
+    kernels by three leading operands of one shape."""
+    from rafiki_tpu.ops import attention
+
+    calls = {}
+    real = attention.pl.pallas_call
+
+    def spy(kernel, **kw):
+        run = real(kernel, **kw)
+
+        def wrapped(*operands):
+            def blocks(specs):
+                return [tuple(s.block_shape) for s in jax.tree.leaves(
+                    specs, is_leaf=lambda x: hasattr(x, "block_shape"))]
+            calls[kw["metadata"]["kernel"]] = {
+                "operands": [o.shape for o in operands],
+                "in": blocks(kw["in_specs"]),
+                "out": blocks(kw["out_specs"]),
+                "results": [o.shape for o in jax.tree.leaves(
+                    kw["out_shape"])],
+                "scratch": [s.shape for s in kw["scratch_shapes"]]}
+            return run(*operands)
+        return wrapped
+
+    monkeypatch.setattr(attention.pl, "pallas_call", spy)
+    q = jnp.ones((1, 2, 256, 192), jnp.bfloat16)
+    v = jnp.ones((1, 2, 256, d_v), jnp.bfloat16)
+    jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=128, block_kv=128
+    ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, q, v)
+
+    qk, w = (1, 128, 256), (1, 128, v_lanes)
+    row, arr = (1, 1, 128), (2, 256, 256)
+    assert calls["flash_fwd"] == {
+        "operands": [arr] * 3, "in": [qk, qk, qk],
+        "out": [w, (1, 128, 8)],
+        "results": [(2, 256, v_lanes), (2, 256, 8)],
+        "scratch": [(128, 128), (128, 128), (128, 256)]}
+    assert calls["flash_dq"] == {
+        "operands": [arr] * 3 + [(2, 256, v_lanes), (2, 1, 256),
+                                 (2, 1, 256)],
+        "in": [qk, w, qk, w, row, row], "out": [qk],
+        "results": [arr], "scratch": [(128, 256)]}
+    assert calls["flash_dkv"] == dict(
+        calls["flash_dq"], out=[qk, w],
+        results=[arr, (2, 256, v_lanes)],
+        scratch=[(128, 256), (128, v_lanes)])
+
+
+@pytest.mark.parametrize("d_v,barrier", [(192, False), (128, True)],
+                         ids=["equal", "narrow_v"])
+def test_flash_backward_holds_do_behind_a_barrier_only_for_a_narrow_v(
+        d_v, barrier):
+    """With v at its own width nothing stands between the product that
+    makes ``do`` and delta's row sum, and the chip's compiler fuses the
+    two: delta's last bits change (found on the chip, PR 31). The
+    barrier keeps dq, dk, dv bit for bit what the zero-padded path
+    gave; at equal widths the traced program has none, as before."""
+    q = jnp.ones((1, 2, 256, 192), jnp.bfloat16)
+    v = jnp.ones((1, 2, 256, d_v), jnp.bfloat16)
+    text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=128, block_kv=128
+    ).astype(jnp.float32).sum(), argnums=(0, 1, 2)))(q, q, v))
+    assert ("optimization_barrier" in text) == barrier
 
 
 @pytest.mark.slow
