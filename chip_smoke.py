@@ -41,7 +41,7 @@ from concurrent.futures import ThreadPoolExecutor
 
 SEED = 0
 
-#: The flagship LM (the shape of bench.py's roofline config), pinned by
+#: The flagship LM (2048 wide, 8 layers), pinned by
 #: FixedKnobs so the advisor has nothing to search: one trial = one shape.
 FLAGSHIP = {
     "d_model": 2048, "n_layers": 8, "seq_len": 2048, "vocab_size": 32768,

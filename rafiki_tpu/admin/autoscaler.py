@@ -11,8 +11,8 @@ supervise cadence that
 1. **reads** load signals from each RUNNING inference job's predictor
    ``/metrics`` (request-rate deltas, admission-queue depth,
    backpressure counters, the ``/predict`` latency histogram — parsed
-   with the same ``parse_exposition``/``bucket_percentile`` the bench
-   uses, so the controller sees exactly what production scrapes) plus
+   with ``parse_exposition``/``bucket_percentile``, so the controller
+   sees exactly what production scrapes) plus
    the in-process registry's ``rafiki_tpu_train_mfu_ratio`` gauges
    (the idle-training signal);
 2. **decides** per-bin replica targets through :class:`AutoscalePolicy`

@@ -63,9 +63,10 @@ class NodeRegistry:
         # BusServer.add_peer from the registry instead of static config.
         self.bus_uri = bus_uri
         self.lease_s = float(lease_s)
-        # Gauge exists only while a registry does (fabric on) — the
-        # cluster_fabric=off side of the bench A/B asserts ZERO
-        # rafiki_tpu_node_* series.
+        # Gauge exists only while a registry does (fabric on): with
+        # cluster_fabric off there are ZERO rafiki_tpu_node_* series
+        # (tests/test_cluster.py::
+        # test_single_node_construction_has_no_cluster_surface).
         self._peers_gauge = None
         if _metrics.metrics_enabled():
             self._peers_gauge = _metrics.registry().gauge(

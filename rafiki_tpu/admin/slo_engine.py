@@ -5,7 +5,7 @@ The judgment layer over the r17 attribution ledger and the r7 metrics
 plane (vocabulary in ``observe/slo.py``): one ``sweep()`` per
 supervise pass scrapes each RUNNING inference job's predictor
 ``/metrics`` — the exact text production scrapes, parsed with the same
-``parse_exposition`` the bench and the autoscaler trust — folds the
+``parse_exposition`` the autoscaler reads with — folds the
 per-sweep event deltas into each objective's window ring, publishes
 the error-budget and burn-rate gauges, and advances the per-instance
 alert state machines.

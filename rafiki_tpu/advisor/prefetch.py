@@ -13,7 +13,7 @@ trial's feedback arrives, so it is one observation stale — exactly the
 asynchrony N parallel workers sharing one advisor already exhibit
 (proposals routinely race feedback there), and the reason every advisor
 strategy here tolerates out-of-order feedback. Wrap only where that
-trade is wanted (the single-worker bench loop, a latency-sensitive
+trade is wanted (a single-worker search loop, a latency-sensitive
 runner); the default in-process search stays synchronous.
 
 ``close()`` (or the context manager) must run at end of search: the

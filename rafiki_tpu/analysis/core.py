@@ -276,9 +276,9 @@ class Report:
     checkers: List[str]
     stale_baseline: List[str]
     #: Every code a checker that RAN could have emitted — so
-    #: counts_per_code carries explicit zeros (bench.py --config
-    #: analysis records per-code counts; a zero for RTA104 is
-    #: evidence the gate looked, absence would be ambiguous).
+    #: counts_per_code carries explicit zeros (a zero for RTA104 is
+    #: evidence the gate looked, absence would be ambiguous;
+    #: tests/test_analysis.py::test_cli_json_exit_zero).
     covered_codes: List[str] = dataclasses.field(default_factory=list)
     #: Per-checker wall time (seconds) — the --diff mode's cost
     #: breakdown, so a checker that stops scaling is visible in CI

@@ -479,25 +479,6 @@ class Cache:
     # worker): the serving QPS ceiling is bus round-trips, not chip
     # compute, so the scatter/gather rides batch-granular frames.
 
-    def send_query_batch(self, worker_id: str, queries: List[Any],
-                         batch_id: Optional[str] = None,
-                         pre_encoded: bool = False,
-                         trace_ctxs: Optional[List] = None) -> str:
-        """``pre_encoded=True`` lets a caller scattering the same batch
-        to many workers pay ``encode_payload`` once, not once per
-        worker (the serving hot path)."""
-        batch_id = batch_id or uuid.uuid4().hex
-        if not pre_encoded:
-            queries = [encode_payload(q) for q in queries]
-        frame = {"batch_id": batch_id, "queries": queries}
-        if self._packed_wire_on:
-            frame["rw"] = [WIRE_NDBATCH]
-        env = _trace_envelope(trace_ctxs)
-        if env is not None:
-            frame[_trace.ENVELOPE_KEY] = env
-        self.bus.push(f"q:{worker_id}", frame)
-        return batch_id
-
     def send_query_batch_fanout(self, worker_ids: List[str],
                                 encoded_queries: Optional[List[Any]],
                                 batch_id: Optional[str] = None,

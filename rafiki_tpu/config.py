@@ -119,17 +119,17 @@ class NodeConfig:
     # contiguous __ndbatch__ buffer per shard toward workers that
     # advertise it (negotiated — old workers keep per-query frames);
     # "compat" emits/advertises nothing packed but KEEPS the wire-bytes
-    # / host-copies accounting (kill switch with observability, and the
-    # bench A/B's measured legacy side); "off" = legacy frames and
-    # ZERO wire metric series.
+    # / host-copies accounting (kill switch with observability);
+    # "off" = legacy frames and ZERO wire metric series.
     serving_packed_wire: str = "on"
     # Serving quantization mode: "int8" quantizes each InferenceWorker's
     # model post-load (per-channel symmetric weight scales, dequant-free
     # int8 matmuls where the module supports it, f32 fallback per
     # layer); "" (default) serves the trained dtype. Promotion-spawned
     # workers recompute scales for their bin at load. Accuracy contract:
-    # bench.py --config serving-concurrent --quant int8 gates on the
-    # f32-vs-int8 accuracy delta.
+    # tests/test_wire_codec.py::test_int8_quant_close_to_f32 and
+    # tests/test_stacked.py::test_cnn_int8_close_to_f32 hold the
+    # f32-vs-int8 delta.
     serving_quant: str = ""
     # Stacked-ensemble serving (docs/serving.md "Stacked ensembles"):
     # "on" (default) lets an InferenceWorker hosting a multi-member

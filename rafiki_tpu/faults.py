@@ -9,7 +9,7 @@ the one place faults come from: every injection site in the tree asks
 it for a hook at CONSTRUCTION time, and a process with no fault plan
 stores ``None`` — the hot path pays exactly one attribute comparison
 (the "strictly zero-overhead when disabled" contract, tested in
-``tests/test_faults.py`` and A/B'd in ``bench.py --config chaos``).
+``tests/test_faults.py``).
 
 Plan grammar (``RAFIKI_TPU_FAULT_PLAN``; rules ``;``-separated)::
 
@@ -297,7 +297,7 @@ _loaded = False  # env consulted at least once
 class _SiteHook:
     """The per-site callable an injection site stores. Consults the
     CURRENT armed plan on every call, so ``set_plan`` re-arms sites
-    that were constructed earlier (required by the chaos bench: build
+    that were constructed earlier (``tests/test_chaos.py``: build
     quietly, injure mid-flight)."""
 
     __slots__ = ("site",)

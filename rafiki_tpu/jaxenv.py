@@ -15,7 +15,8 @@ outcomes:
   child and no fallback.
 
 ``ensure_platform()`` is the first call of every entry point (serve
-CLI, bench.py, chip_smoke.py, example scripts, ``__graft_entry__``). It
+CLI, chip_smoke.py, ``benchmarks/``, example scripts,
+``__graft_entry__``). It
 is also the one place that places JAX's persistent compilation cache
 for a chip run: wherever ``JAX_COMPILATION_CACHE_DIR`` says, else a
 fixed directory inside the checkout. An explicit CPU run gets no cache

@@ -33,7 +33,7 @@ class ModelLogger:
 
     def current_sink(self) -> Optional[LogSink]:
         """This thread's sink binding. Harnesses that install a
-        temporary sink (bench probes, trial runners) must save this and
+        temporary sink (probes, trial runners) must save this and
         restore it — and usually chain to it — rather than nulling the
         binding on exit."""
         return getattr(self._tls, "sink", None)

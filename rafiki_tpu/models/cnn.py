@@ -111,8 +111,9 @@ class JaxCnn(JaxModel):
         ``dynamic_int8_conv`` (4-D kernels carry per-output-channel
         scales since r16) and the head Denses via
         ``dynamic_int8_matmul``, mirroring ``_Cnn.__call__``'s
-        masked-supernet forward exactly — the ``bench.py --quant
-        int8`` accuracy-delta gate is the regression net. A kernel
+        masked-supernet forward exactly —
+        ``tests/test_stacked.py::test_cnn_int8_close_to_f32`` is the
+        regression net. A kernel
         the quantizer left in f32 falls back per layer, as the wire
         contract promises."""
         mask16 = extra["width_16ths"]

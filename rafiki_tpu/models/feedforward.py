@@ -113,9 +113,9 @@ class JaxFeedForward(JaxModel):
         dynamically per row — no calibration pass), mirroring
         ``_FeedForward.__call__``'s masked-supernet forward exactly. A
         kernel the quantizer left in f32 (none today, but the contract
-        is per-layer) falls back to a plain matmul on that layer. The
-        accuracy-delta gate in ``bench.py --quant int8`` is the
-        regression net for this hand-mirrored forward."""
+        is per-layer) falls back to a plain matmul on that layer.
+        ``tests/test_wire_codec.py::test_int8_quant_close_to_f32`` is
+        the regression net for this hand-mirrored forward."""
         import jax.numpy as jnp
 
         def dense(h, i):

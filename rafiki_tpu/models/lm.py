@@ -6,8 +6,8 @@ exists for a platform reason as much as a product one: the BASELINE
 north star demands ≥90% chip utilization during training, and every
 parity model (28×28/32×32 images, 2.4k-token corpora) is far too small
 to put meaningful load on a 197-TFLOP/s MXU. This model is the zoo's
-compute-dense citizen — the shape the ``roofline`` bench config drives
-to high sustained MFU on one chip (r4 verdict item 1).
+compute-dense citizen — the shape the benchmark's ``lm14-*`` cells
+drive on one chip (``PERF.md`` §4, §5).
 
 TPU-first design choices, all measured on a v5e-1 (2026-07-31):
 
@@ -71,7 +71,7 @@ from .transformer import _sinusoidal
 def _jitted_param_init(v, d, L, mesh):
     """One jitted device-side initializer per shape and mesh
     (lru-cached: a fresh jit per model instance would re-trace ~2 s
-    every bench window / AutoML trial). The whole tree is born
+    every AutoML trial). The whole tree is born
     replicated on ``mesh`` — the trial's own chip group — so nothing is
     staged through the process's default device."""
     shapes = {
@@ -361,10 +361,10 @@ class JaxTransformerLM(BaseModel):
         params = jax.device_put(self._params or self._init_params(),
                                 replicated(mesh))
         # Compiled-step cache, shared convention with the whole zoo
-        # (model/jax_model.py): repeated trials of one config — the
-        # bench's adaptive windows, an AutoML search over lr — reuse
+        # (model/jax_model.py): repeated trials of one config reuse
         # ONE executable instead of re-paying the ~10 s flagship
-        # compile per train() call.
+        # compile per train() call. A search over lr does not: the
+        # rate is a constant of the step (PERF.md §6, `lm14-search`).
         cache_key = step_cache_key(self, "train", mesh, steps, b, k_disp)
         cached = _step_cache_get(cache_key)
         lr = float(self.knobs.get("learning_rate", 3e-4))

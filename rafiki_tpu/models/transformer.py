@@ -137,8 +137,9 @@ def quantized_encoder_block(qvars, scales, fvars, prefix: str, x,
     None for a MoE block (3-D expert stacks sit outside the
     quantizer's 2-D/4-D kernel eligibility) so callers fall back to
     the generic dequantized path. Shared by the transformer zoo's
-    ``quantized_apply`` implementations (models/vit.py); the
-    ``bench.py --quant int8`` accuracy gate is the regression net."""
+    ``quantized_apply`` implementations (models/vit.py);
+    ``tests/test_stacked.py::test_vit_stacked_parity_and_int8_accuracy``
+    is the regression net."""
     from ..model.jax_model import dynamic_int8_matmul
 
     if f"{prefix}/moe_gate" in fvars or f"{prefix}/moe_gate" in qvars:

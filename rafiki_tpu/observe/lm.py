@@ -15,8 +15,9 @@ generative bins. This module owns that family:
 - ``rafiki_tpu_lm_decode_dispatches_total`` — compiled decode-program
   launches. tokens/dispatches is the continuous-batching evidence:
   static batching pays ~1 dispatch per token per sequence, the
-  batched decode loop pays 1/batch-width (`bench.py --config
-  lm-serving` judges its A/B on exactly this counter pair).
+  batched decode loop pays 1/batch-width
+  (``tests/test_decode_scheduler.py::
+  test_short_request_finishes_while_long_decodes`` reads this pair).
 - ``rafiki_tpu_lm_prefill_total{mode=run|cached}`` — prefills
   executed vs skipped whole via the prefix cache.
 - ``rafiki_tpu_lm_preemptions_total`` — sequences evicted under

@@ -5,8 +5,8 @@ subsystem grew its own ad-hoc counters (``ServingStats``' dict, the
 ``MfuMeter``'s properties, per-trial logs). This module is the one
 place counters, gauges, and fixed-bucket latency histograms live, so
 every service exposes the SAME numbers over ``GET /metrics`` (wired
-into ``utils.service.JsonHttpServer``) that the bench and the admin
-dashboard read.
+into ``utils.service.JsonHttpServer``) that the autoscaler, the SLO
+plane and the admin dashboard read.
 
 Design constraints, in order:
 
@@ -325,8 +325,9 @@ def bucket_percentile(cum_buckets: List[Tuple[float, int]],
                       q: float) -> Optional[float]:
     """Approximate the q-quantile (0..1) from cumulative ``le`` buckets
     by linear interpolation inside the containing bucket — the same
-    estimate Prometheus's ``histogram_quantile`` computes, so bench and
-    production dashboards agree by construction. None when empty; a
+    estimate Prometheus's ``histogram_quantile`` computes, so the
+    control loops and production dashboards agree by construction.
+    None when empty; a
     quantile landing in the +Inf bucket reports the last finite bound
     (a known floor, not a fabricated value)."""
     if not cum_buckets:
@@ -441,7 +442,7 @@ def bound_labels() -> Dict[str, str]:
     return dict(getattr(_labels_local, "labels", {}))
 
 
-# --- Exposition parsing (bench / tests read what production exposes) --
+# --- Exposition parsing (readers see what production exposes) --------
 
 def _is_escaped(s: str, i: int) -> bool:
     """Whether ``s[i]`` is escaped: preceded by an ODD number of
@@ -459,7 +460,7 @@ def strip_exemplar(line: str) -> str:
     """Drop an OpenMetrics exemplar annotation (`` # {...} value
     [ts]``) from a sample line, respecting quotes — a ``#`` inside a
     quoted label value is data, not an annotation. Scrapers of the
-    exposition (bench, the autoscaler, tests) route through
+    exposition (the autoscaler, the SLO engine, tests) route through
     :func:`parse_exposition`, so exemplars can never break them."""
     if "#" not in line:  # the overwhelming default: no scan at all
         return line
@@ -480,7 +481,7 @@ def parse_exposition(text: str) -> Dict[str, List[Tuple[Dict[str, str],
     including OpenMetrics-style exemplar annotations on histogram
     bucket lines (tolerated and stripped) and json-escaped label
     values (``\\"``, ``\\n``, ``\\\\`` round-trip exactly). It is how
-    the bench and the autoscaler read ``/metrics`` instead of
+    the autoscaler and the SLO engine read ``/metrics`` instead of
     re-deriving numbers client-side, so it must never regress on what
     the exposition grows."""
     out: Dict[str, List[Tuple[Dict[str, str], float]]] = {}
@@ -523,33 +524,6 @@ def _split_labels(body: str) -> Iterable[str]:
         i += 1
     if start < len(body):
         yield body[start:]
-
-
-def histogram_percentiles_ms(samples: List[Tuple[Dict[str, str], float]],
-                             qs: Sequence[float] = (0.5, 0.95, 0.99),
-                             **match: str) -> Optional[List[float]]:
-    """Percentiles (milliseconds) of one exposed histogram: feed the
-    ``<name>_bucket`` samples from :func:`parse_exposition`, filtered
-    to the label subset ``match``. None when no matching observations."""
-    cum: Dict[float, int] = {}
-    for labels, value in samples:
-        if any(labels.get(k) != str(v) for k, v in match.items()):
-            continue
-        le = labels.get("le")
-        if le is None:
-            continue
-        bound = math.inf if le == "+Inf" else float(le)
-        cum[bound] = cum.get(bound, 0) + int(value)
-    if not cum:
-        return None
-    buckets = sorted(cum.items(), key=lambda kv: kv[0])
-    if buckets[-1][1] <= 0:
-        return None
-    out = []
-    for q in qs:
-        v = bucket_percentile(buckets, q)
-        out.append(round(v * 1e3, 3) if v is not None else None)
-    return out
 
 
 # --- Standalone metrics server (worker runners have no HTTP surface) --

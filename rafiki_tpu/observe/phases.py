@@ -1,10 +1,11 @@
 """Trial-lifecycle phase metrics, folded into the unified registry.
 
 The trial hot loop (propose -> load -> stage -> train -> eval ->
-persist) is where the training plane's trials/hour lives, and BENCH_r05
-showed it almost entirely host-bound (``chip_util ~ 0`` for the trials
-config). These series make the breakdown measurable the same way the
-serving stage histogram did for the frontend:
+persist) is where the training plane's trials/hour lives; where its
+time goes today is ``PERF.md`` §5 (the image zoo's short trials have not
+been re-measured since 2026-07-31: ``PERF.md`` §7 row 1). These series
+make the breakdown measurable the same way the serving stage histogram
+did for the frontend:
 
 - ``rafiki_tpu_trial_phase_seconds{phase=}`` — wall time per phase,
   every one timed by ``span`` below (``PHASES`` lists them with their
@@ -21,7 +22,8 @@ serving stage histogram did for the frontend:
   host dataset cache (``model/dataset.py``) and device staging cache
   (``model/jax_model.py``) hit/miss/eviction counters. Trial 2..N of a
   job performing ZERO disk loads and ZERO full-dataset H2D shows up as
-  misses staying flat while hits grow (the bench's regression check).
+  misses staying flat while hits grow (``tests/test_trial_pipeline.py::
+  test_trial_2_zero_disk_loads_and_zero_h2d``).
 - ``rafiki_tpu_trial_step_cache_total{event=hit|miss}`` — lookups of
   the compiled-step cache (``model/jax_model.py:_step_cache_get``), one
   per train / init / eval program a trial asks for, whatever the model
@@ -45,8 +47,8 @@ serving stage histogram did for the frontend:
 Stdlib-only (this module is imported by ``model/dataset.py``, which
 must stay importable without jax). Labels are bounded: phase names and
 cache event kinds only — deliberately NOT per-trial, so the families
-never need per-trial series cleanup and the bench can read cumulative
-sums across a whole window.
+never need per-trial series cleanup and the benchmark can read
+cumulative sums across a whole window.
 """
 
 from __future__ import annotations
@@ -215,8 +217,8 @@ def set_cache_bytes(cache: str, n_bytes: int) -> None:
 
 
 def cache_counts(cache: str) -> Dict[str, int]:
-    """Current {event: count} for one cache family — what the bench's
-    zero-disk-load / zero-H2D regression check reads."""
+    """Current {event: count} for one cache family — what the
+    zero-disk-load / zero-H2D tests and ``GET /trial_phases`` read."""
     m = _reg()[f"{cache}_cache"]
     return {labels.get("event", ""): int(v) for labels, v in m.samples()}
 
@@ -242,8 +244,8 @@ def moe_counts() -> Dict[str, int]:
 
 def phase_totals() -> Dict[str, Dict[str, float]]:
     """{phase: {"sum": seconds, "count": n}} — snapshot-diffable, which
-    is how the benchmark's readers (``benchmarks/metrics/``) and
-    ``bench.py --config trials`` derive a per-trial phase breakdown."""
+    is how the benchmark's readers (``benchmarks/metrics/``) derive a
+    per-trial phase breakdown."""
     h = _reg()["phase"]
     return {p: {"sum": h.sum(phase=p), "count": h.count(phase=p)}
             for p in PHASES}

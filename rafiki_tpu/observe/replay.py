@@ -25,8 +25,9 @@ stragglers; per-bin arrival attribution is uniform (every admitted
 request scatters to every bin — the recorder sees the frontend, not the
 scatter plan), so ``JobSignals.bins`` stays None and the policy runs
 its per-job fallback ordering. Treat absolute numbers as calibrated
-estimates (``bench.py --config replay`` measures the gap against a
-live stack); treat POLICY COMPARISONS — the regression gate — as the
+estimates (the gap against a live stack is not measured: no serving
+cell yet, ``PERF.md`` §7); treat POLICY COMPARISONS — the regression
+gate — as the
 load-bearing output.
 
 Determinism: one ``random.Random(seed)`` drives every sample, the event
@@ -123,7 +124,7 @@ class FleetModel:
         Unlike :meth:`from_exposition` — the device-kernel histogram —
         this includes the scatter/gather and HTTP overhead the edge
         actually pays per dispatch, so it is the fit calibration runs
-        compare against a LIVE p99 (``bench.py --config replay``).
+        compare against a LIVE p99.
         None when the trace carries no served compute samples."""
         comp = sorted(float(r.get("compute_ms") or 0.0) / 1e3
                       for r in trace

@@ -13,7 +13,7 @@ r6 grew this as a bespoke dict; it is now a facade over
 ``rafiki_tpu_serving_*`` (labeled by the frontend's short service id,
 so two predictors in one resident-runner process stay separable) and
 ``GET /stats`` and ``GET /metrics`` read the SAME source. ``snapshot``
-keeps its r6 shape (the bench and dashboard consume it) and adds
+keeps its r6 shape (the dashboard consumes it) and adds
 bucket-derived p50/p95 per stage.
 
 Still cheap enough to always be on: a lock and a few adds per

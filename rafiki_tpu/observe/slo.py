@@ -14,8 +14,8 @@ budget window:
 - ``latency``: "at least ``target`` of requests complete within
   ``threshold_ms``" — evaluated from histogram BUCKET DELTAS via the
   same cumulative-bucket interpolation ``bucket_percentile`` uses, so
-  the SLO plane judges exactly what the bench and the autoscaler
-  already trust. Scoped ``job`` (the predictor's ``/predict`` http
+  the SLO plane judges exactly what the autoscaler already trusts.
+  Scoped ``job`` (the predictor's ``/predict`` http
   histogram), ``bin`` (the r17 worker-side per-bin device-time
   histogram) or ``tenant`` (the tenant-labeled request-latency
   histogram the attribution ledger records at the frontend).

@@ -478,8 +478,8 @@ def _write_lines(lines: List[str]) -> None:
     if wrote:
         # Counted at WRITE time (outside the sink lock), so a tail-
         # buffered span only counts once its trace's verdict actually
-        # lands it in the store — the bench's overhead delta reads
-        # spans that exist, not spans that were considered.
+        # lands it in the store — the counter reads spans that
+        # exist, not spans that were considered.
         from . import metrics
 
         metrics.registry().counter(
@@ -1058,7 +1058,7 @@ def exemplar_ok(ctx: TraceContext) -> bool:
 
 
 def seed_tail(seed: int) -> None:
-    """Deterministic tail-sampling decisions (tests / seeded bench)."""
+    """Deterministic tail-sampling decisions (tests)."""
     global _tail_rng
     with _tail_lock:
         _tail_rng = random.Random(seed)

@@ -4,10 +4,9 @@ The packed serving path (docs/serving.md "Wire format & quantization")
 claims two things a throughput number on a noisy box cannot prove: the
 bytes that actually ride the bus shrink, and the per-burst host copies
 (per-query decode, ``np.stack``, pad-``concatenate``) disappear. These
-counters ARE that evidence — `bench.py --config serving-concurrent`
-judges its packed A/B on their deltas, per the r9 discipline (counter
-breakdowns are the stable signal on a 1-device box; throughput ratios
-are noise).
+counters ARE that evidence (``tests/test_wire_codec.py::
+test_packed_wire_bytes_materially_lower`` pins their deltas; what the
+packed wire is worth in time is not measured: ``PERF.md`` §7 row 5).
 
 - ``rafiki_tpu_serving_wire_bytes_total{format=packed|perquery,
   direction=scatter|reply}`` — estimated serialized payload bytes at
@@ -23,8 +22,8 @@ are noise).
 - ``rafiki_tpu_serving_stacked_dispatch_total{mode=stacked|fallback}``
   + ``rafiki_tpu_serving_dispatches_per_query_ratio`` — the stacked-
   ensemble dispatch evidence (worker-side; own lazy family gated on
-  ``RAFIKI_TPU_SERVING_STACKED``, so the stacked-off side of the
-  bench A/B exposes zero stacked series).
+  ``RAFIKI_TPU_SERVING_STACKED``, so a stacked-off process exposes
+  zero stacked series).
 
 Gating (the r11 disabled-means-free discipline): the wire/copies
 family exists only while ``RAFIKI_TPU_SERVING_PACKED_WIRE`` is not
@@ -32,10 +31,9 @@ family exists only while ``RAFIKI_TPU_SERVING_PACKED_WIRE`` is not
 paths pay one function call + one None check. ``compat`` keeps the
 accounting while disabling packed *emission/advertisement* (each
 Cache/worker/predictor snapshots the mode at construction), which is
-both the bench's measured legacy side and an operational kill switch
-that keeps observability. Labels are bounded static vocabularies, so
-the series are deliberately process-immortal (no per-instance label to
-remove).
+an operational kill switch that keeps observability. Labels are
+bounded static vocabularies, so the series are deliberately
+process-immortal (no per-instance label to remove).
 """
 
 from __future__ import annotations
@@ -156,8 +154,8 @@ _quant_counter = None
 #: (dispatch counter, dispatches-per-query gauge) | (None, None);
 #: lazy own family like the quant counter — registered only when a
 #: stacked-capable ensemble actually serves AND the knob is on, so a
-#: stacked-off process (the bench A/B's off side) exposes ZERO stacked
-#: series.
+#: stacked-off process exposes ZERO stacked series
+#: (``tests/test_stacked.py::test_stacked_off_zero_series``).
 _stacked_state: Optional[Tuple] = None
 _lock = threading.Lock()
 

@@ -635,9 +635,9 @@ def flash_attention(q, k, v, *, causal: bool = False, block_q: int = 1024,
     """Pallas-kernel attention (TPU); the interpreter on the CPU.
 
     Default block sizes (1024/1024) come from a sweep on one v5e chip
-    early in the project; the last recorded figure is 92.8 TFLOP/s bf16
-    on causal T=8192 (``BENCH_r05.json``, 2026-07-31) and the kernel
-    has not been measured on today's code (``PERF.md``).
+    early in the project, not re-measured since 2026-07-31; what the
+    kernels reach on today's code is ``PERF.md`` §5 (roofline shares)
+    and §7 (block readings at 1 x 32 x 8192).
     ``kv_mask`` (B, Tkv) bool, True = real token. ``v`` may have
     another width than q and k: the result has v's, the scale is
     1/sqrt(q's).

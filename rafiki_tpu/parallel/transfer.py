@@ -19,8 +19,8 @@ buffer (TPU runtimes expose it as the ``pinned_host`` memory kind;
 a pageable source forces the runtime to bounce through its own pinned
 pool first) and falls back silently to a plain ``device_put`` where
 the memory space doesn't exist. The worker records which path is live
-in its bus registration (``staging``) so bench artifacts can tell what
-was measured.
+in its bus registration (``staging``) so a reader of a run can tell
+what was measured.
 """
 
 from __future__ import annotations

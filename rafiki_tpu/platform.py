@@ -122,7 +122,7 @@ class LocalPlatform:
                            datasets_dir=os.path.join(workdir, "datasets"))
         # Metrics-driven autoscaler (docs/autoscaling.md): constructed
         # ONLY when RAFIKI_TPU_AUTOSCALE is on (NodeConfig.apply_env
-        # exports it; env is the transport so tests/bench flip it the
+        # exports it; env is the transport so tests flip it the
         # same way the serve CLI does). Off = services.autoscaler stays
         # None: supervise pays one attribute check, zero new series.
         self.autoscaler = None

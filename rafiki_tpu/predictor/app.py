@@ -21,7 +21,8 @@ scatter: a continuous micro-batcher (``predictor/batcher.py``)
 coalesces everything arriving within one fill window into a single
 scatter-gather super-batch and slices the ensembled results back out
 per request. ``RAFIKI_TPU_SERVING_MICROBATCH=0`` restores the direct
-one-scatter-per-request path (the bench's A/B comparison rides this).
+one-scatter-per-request path (``tests/test_predictor_batcher.py::
+test_microbatch_disabled_restores_direct_path``).
 """
 
 from __future__ import annotations
@@ -109,8 +110,9 @@ class PredictorService:
         # Cluster cache fabric (docs/cluster.md): construction-time
         # snapshot, active only when BOTH the fabric and the edge cache
         # are on. Off (the default) = plain bool checks on the miss
-        # path, no frontend registration, zero fabric series — the
-        # bench's fabric-off side asserts exactly that.
+        # path, no frontend registration, zero fabric series
+        # (tests/test_cluster.py::
+        # test_single_node_construction_has_no_cluster_surface).
         self._fabric = False
         self._fabric_probe_timeout = 0.25
         self._m_fabric = None
@@ -289,8 +291,8 @@ class PredictorService:
         snap = self.stats.snapshot()
         snap["microbatch"] = self.microbatch
         # The HTTP layer's own series (rafiki_tpu_http_request_seconds)
-        # label by the server name — expose it so /metrics readers (the
-        # bench) can match this frontend's series without guessing.
+        # label by the server name — expose it so /metrics readers
+        # can match this frontend's series without guessing.
         snap["http_service"] = self._http.name
         snap["shard_replicas"] = self.predictor.shard_replicas
         snap["tier_threshold"] = self.predictor.tier_threshold

@@ -2,9 +2,9 @@
 
 Parity+: upstream's Predictor scatter-gathers once per incoming request
 (SURVEY.md §3.3); the reproduction kept that shape, so every concurrent
-``/predict`` paid its own worker scan + bus scatter + blocking gather —
-the r5 bench showed the serving configs frontend-bound (window spread
-0.4-0.6 vs ~0.001 for compute-bound configs). This module puts ONE
+``/predict`` paid its own worker scan + bus scatter + blocking gather
+(what that costs on the chip is not measured: ``PERF.md`` §7 row 5).
+This module puts ONE
 shared admission queue between the HTTP handlers and the Predictor:
 
 - **Coalescing.** All requests arriving within a short fill window (or

@@ -282,7 +282,7 @@ class Predictor:
         # Packed emission is a construction-time snapshot
         # (NodeConfig.serving_packed_wire): "on" packs toward
         # advertising workers; "compat"/"off" keep per-query frames
-        # (compat keeps the wire accounting — the bench's legacy side).
+        # (compat keeps the wire accounting).
         self._packed_wire = _wire_obs.packed_wire_mode() == "on"
         # bin -> tracked eval score (from worker registration info; the
         # tiered path's "best bin"). Keyed by bin, bounded by the

@@ -585,16 +585,14 @@ class InferenceWorker:
             # treat same-bin workers as REPLICAS (one is chosen per
             # request) instead of extra ensemble members. The pipeline
             # decision (and the measured sync latency that drove an
-            # "auto" decision) rides along so artifact readers — the
-            # bench record in particular — can tell which serving mode
-            # was actually measured (r4 verdict: the auto decision was
-            # logged but unrecoverable from the bench artifact).
+            # "auto" decision) rides along so a reader of the
+            # registration can tell which serving mode actually ran.
             # "wire" is the packed-format negotiation: only workers
             # that LIST ndbatch1 ever receive packed frames, so an old
             # worker (no key) and a compat-mode one are
             # indistinguishable to the predictor — both keep the
             # per-query format. "quant" records what this worker
-            # actually serves (bench/debug evidence, not negotiation).
+            # actually serves (debug evidence, not negotiation).
             # "stacked" advertises that this worker's multi-member bin
             # serves via ONE vmapped program — the admin's promote
             # path may then restack a single member in place
@@ -612,7 +610,7 @@ class InferenceWorker:
             # pick a worker); None for classifier bins or when the
             # gate is off. "staging" records which host→device path
             # the per-step token upload actually took (pinned vs
-            # pageable — bench evidence, not negotiation).
+            # pageable — debug evidence, not negotiation).
             gen_info = self._start_generate() if self._gen_enabled \
                 else None
             self._reg_info = {"trial_id": self.trial_id,

@@ -1,7 +1,7 @@
 """TrialRunner: the propose → train → evaluate → persist hot loop.
 
 Parity: SURVEY.md §3.1 — the system's primary hot loop, factored out of the
-TrainWorker so the same code runs in-process (tests, ``bench.py``, local
+TrainWorker so the same code runs in-process (tests, local
 dev — upstream's ``test_model_class`` writ large) and inside a distributed
 TrainWorker bound to a chip group. The runner is advisor-transport-agnostic:
 it accepts anything with ``propose()/feedback()`` (an in-process advisor or
@@ -405,7 +405,7 @@ class TrialRunner:
                 proposal=proposal.to_json(), trial_id=trial_id)
 
             # Save + chain whatever sink this thread already had (a
-            # bench harness's utilization probe, a test capture): the
+            # test capture, a caller's probe): the
             # trial's records go to the meta store AND keep flowing
             # outward, and the prior binding is restored afterwards
             # instead of nulled. With the persist pipeline on, the

@@ -9,7 +9,7 @@ import os
 
 # Tests run on the CPU, always: the sharding tests need 8 virtual
 # devices, and pytest must never hold the chip (one process per chip —
-# the chip is reached only through chip_smoke.py / bench.py).
+# the chip is reached only through chip_smoke.py / benchmarks/run.py).
 xla_flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in xla_flags:
     os.environ["XLA_FLAGS"] = (

@@ -867,7 +867,6 @@ def test_cli_json_exit_zero():
     data = json.loads(proc.stdout)
     assert data["new"] == 0
     assert data["files"] > 50
-    # per-code counts is what bench.py --config analysis records
     assert all(k.startswith("RTA") for k in data["counts_per_code"])
 
 

@@ -455,8 +455,8 @@ def test_profile_control_frame_on_live_worker(tmp_path, ledger_off):
         assert cache.running_workers("job") == ["psvc"]
 
         def ask(n, tag):
-            bid = cache.send_query_batch("psvc", list(range(n)),
-                                         batch_id=f"{tag}")
+            bid = cache.send_query_batch_fanout(
+                ["psvc"], list(range(n)), batch_id=f"{tag}")
             replies = cache.gather_prediction_batches(bid, 1,
                                                       timeout=10)
             assert replies and len(replies[0]["predictions"]) == n, tag

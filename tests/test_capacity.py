@@ -253,8 +253,7 @@ def test_policy_gate_is_deterministic():
 def test_predictive_scale_ahead_ab_in_sim():
     """Reactive vs predictive on the canned ramp under a slow-
     provisioning fleet: the predictive side must apply >= 1
-    ``scale_up:predicted`` and reject STRICTLY fewer (bench.py
-    --config replay runs the same A/B as its third act)."""
+    ``scale_up:predicted`` and reject STRICTLY fewer."""
     trace = capacity.canned_trace("ramp")
     table = capacity.learn_periodicity(trace, period_s=120.0,
                                        bin_s=10.0)

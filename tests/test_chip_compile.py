@@ -28,7 +28,7 @@ from rafiki_tpu.parallel import build_mesh, replicated
 
 HBM_BYTES = 16e9  # one v5e chip
 
-#: The repo's flagship LM width (bench.py roofline config), depth 8.
+#: The repo's flagship LM width (chip_smoke.py's FLAGSHIP), depth 8.
 FLAGSHIP = {"d_model": 2048, "n_layers": 8, "seq_len": 2048,
             "vocab_size": 32768}
 
@@ -86,9 +86,9 @@ _KERNEL_SHAPES = {
     # flagship LM step: B4·H16·T2048·D128 bf16 causal
     "flagship": ((4, 16, 2048, 128), jnp.bfloat16, {"causal": True},
                  False),
-    # bench.py attention config: B2·H8·T8192·D128
-    "bench_t8192": ((2, 8, 8192, 128), jnp.bfloat16, {"causal": True},
-                    False),
+    # a long sequence: B2·H8·T8192·D128
+    "long_t8192": ((2, 8, 8192, 128), jnp.bfloat16, {"causal": True},
+                   False),
     # ViT-shaped: 197 tokens (not a block multiple), key-padding mask
     "masked_197x64": ((8, 4, 197, 64), jnp.bfloat16, {}, True),
     # Small explicit blocks with nq > 1: block_q is the backward
@@ -116,7 +116,7 @@ def _qkv_shapes(shape_name, sharding):
 
 @pytest.mark.parametrize("case", [
     "flagship-fwd", "flagship-grad",
-    "bench_t8192-fwd",  # the bench config times the forward only
+    "long_t8192-fwd",
     "masked_197x64-fwd", "masked_197x64-grad",
     "small_blocks-fwd", "small_blocks-grad",
     "mla-fwd", "mla-grad"])

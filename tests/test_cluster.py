@@ -5,9 +5,7 @@ relay, the shared edge-cache fabric, per-node scrape grouping, and the
 The zero-series / zero-thread contract is asserted at the CONSTRUCTION
 level here (``relay_counter is None``, ``_fabric is False``) rather
 than by grepping the process-global metrics registry, because sibling
-tests in one pytest process legitimately register cluster series; the
-registry-global form of the contract is asserted by
-``bench.py --config cluster``, which owns its process.
+tests in one pytest process legitimately register cluster series.
 """
 
 import os

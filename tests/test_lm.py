@@ -1,4 +1,5 @@
-"""JaxTransformerLM — the flagship causal LM (roofline config model).
+"""JaxTransformerLM — the flagship causal LM (the model of the
+benchmark's ``lm14-*`` cells).
 
 No reference counterpart (upstream Rafiki has no LM task — SURVEY.md
 §2); the model exists to give the platform a compute-dense training

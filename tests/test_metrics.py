@@ -17,7 +17,6 @@ import requests
 from rafiki_tpu.observe.metrics import (Counter, Gauge, Histogram,
                                         MetricsRegistry,
                                         bucket_percentile,
-                                        histogram_percentiles_ms,
                                         label_context, bound_labels,
                                         metrics_enabled,
                                         parse_exposition, registry,
@@ -136,19 +135,6 @@ def test_bucket_percentile_edge_cases():
     # single bucket, all mass: interpolates within [0, bound]
     assert bucket_percentile([(2.0, 10), (math.inf, 10)], 0.5) == \
         pytest.approx(1.0)
-
-
-def test_histogram_percentiles_ms_filters_labels():
-    reg = MetricsRegistry()
-    h = reg.histogram("rafiki_tpu_node_f_seconds", buckets=(0.1, 1.0))
-    h.observe(0.05, service="a", stage="fill")
-    h.observe(0.5, service="b", stage="fill")
-    samples = parse_exposition(reg.expose())[
-        "rafiki_tpu_node_f_seconds_bucket"]
-    p_a = histogram_percentiles_ms(samples, qs=(0.5,), service="a")
-    p_b = histogram_percentiles_ms(samples, qs=(0.5,), service="b")
-    assert p_a[0] <= 100.0 < p_b[0]
-    assert histogram_percentiles_ms(samples, service="zzz") is None
 
 
 # --- Label context (per-trial attribution) ---

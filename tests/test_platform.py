@@ -404,7 +404,7 @@ def test_supervisor_thread_sweeps_automatically(tmp_path, synth_image_data):
 
 def test_inference_pipeline_env_toggle(monkeypatch):
     """RAFIKI_TPU_SERVING_PIPELINE: 0/1 force the one-burst-in-flight
-    overlap off/on (the bench's on-vs-off comparison rides this);
+    overlap off/on;
     the default "auto" defers to a startup sync-latency measurement
     (pipeline is None until the worker's run() resolves it)."""
     from rafiki_tpu.bus import MemoryBus

@@ -160,8 +160,7 @@ def test_vit_stacked_parity_and_int8_accuracy():
 
 def test_cnn_int8_close_to_f32():
     """The conv zoo's dequant-free path (dynamic_int8_conv): int8
-    serving stays within tolerance of f32 — the model-level face of
-    the bench accuracy-delta gate."""
+    serving stays within tolerance of f32."""
     m = _member(JaxCnn, 0, width_16ths=8)
     q = _queries(_SHAPES[JaxCnn])
     p32 = np.asarray(m.predict_proba(q))
@@ -315,7 +314,7 @@ _STACKED_METRICS = ("rafiki_tpu_serving_stacked_dispatch_total",
 
 def test_stacked_off_zero_series(fresh_registry, monkeypatch):
     """RAFIKI_TPU_SERVING_STACKED=off ⇒ per-member serving and NO
-    stacked series at all (the bench A/B's off-side assertion)."""
+    stacked series at all."""
     monkeypatch.setenv(obs_wire.STACKED_ENV, "off")
     obs_wire.reset_for_tests()
     assert not obs_wire.stacked_mode()

@@ -125,7 +125,7 @@ def test_trace_rides_bus_envelope(bus):
 def test_ambient_context_injected_on_direct_path(bus):
     cache = Cache(bus)
     with trace.use(trace.TraceContext("beef" * 8)):
-        cache.send_query_batch("wC", [1, 2])
+        cache.send_query_batch_fanout(["wC"], [1, 2])
         cache.send_query("wC", 3)
     items = cache.pop_queries("wC", timeout=5.0)
     assert len(items) == 2

@@ -528,7 +528,7 @@ def test_compat_mode_accounts_without_packing(fresh_registry,
 
 def test_packed_wire_bytes_materially_lower(fresh_registry,
                                             monkeypatch):
-    """The bench's judged claim, pinned as a unit property: the same
+    """The packed wire's claim, pinned as a unit property: the same
     super-batch costs materially fewer wire bytes packed than
     per-query (framing overhead amortizes to one header per shard)."""
     monkeypatch.setenv(obs_wire.PACKED_WIRE_ENV, "on")
