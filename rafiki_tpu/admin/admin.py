@@ -854,7 +854,8 @@ class Admin:
                   for c in obs_phases.CACHES}
         return {"enabled": obs_metrics.metrics_enabled(),
                 "resident": resident, "phases": phases,
-                "caches": caches, "moe": obs_phases.moe_counts()}
+                "caches": caches, "moe": obs_phases.moe_counts(),
+                "dump_leaves": obs_phases.dump_leaf_counts()}
 
     def get_autoscale(self) -> Dict[str, Any]:
         """The autoscaler's decision ring + per-bin targets (the

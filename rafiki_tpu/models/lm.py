@@ -482,9 +482,9 @@ class JaxTransformerLM(BaseModel):
                         meter.mfu, **_obs_metrics.bound_labels())
             logger.log(step=done, loss=float(loss_acc[0]),
                        token_acc=float(loss_acc[1]), **util)
-        # Params stay DEVICE-RESIDENT: dump_parameters materializes
-        # the 1.9 GB on the host only when something (param store,
-        # checkpoint) actually needs them.
+        # Params stay DEVICE-RESIDENT: dump_parameters hands them on
+        # as device arrays, and they cross to the host only where
+        # something (the persist stage, a checkpoint) needs the bytes.
         self._params = params
         self._invalidate_compiled()
 
@@ -622,9 +622,16 @@ class JaxTransformerLM(BaseModel):
 
     def dump_parameters(self) -> Params:
         """The parameter tree, nested to any depth, under flat
-        ``a/b`` names (``layers/qkv``)."""
+        ``a/b`` names (``layers/qkv``). A leaf on the device is
+        returned as the ``jax.Array`` it is: whoever needs the bytes
+        calls ``np.asarray`` (the trial runner's persist stage does,
+        leaf by leaf behind the next trial's steps; ``model/dev.py``
+        does at once). No copy is started here: with every leaf's
+        ``copy_to_host_async`` queued at once, the next trial's set-up
+        waited 0.3 s behind them on a v5e (PERF.md §6, PR 33)."""
         assert self._params is not None
-        return {name: np.asarray(leaf)
+        return {name: leaf if isinstance(leaf, jax.Array)
+                else np.asarray(leaf)
                 for name, leaf in _flat_names(self._params).items()}
 
     def load_parameters(self, params: Params) -> None:

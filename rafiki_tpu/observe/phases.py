@@ -30,6 +30,13 @@ did for the frontend:
   class. A job of congruent trials shows its misses staying flat after
   the first trial; a miss per trial is a program built (and, where its
   constants differ, compiled) per trial.
+- ``rafiki_tpu_trial_dump_leaves_total{where=device|host}`` — how a
+  finished trial's parameters reached the persist stage
+  (``dump_leaves``, once a trial, by leaf): still on the device, so
+  that the stage copies them to the host behind the next trial's steps
+  (span ``persist``, attrs ``d2h_ms`` / ``d2h_bytes``), or already host
+  arrays, which the model paid for inside span ``dump`` with the chip
+  idle. A runner without the stage counts nothing.
 - ``rafiki_tpu_moe_assignments_total{where=held|absent}`` and
   ``rafiki_tpu_moe_busiest_expert_total`` — what a sparse-expert
   model's train steps routed (``moe_routed``, once a dispatch, from the
@@ -77,12 +84,15 @@ from . import trace as _trace
 #:                      (its trace and compile on a step-cache miss)
 #:       step_wait      LM, each chunk: host blocked on the device
 #:     eval           model.evaluate
-#:     dump           model.dump_parameters()
+#:     dump           model.dump_parameters(): the leaves handed on
+#:                    (LM, image zoo: device arrays as they lie, no
+#:                    copy started, no wait for the bytes)
 #:     feedback       advisor.feedback
 #:     handover       wait for the previous trial's tail to leave the
 #:                    persist stage (pipeline off: contains persist)
-#:   persist          the tail: log flush, param save, meta commit
-#:                    (persist thread; overlaps the NEXT trial)
+#:   persist          the tail: log flush, the device leaves' copy to
+#:                    the host, param save, meta commit (persist
+#:                    thread; overlaps the NEXT trial)
 PHASES = ("trial", "propose", "open", "init", "train", "load", "stage",
           "step_setup", "step_dispatch", "step_wait", "eval", "dump",
           "feedback", "handover", "persist")
@@ -118,6 +128,12 @@ def _reg() -> Dict[str, object]:
             "step_cache": r.counter(
                 "rafiki_tpu_trial_step_cache_total",
                 "Compiled-step cache lookups (event=hit|miss)"),
+            "dump_leaves": r.counter(
+                "rafiki_tpu_trial_dump_leaves_total",
+                "Parameter leaves of finished trials as the persist "
+                "stage found them (where=device: a jax.Array, copied "
+                "to the host there, behind the next trial; host: the "
+                "model had copied it inside its dump)"),
             "moe_assignments": r.counter(
                 "rafiki_tpu_moe_assignments_total",
                 "Token-to-expert assignments a sparse-expert model's "
@@ -219,8 +235,27 @@ def set_cache_bytes(cache: str, n_bytes: int) -> None:
 def cache_counts(cache: str) -> Dict[str, int]:
     """Current {event: count} for one cache family — what the
     zero-disk-load / zero-H2D tests and ``GET /trial_phases`` read."""
-    m = _reg()[f"{cache}_cache"]
-    return {labels.get("event", ""): int(v) for labels, v in m.samples()}
+    return _by_label(_reg()[f"{cache}_cache"], "event")
+
+
+def _by_label(counter: Any, label: str) -> Dict[str, int]:
+    return {labels.get(label, ""): int(value)
+            for labels, value in counter.samples()}
+
+
+def dump_leaves(device: int, host: int) -> None:
+    """One finished trial's leaves, as its model handed them to the
+    persist stage."""
+    if metrics.metrics_enabled():
+        m = _reg()["dump_leaves"]
+        m.inc(device, where="device")
+        m.inc(host, where="host")
+
+
+def dump_leaf_counts() -> Dict[str, int]:
+    """{"device", "host"}: this process's cumulative totals."""
+    return {"device": 0, "host": 0,
+            **_by_label(_reg()["dump_leaves"], "where")}
 
 
 def moe_routed(held: float, absent: float, busiest: float) -> None:
@@ -235,11 +270,9 @@ def moe_routed(held: float, absent: float, busiest: float) -> None:
 def moe_counts() -> Dict[str, int]:
     """{"held", "absent", "busiest"}: this process's cumulative totals."""
     m = _reg()
-    out = {"held": 0, "absent": 0,
-           "busiest": int(sum(v for _, v in m["moe_busiest"].samples()))}
-    for labels, value in m["moe_assignments"].samples():
-        out[labels.get("where", "")] = int(value)
-    return out
+    return {"held": 0, "absent": 0,
+            "busiest": int(sum(v for _, v in m["moe_busiest"].samples())),
+            **_by_label(m["moe_assignments"], "where")}
 
 
 def phase_totals() -> Dict[str, Dict[str, float]]:

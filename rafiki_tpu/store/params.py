@@ -41,6 +41,13 @@ lock after the flush and unlinks its own file when the save was
 deleted mid-flight — no orphaned ``.safetensors``, no row without a
 file.
 
+Who still saves device leaves: a ``TrialRunner`` without its persist
+stage (the inline tail) and direct callers. The stage
+(``worker/runner.py:_to_host``) copies a finished trial's leaves to the
+host itself, behind the next trial's steps, and hands this store host
+arrays, so under a TrainWorker ``save`` writes file and row before it
+returns and a trial is COMPLETED only once both exist.
+
 Durability is unchanged in kind: a crash between ``save`` returning
 and the flush landing loses that save — exactly the window a crash
 mid-``save_file`` always had, a few hundred ms wider.

@@ -23,6 +23,9 @@ import uuid
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional, Type
 
+import jax
+import numpy as np
+
 from ..advisor.base import Proposal
 from ..constants import BudgetOption, TrialStatus
 from ..model.base import BaseModel
@@ -38,8 +41,9 @@ _log = logging.getLogger(__name__)
 
 class _PersistStage:
     """Single-slot background stage for the completed-trial persist
-    tail (trial-log flush, ``ParamStore.save`` hand-off,
-    ``mark_trial_completed``, spent-checkpoint sweep).
+    tail (trial-log flush, the dumped parameters' copy off the device,
+    ``ParamStore.save``, ``mark_trial_completed``, spent-checkpoint
+    sweep).
 
     Exactly ONE trial's tail may be in flight: ``submit`` first waits
     for the previous tail to finish — strict per-trial ordering (trial
@@ -479,11 +483,13 @@ class TrialRunner:
                 # weights but keeps writing its own lineage).
                 save_scope = proposal.meta.get("params_save_scope") \
                     or params_scope
-                # What ``dump`` costs is the model's choice: the LM
-                # pulls every leaf to the host here, synchronously
-                # (np.asarray, 1.6 GB at the benchmark's widths); a
-                # model that returns device arrays leaves the D2H to
-                # the ParamStore write-behind on the persist thread.
+                # ``dump`` is the model handing its leaves on. The LM
+                # and the image zoo return them as they lie on the
+                # device, no copy started: the persist stage turns
+                # them into host arrays behind the next trial's steps
+                # (``_to_host``), or, with no stage, the ParamStore's
+                # write-behind writer pulls them. A model that returns
+                # host arrays paid for them here, with the chip idle.
                 with span("dump"):
                     dumped = model.dump_parameters()
             finally:
@@ -551,9 +557,10 @@ class TrialRunner:
                       ckpt_tomb: Optional[str],
                       span: Callable[..., Any]) -> None:
         """The completed-trial persist tail: flush the buffered trial
-        logs, hand the dumped parameters to the ParamStore, mark the
-        trial COMPLETED, sweep the spent (already tombstone-renamed)
-        crash-resume checkpoint dir.
+        logs, copy the dumped parameters off the device (on the stage
+        only), hand them to the ParamStore, mark the trial COMPLETED,
+        sweep the spent (already tombstone-renamed) crash-resume
+        checkpoint dir.
 
         Runs inline when the pipeline is off; on the single-slot
         persist stage otherwise — trial N+1's propose/validate/init
@@ -578,6 +585,14 @@ class TrialRunner:
                 for rec in log_buffer:
                     self.meta.add_trial_log(trial_id, rec)
                 took["log_flush_ms"] = ms_since(t)
+                if self._persist is not None:
+                    # The stage owns the copy off the device, so the
+                    # store sees host arrays and writes file and index
+                    # row before it returns: the row below turns
+                    # COMPLETED only once both exist.
+                    t = time.monotonic()
+                    took["d2h_bytes"] = _to_host(dumped)
+                    took["d2h_ms"] = ms_since(t)
                 t = time.monotonic()
                 params_id = self.params.save(
                     dumped, session_id=self.sub_train_job_id,
@@ -644,6 +659,24 @@ class TrialRunner:
              "knobs": _jsonable_knobs(knobs)},
             sort_keys=True, default=str).encode()).hexdigest()[:16]
         return os.path.join(self.params.params_dir, "ckpt", digest)
+
+
+def _to_host(dumped: Dict[str, Any]) -> int:
+    """Turn the device leaves of ``dumped`` into host arrays, in place
+    and leaf by leaf: a leaf's device buffer is dropped as soon as its
+    host copy exists, so what a finished trial keeps on the device
+    only shrinks while the next trial trains. (Not the packed pull of
+    ``parallel.device_get_tree``: it concatenates a second copy of the
+    whole tree on the device first.) Returns the bytes copied, and
+    counts the leaves by where the stage found them."""
+    n_bytes = n_device = 0
+    for name in dumped:
+        if isinstance(dumped[name], jax.Array):
+            dumped[name] = np.asarray(dumped[name])
+            n_bytes += dumped[name].nbytes
+            n_device += 1
+    _phases.dump_leaves(device=n_device, host=len(dumped) - n_device)
+    return n_bytes
 
 
 def _jsonable_knobs(knobs: Dict[str, Any]) -> Dict[str, Any]:

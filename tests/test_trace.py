@@ -629,6 +629,7 @@ def test_admin_trace_route_and_metrics(tmp_path):
             "stage", "step_setup", "step_dispatch", "step_wait", "eval",
             "dump", "feedback", "handover", "persist"}
         assert set(tp["caches"]) == {"dataset", "stage", "step"}
+        assert set(tp["dump_leaves"]) == {"device", "host"}
         assert "resident" in tp and "enabled" in tp
         assert requests.get(base + "/trial_phases",
                             timeout=10).status_code == 401

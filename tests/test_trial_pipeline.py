@@ -433,3 +433,320 @@ def test_failed_final_tail_refunds_budget_slot(tmp_path, monkeypatch):
     assert by_status.get(TrialStatus.ERRORED) == 1, by_status
     meta.close()
     params.close()
+
+
+# --- The persist stage owns the copy off the device ---
+
+LM_TINY = {"d_model": 256, "n_layers": 2, "seq_len": 256, "batch_size": 4,
+           "learning_rate": 1e-2, "train_steps": 8, "vocab_size": 512,
+           "quick_train": False}
+MOE_TINY = {"d_model": 64, "n_heads": 4, "n_layers": 3,
+            "n_dense_layers": 1, "seq_len": 32, "vocab_size": 96,
+            "q_lora_rank": 48, "kv_lora_rank": 32, "qk_nope_head_dim": 16,
+            "qk_rope_head_dim": 8, "v_head_dim": 16, "ffn_dense": 160,
+            "ffn_expert": 48, "n_experts": 8, "experts_per_token": 2,
+            "experts_held": 4, "first_expert": 2, "n_shared_experts": 1,
+            "routed_scaling": 2.5, "rope_theta": 32e6, "rms_eps": 1e-6,
+            "mtp_depth": 1, "mtp_weight": 0.3, "bias_rate": 0.001,
+            "batch_size": 8, "learning_rate": 1e-3, "train_steps": 4,
+            "steps_per_dispatch": 2, "remat": "dots",
+            "quick_train": False, "seed": 5}
+
+
+def _pinned(base, knobs):
+    """``base`` with every knob of ``knobs`` fixed; the class keeps each
+    dict its ``dump_parameters`` handed on (and so the device leaves)."""
+    class Pinned(base):
+        dumps = []
+
+        @staticmethod
+        def get_knob_config():
+            config = dict(base.get_knob_config())
+            config.update({k: FixedKnob(v) for k, v in knobs.items()})
+            return config
+
+        def dump_parameters(self):
+            out = super().dump_parameters()
+            type(self).dumps.append(dict(out))
+            return out
+
+    return Pinned
+
+
+def _lm_case(name):
+    from rafiki_tpu.models import JaxLatentMoELM, JaxTransformerLM
+
+    return {"dense": (JaxTransformerLM, LM_TINY, 512),
+            "latent-moe": (JaxLatentMoELM, MOE_TINY, 96)}[name]
+
+
+def test_lm_dump_hands_on_device_leaves_and_they_load_alike(tmp_path):
+    from rafiki_tpu.models import JaxTransformerLM
+    from rafiki_tpu.models.lm import _flat_names
+
+    p = _write_tokens(tmp_path / "tok.npz", n=4000)
+    knobs = JaxTransformerLM.validate_knobs(dict(LM_TINY, train_steps=20))
+    m = JaxTransformerLM(**knobs)
+    m._params = m._init_params()  # training is not under test
+    dumped = m.dump_parameters()
+    assert set(dumped) == {"embed", "lnf", "layers/ln1", "layers/ln2",
+                           "layers/qkv", "layers/proj", "layers/w1",
+                           "layers/w2"}
+    named = _flat_names(m._params)
+    for name, leaf in dumped.items():
+        # the leaf itself, not a copy on the device or on the host
+        assert isinstance(leaf, jax.Array) and leaf is named[name], name
+    score = m.evaluate(p)
+    m.destroy()  # the buffers live on through ``dumped``
+    other = JaxTransformerLM(**knobs)
+    other.load_parameters(dumped)
+    assert other.evaluate(p) == score
+    np.testing.assert_array_equal(
+        np.asarray(other.dump_parameters()["layers/w2"]),
+        np.asarray(dumped["layers/w2"]))
+
+
+@pytest.mark.parametrize("case", ["dense", "latent-moe"])
+def test_persist_thread_copies_and_the_store_reads_back_bit_for_bit(
+        tmp_path, monkeypatch, case):
+    from rafiki_tpu.observe import trace
+    from rafiki_tpu.worker import runner as mod_runner
+
+    base, knobs, vocab = _lm_case(case)
+    model_class = _pinned(base, knobs)
+    p = _write_tokens(tmp_path / "tok.npz", n=4000, vocab=vocab)
+    calls = []
+    to_host = mod_runner._to_host
+
+    def watched(dumped):
+        were = {k: isinstance(v, jax.Array) for k, v in dumped.items()}
+        t0 = time.time()
+        n_bytes = to_host(dumped)
+        calls.append((threading.current_thread().name, were, t0,
+                      time.time(), n_bytes))
+        return n_bytes
+
+    monkeypatch.setattr(mod_runner, "_to_host", watched)
+    meta = MetaStore(":memory:")
+    params = ParamStore(str(tmp_path / "p"))
+    trace.configure(str(tmp_path / "logs"))
+    try:
+        runner = TrialRunner(model_class, _FixedAdvisor({}), p, p, meta,
+                             params, "sub-d2h", worker_id="w-d2h",
+                             budget={BudgetOption.MODEL_TRIAL_COUNT: 1},
+                             pipeline_persist=True)
+        (row,) = runner.run()
+        runner.close()
+    finally:
+        trace.configure(None)
+    assert row["status"] == TrialStatus.COMPLETED
+    (kept,) = model_class.dumps
+    assert all(isinstance(v, jax.Array) for v in kept.values())
+    if case == "latent-moe":
+        assert {"state/sparse_bias", "state/mtp_bias"} <= set(kept)
+    # one conversion, of every leaf, on the persist thread ...
+    ((thread, were, t0, t1, n_bytes),) = calls
+    assert thread.startswith("trial-persist")
+    assert were == {name: True for name in kept}
+    assert n_bytes == sum(v.nbytes for v in kept.values())
+    # ... inside span ``persist``, which says what it cost
+    (span,) = [s for s in trace.collect_trace(
+        str(tmp_path / "logs"), row["id"])["spans"]
+        if s["name"] == "trial.persist"]
+    assert span["start_s"] <= t0 + 1e-3
+    assert t1 <= span["start_s"] + span["dur_ms"] / 1e3 + 5e-3
+    assert span["attrs"]["d2h_bytes"] == n_bytes
+    assert 0 <= span["attrs"]["d2h_ms"] <= span["dur_ms"]
+    # the store wrote host arrays at once (nothing rode its writer) ...
+    assert params._writer is None
+    # ... and holds the trained leaves' bytes
+    stored = params.load(row["params_id"])
+    assert set(stored) == set(kept)
+    for name, leaf in kept.items():
+        mine = np.asarray(leaf)
+        assert stored[name].dtype == mine.dtype, name
+        np.testing.assert_array_equal(stored[name], mine, err_msg=name)
+    meta.close()
+    params.close()
+
+
+def _device_model(events, leaves=None):
+    """A model whose parameters lie on the device; ``leaves`` collects a
+    weak reference to each leaf it dumps."""
+    import weakref
+
+    import jax.numpy as jnp
+
+    class _OnDevice(_fake_model(events)):
+        def train(self, path, *, shared_params=None, **kw):
+            super().train(path, shared_params=shared_params, **kw)
+            self._params = {"w": jnp.arange(6.0).reshape(2, 3) + len(events),
+                            "b": jnp.ones((4,), jnp.int32),
+                            "meta": np.asarray([3, 4])}
+
+        def dump_parameters(self):
+            out = dict(self._params)
+            if leaves is not None:
+                leaves.extend(weakref.ref(v) for v in out.values()
+                              if isinstance(v, jax.Array))
+            return out
+
+    return _OnDevice
+
+
+def test_row_completes_only_after_file_and_index_row(tmp_path,
+                                                     monkeypatch):
+    """The write of trial 1 is held: its row stays RUNNING and nothing
+    of it is in the store while trial 2 trains; when the row turns
+    COMPLETED, the file and the index row are there."""
+    import os
+
+    meta = MetaStore(":memory:")
+    params = ParamStore(str(tmp_path / "p"))
+    release = threading.Event()
+    flush = params._flush_to_disk
+    seen = {}
+
+    def held_flush(params_id, tree):
+        seen.setdefault("first_write", params_id)
+        assert release.wait(30)
+        return flush(params_id, tree)
+
+    monkeypatch.setattr(params, "_flush_to_disk", held_flush)
+    completed = meta.mark_trial_completed
+
+    def watched_commit(trial_id, score, params_id):
+        seen.setdefault("at_commit", []).append(
+            (os.path.exists(params._path(params_id)),
+             params_id in params.session_params_ids("sub-order")))
+        return completed(trial_id, score, params_id)
+
+    monkeypatch.setattr(meta, "mark_trial_completed", watched_commit)
+    events = []
+
+    class _Watching(_device_model(events)):
+        def train(self, path, **kw):
+            super().train(path, **kw)
+            if len(events) == 2:  # trial 2 trains, trial 1 is held
+                (first,) = [t for t in meta.get_trials("sub-order")
+                            if t["no"] == 1]
+                seen["while_held"] = (
+                    first["status"], first["params_id"],
+                    os.listdir(params.params_dir),
+                    params.session_params_ids("sub-order"))
+                release.set()
+
+    runner = TrialRunner(_Watching, _FixedAdvisor({"width": 32}), "tr",
+                         "va", meta, params, "sub-order",
+                         budget={BudgetOption.MODEL_TRIAL_COUNT: 2},
+                         pipeline_persist=True)
+    rows = runner.run()
+    runner.close()
+    status, params_id, files, index = seen["while_held"]
+    assert status == TrialStatus.RUNNING and params_id is None
+    assert not [f for f in files if f.endswith(".safetensors")]
+    assert index == []
+    assert seen["at_commit"] == [(True, True), (True, True)]
+    assert [r["status"] for r in rows] == [TrialStatus.COMPLETED] * 2
+    assert rows[0]["params_id"] == seen["first_write"]
+    assert params._writer is None  # no write-behind for device leaves
+    meta.close()
+    params.close()
+
+
+def test_no_device_leaf_outlives_the_drained_tail(tmp_path):
+    import gc
+
+    meta = MetaStore(":memory:")
+    params = ParamStore(str(tmp_path / "p"))
+    leaves = []
+    runner = TrialRunner(_device_model([], leaves),
+                         _FixedAdvisor({"width": 32}), "tr", "va", meta,
+                         params, "sub-weak",
+                         budget={BudgetOption.MODEL_TRIAL_COUNT: 2},
+                         pipeline_persist=True)
+    row = runner.run_one()
+    runner.drain_persist()
+    del row
+    gc.collect()
+    assert len(leaves) == 2 and all(ref() is None for ref in leaves)
+    (trial,) = meta.get_trials("sub-weak")
+    assert trial["status"] == TrialStatus.COMPLETED
+    np.testing.assert_array_equal(
+        params.load(trial["params_id"])["w"],
+        np.arange(6.0, dtype=np.float32).reshape(2, 3) + 1)
+    runner.close()
+    meta.close()
+    params.close()
+
+
+def test_failed_copy_errors_the_trial_and_the_next_one_runs(tmp_path):
+    meta = MetaStore(":memory:")
+    params = ParamStore(str(tmp_path / "p"))
+    events = []
+
+    class _FirstDumpIsGone(_device_model(events)):
+        def dump_parameters(self):
+            out = super().dump_parameters()
+            if len(events) == 1:
+                out["w"].delete()  # np.asarray of it raises
+            return out
+
+    advisor = _FixedAdvisor({"width": 32})
+    runner = TrialRunner(_FirstDumpIsGone, advisor, "tr", "va", meta,
+                         params, "sub-gone",
+                         budget={BudgetOption.MODEL_TRIAL_COUNT: 1},
+                         pipeline_persist=True)
+    rows = runner.run()
+    assert runner._persist.failure_count() == 1
+    runner.close()
+    assert [r["status"] for r in rows] == [TrialStatus.ERRORED,
+                                           TrialStatus.COMPLETED]
+    assert "deleted" in rows[0]["error"]
+    assert rows[0]["params_id"] is None
+    assert len(params.session_params_ids("sub-gone")) == 1
+    # the score was real: the advisor heard of both
+    assert [no for no, _ in advisor.feedbacks] == [1, 2]
+    meta.close()
+    params.close()
+
+
+def test_counter_and_span_attrs_read_what_was_copied(tmp_path):
+    from rafiki_tpu.observe import trace
+
+    meta = MetaStore(":memory:")
+    params = ParamStore(str(tmp_path / "p"))
+    before = phases.dump_leaf_counts()
+    trace.configure(str(tmp_path / "logs"))
+    try:
+        runner = TrialRunner(_device_model([]),
+                             _FixedAdvisor({"width": 32}), "tr", "va",
+                             meta, params, "sub-count",
+                             budget={BudgetOption.MODEL_TRIAL_COUNT: 2},
+                             pipeline_persist=True)
+        rows = runner.run()
+        runner.close()
+        # a model that hands on host arrays only, and no stage at all
+        for pipelined in (True, False):
+            other = TrialRunner(_fake_model([]),
+                                _FixedAdvisor({"width": 32}), "tr", "va",
+                                meta, params, f"sub-host-{pipelined}",
+                                budget={BudgetOption.MODEL_TRIAL_COUNT: 1},
+                                pipeline_persist=pipelined)
+            rows += other.run()
+            other.close()
+    finally:
+        trace.configure(None)
+    after = phases.dump_leaf_counts()
+    # two trials of two device leaves (24 + 16 bytes) and one host leaf;
+    # one trial of one host leaf; the runner with no stage counts nothing
+    assert after["device"] - before["device"] == 4
+    assert after["host"] - before["host"] == 2 + 1
+    attrs = [next(s["attrs"] for s in trace.collect_trace(
+        str(tmp_path / "logs"), row["id"])["spans"]
+        if s["name"] == "trial.persist") for row in rows]
+    assert [a.get("d2h_bytes") for a in attrs] == [40, 40, 0, None]
+    assert all(a["d2h_ms"] >= 0 for a in attrs[:3])
+    assert "d2h_ms" not in attrs[3]
+    meta.close()
+    params.close()
