@@ -26,7 +26,8 @@ cycle (completion to completion).
 platform is shut down, the plain reference follows one trial completed
 in the window, drawn from the seed, and is compared with that trial's
 logged losses and its parameters read back from the param store
-(``compare.py``).
+(``compare.py``: every number it gives is printed under ``compared``,
+and ``compare.judge`` holds those the workload's file limits).
 
 Everything of one cell comes from its workload and configuration files;
 this file names none.
@@ -35,7 +36,6 @@ this file names none.
 from __future__ import annotations
 
 import gc
-import math
 import os
 import random
 import shutil
@@ -355,25 +355,16 @@ def run(*, args, workload, config, t_start, selftest):
     first, final, step_losses = reference.train(
         train_ids, seed, dims, config["recipe"], steps=steps,
         batch=int(knobs["batch_size"]), per_dispatch=per_dispatch,
-        learning_rate=float(knobs["learning_rate"]))
+        learning_rate=float(knobs["learning_rate"]),
+        host_dtype=np.float32)
     say(f"reference followed {steps} steps in {time.time() - t_ref:.1f}s")
-    gap, where = compare.dparam_gap(program_params, final, first,
-                                    dims["layers"])
-    numbers = {
-        "loss_gap": compare.loss_gap(
-            [x for _, x in logs[checked["id"]][0]],
-            compare.chunk_means(step_losses, per_dispatch)),
-        "dparam_gap": gap,
-        "bad_trials": compare.bad_trials(
-            [logs[t["id"]][0] for t in completed], steps),
-    }
-    limits = dict(workload["limits"], bad_trials=0)
-    record["compared"] = {
-        name: {"value": value, "limit": limits[name]}
-        for name, value in numbers.items()}
-    record["compared"]["dparam_gap"]["leaf"] = where
-    record["correct"] = all(
-        math.isfinite(v) and v <= limits[name]
-        for name, v in numbers.items())
+    numbers = compare.trial_numbers(
+        [x for _, x in logs[checked["id"]][0]], step_losses, per_dispatch,
+        program_params, final, first, dims["layers"],
+        **compare.kinds_of(reference))
+    numbers["bad_trials"] = {"value": compare.bad_trials(
+        [logs[t["id"]][0] for t in completed], steps)}
+    record["compared"], record["correct"] = compare.judge(
+        numbers, compare.limits_of(workload))
     shutil.rmtree(workdir, ignore_errors=True)
     return record

@@ -92,7 +92,9 @@ def attention_bwd_least(s: dict, peaks: dict):
 
 
 def _self_check() -> int:
-    """Drift against the program's own arithmetic (models/lm.py)."""
+    """Drift against the program's own arithmetic (models/lm.py), over
+    the configurations of the dense class: those whose ``reference`` is
+    ``lm`` (``flops_moe.py`` checks the sparse class itself)."""
     import sys
 
     sys.path.insert(0, os.path.dirname(HERE))
@@ -105,6 +107,8 @@ def _self_check() -> int:
         for name in sorted(os.listdir(root)):
             with open(os.path.join(root, name)) as f:
                 config = json.load(f)
+            if config["reference"] != "lm":
+                continue
             s = shapes_of(config)
             model = JaxTransformerLM(
                 d_model=s["d"], n_layers=s["layers"], seq_len=s["t"],

@@ -169,6 +169,15 @@ def is_state(name: str) -> bool:
     return name.endswith("_bias")
 
 
+def is_routed(name: str) -> bool:
+    """Leaves behind the top-k choice: the routed experts and their
+    routers. A choice that flips between two sound runs moves these
+    leaves and hardly any other, so their update is compared on its
+    own (``compare.py``: ``routed_gap``)."""
+    part = name.rsplit("/", 1)[-1]
+    return part == "router" or part.startswith("e_")
+
+
 def rms_norm(x, g, eps):
     return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) * g
 
@@ -373,27 +382,28 @@ def _step_fn(dims_items, recipe_items, mode: str, fault: str):
     return step
 
 
-def _to_host(params: dict) -> dict:
-    """Leaf by leaf to host float64, each device leaf freed as it goes.
-    float64 is what ``compare.py`` computes in: handed that, it makes
-    no copy of its own, and no float32 copy lies beside it (at 491 M
-    parameters 4 GB a parameter set, two of which come from here)."""
+def _to_host(params: dict, dtype) -> dict:
+    """Leaf by leaf to the host, each device leaf freed as it goes."""
     out = {}
     for name in sorted(params):
         leaf = params.pop(name)
-        out[name] = np.asarray(leaf, np.float64)
+        out[name] = np.asarray(leaf, dtype)
         leaf.delete()
     return out
 
 
 def train(ids: np.ndarray, seed: int, dims: dict, recipe: dict, *,
           steps: int, batch: int, per_dispatch: int, learning_rate: float,
-          mode: str = "f32", fault: str = ""):
+          mode: str = "f32", fault: str = "", host_dtype=np.float64):
     """One trial of ``steps`` optimizer steps from the seed. Returns
-    ``(initial params, final params, per-step losses)`` as host numpy
-    float64 (exact copies of the float32 values). The initial state is
-    drawn again from the seed once the trial is over, so that no host
-    copy of it is held through the step's compile and the training."""
+    ``(initial params, final params, per-step losses)`` as host numpy,
+    the parameters in ``host_dtype``. The benchmark asks for float32,
+    what the values are: ``compare.py`` widens one leaf at a time, and
+    at 491 M parameters a float64 set is 4 GB. The default stays
+    float64 only because a test outside the benchmark pins it. The
+    initial state is drawn again from the seed once the trial is over,
+    so that no host copy of it is held through the step's compile and
+    the training."""
     params = init_params(seed, dims)
     mu = {k: jnp.zeros_like(v) for k, v in params.items()
           if not is_state(k)}
@@ -410,6 +420,6 @@ def train(ids: np.ndarray, seed: int, dims: dict, recipe: dict, *,
         losses.append(loss)
     losses = np.asarray(jnp.stack(losses), np.float64)
     del mu, nu
-    final = _to_host(params)
-    first = _to_host(init_params(seed, dims))
+    final = _to_host(params, host_dtype)
+    first = _to_host(init_params(seed, dims), host_dtype)
     return first, final, losses

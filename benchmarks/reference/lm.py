@@ -221,11 +221,12 @@ def _step_fn(dims_items, recipe_items, mode: str, fault: str):
 
 def train(ids: np.ndarray, seed: int, dims: dict, recipe: dict, *,
           steps: int, batch: int, per_dispatch: int, learning_rate: float,
-          mode: str = "f32", fault: str = ""):
+          mode: str = "f32", fault: str = "", host_dtype=np.float32):
     """One trial of ``steps`` optimizer steps from the seed. Returns
-    ``(initial params, final params, per-step losses)`` as host numpy."""
+    ``(initial params, final params, per-step losses)`` as host numpy,
+    the parameters in ``host_dtype``."""
     params = init_params(seed, dims)
-    first = jax.tree.map(np.asarray, params)
+    first = jax.tree.map(lambda x: np.asarray(x, host_dtype), params)
     mu = jax.tree.map(jnp.zeros_like, params)
     nu = jax.tree.map(jnp.zeros_like, params)
     wins = windows(ids, seed, steps, batch, dims["t"], per_dispatch)
@@ -238,6 +239,6 @@ def train(ids: np.ndarray, seed: int, dims: dict, recipe: dict, *,
             params, mu, nu, jnp.asarray(wins[i], jnp.int32),
             jnp.float32(lrs[i]), jnp.float32(i + 1))
         losses.append(loss)
-    final = jax.tree.map(np.asarray, params)
+    final = jax.tree.map(lambda x: np.asarray(x, host_dtype), params)
     del params, mu, nu
     return first, final, np.asarray(jnp.stack(losses), np.float64)

@@ -10,8 +10,12 @@ taken over the rest), against the reference itself.
 
 On the chip at the cell's own size this gives the upper readings the
 limits in the workload files were set from (PERF.md lists them); the
-benchmark's own runs never run it. ``run_selftest.py control`` runs the
-same function at the toy size on the CPU. One JSON line per variant.
+benchmark's own runs never run it. Each variant is held to the
+workload's limits by ``compare.judge``, as ``drivers/train_job.py``
+holds the program: its line says ``correct``, and this command exits 1
+if any variant reads ``correct`` true (a control or a fault that the
+cell's limits let through). ``run_selftest.py control`` runs the same
+function at the toy size on the CPU. One JSON line per variant.
 """
 
 from __future__ import annotations
@@ -30,7 +34,9 @@ VARIANTS = (("fp8", ""), ("f32", "half_batch"))
 
 def readings(config: dict, job: dict, seed: int, learning_rate: float,
              variants=VARIANTS):
-    """{variant: {"loss_gap", "dparam_gap"}} of one trial of ``job``."""
+    """{variant: ``compare.trial_numbers``} of one trial of ``job``."""
+    import numpy as np
+
     import compare
     from harness import load_module
 
@@ -48,22 +54,21 @@ def readings(config: dict, job: dict, seed: int, learning_rate: float,
             ids, seed, dims, config["recipe"],
             steps=int(knobs["train_steps"]), batch=int(knobs["batch_size"]),
             per_dispatch=per_dispatch, learning_rate=learning_rate,
-            mode=mode, fault=fault)
+            mode=mode, fault=fault, host_dtype=np.float32)
 
     first, final, losses = trial("f32", "")
     out = {}
     for mode, fault in variants:
         _, theirs, their_losses = trial(mode, fault)
-        out[fault or mode] = {
-            "loss_gap": compare.loss_gap(
-                compare.chunk_means(their_losses, per_dispatch),
-                compare.chunk_means(losses, per_dispatch)),
-            "dparam_gap": compare.dparam_gap(
-                theirs, final, first, dims["layers"])[0]}
+        out[fault or mode] = compare.trial_numbers(
+            compare.chunk_means(their_losses, per_dispatch), losses,
+            per_dispatch, theirs, final, first, dims["layers"],
+            **compare.kinds_of(reference))
     return out
 
 
 def main(argv=None) -> int:
+    import compare
     from harness import load_json
 
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
@@ -74,15 +79,20 @@ def main(argv=None) -> int:
     args = parser.parse_args(argv)
     workload, _ = load_json("workloads", args.workload)
     config, _ = load_json("configs", workload["config"])
+    let_through = 0
     for i, seed in enumerate(args.seeds):
         lr = args.lr[i] if i < len(args.lr) \
             else float(workload["job"]["fixed"]["learning_rate"])
         for variant, numbers in readings(config, workload["job"],
                                          seed % 2147483647, lr).items():
+            compared, correct = compare.judge(
+                numbers, compare.limits_of(workload))
             print(json.dumps({"workload": args.workload, "seed": seed,
                               "learning_rate": lr, "variant": variant,
-                              **numbers}), flush=True)
-    return 0
+                              "correct": correct, "compared": compared}),
+                  flush=True)
+            let_through += correct
+    return 1 if let_through else 0
 
 
 if __name__ == "__main__":

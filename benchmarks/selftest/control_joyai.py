@@ -13,8 +13,8 @@ be empty), against the reference itself.
 
 On the chip at the cell's own size this gives the upper readings the
 limits in the workload file were set from (PERF.md lists them). Each
-variant is held to the workload's own ``limits`` by ``compare.py``'s
-numbers, as ``drivers/train_job.py`` holds the program: its line says
+variant is held to the workload's own limits by ``compare.judge``, as
+``drivers/train_job.py`` holds the program: its line says
 ``correct``, and this command exits 1 if any variant reads ``correct``
 true (a control or a fault that the cell's limits let through). It does
 what ``control.py`` does, one trial a PROCESS: at 491 M parameters a
@@ -23,14 +23,15 @@ GiB (chip runs, PR 29). So this process, which never touches jax,
 starts one child per trial; the reference's own trial leaves its
 parameters and losses under ``.bench_work/`` and each variant's child
 reads them back for ``compare.py``. One JSON line per variant; each
-child logs its host peak.
+child logs its host peak. The children keep their compiled steps in the
+checkout's ``.jax_cache``, as ``run.py`` does: the reference's step
+compiles once for all seeds.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import math
 import os
 import resource
 import shutil
@@ -48,8 +49,9 @@ def stage(config: dict, workload: dict, seed: int, mode: str, fault: str,
           workdir: str):
     """One trial in this process. The reference's own (``f32``, no
     fault) is saved under ``workdir``; any other is compared with it
-    and held to the workload's limits: returns {"loss_gap",
-    "dparam_gap", "leaf", "correct"}, else None."""
+    and held to the workload's limits: returns every number's value
+    under its name, ``dparam_gap``'s ``leaf``, the other numbers'
+    ``notes`` and ``correct``, else None."""
     import numpy as np
 
     import compare
@@ -67,31 +69,30 @@ def stage(config: dict, workload: dict, seed: int, mode: str, fault: str,
         ids, seed, dims, config["recipe"],
         steps=int(knobs["train_steps"]), batch=int(knobs["batch_size"]),
         per_dispatch=per_dispatch,
-        learning_rate=float(knobs["learning_rate"]), mode=mode, fault=fault)
+        learning_rate=float(knobs["learning_rate"]), mode=mode, fault=fault,
+        host_dtype=np.float32)
     own = not fault and mode == "f32"
     if own:
-        # float32 on disk: the values are float32's, read back exactly.
         for name, tree in (("first", first), ("final", final)):
-            np.savez(os.path.join(workdir, name + ".npz"),
-                     **{k: v.astype(np.float32) for k, v in tree.items()})
+            np.savez(os.path.join(workdir, name + ".npz"), **tree)
         np.save(os.path.join(workdir, "losses.npy"), losses)
         out = None
     else:
         del first
-        gap, leaf = compare.dparam_gap(
+        numbers = compare.trial_numbers(
+            compare.chunk_means(losses, per_dispatch),
+            np.load(os.path.join(workdir, "losses.npy")), per_dispatch,
             final, dict(np.load(os.path.join(workdir, "final.npz"))),
             dict(np.load(os.path.join(workdir, "first.npz"))),
-            dims["layers"])
-        out = {
-            "loss_gap": compare.loss_gap(
-                compare.chunk_means(losses, per_dispatch),
-                compare.chunk_means(np.load(os.path.join(
-                    workdir, "losses.npy")), per_dispatch)),
-            "dparam_gap": gap}
-        out["correct"] = all(
-            math.isfinite(v) and v <= workload["limits"][name]
-            for name, v in out.items())
-        out["leaf"] = leaf
+            dims["layers"], **compare.kinds_of(reference))
+        out = {name: number["value"] for name, number in numbers.items()}
+        out["correct"] = compare.judge(numbers,
+                                       compare.limits_of(workload))[1]
+        out["leaf"] = numbers["dparam_gap"]["leaf"]
+        out["notes"] = {
+            name: {k: v for k, v in number.items() if k != "value"}
+            for name, number in numbers.items()
+            if name != "dparam_gap" and len(number) > 1}
     print(f"[control] seed {seed} {fault or mode}: host peak "
           f"{resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 2**20:.1f}"
           f" GiB", file=sys.stderr, flush=True)
@@ -99,6 +100,7 @@ def stage(config: dict, workload: dict, seed: int, mode: str, fault: str,
 
 
 def main(argv=None) -> int:
+    import compare
     from harness import ROOT, load_json
 
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
@@ -120,8 +122,12 @@ def main(argv=None) -> int:
             return 0
         print(json.dumps({"workload": args.workload, "seed": seed,
                           "variant": fault or mode, **numbers,
-                          "limits": workload["limits"]}), flush=True)
+                          "limits": compare.limits_of(workload)}),
+              flush=True)
         return int(numbers["correct"])
+    env = dict(os.environ)
+    if env.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(ROOT, ".jax_cache")
     let_through = 0
     for seed in args.seeds:
         shutil.rmtree(workdir, ignore_errors=True)
@@ -131,7 +137,7 @@ def main(argv=None) -> int:
                 child = subprocess.run(
                     [sys.executable, os.path.abspath(__file__),
                      "--workload", args.workload, "--seeds", str(seed),
-                     "--stage", f"{mode},{fault}"])
+                     "--stage", f"{mode},{fault}"], env=env)
                 if child.returncode not in (0, 1):
                     return child.returncode
                 let_through += child.returncode

@@ -15,9 +15,13 @@ BENCHMARK.json, not under tests/.
                metric with --trace 1.
 ``control``    control.py at the toy size: the reference in the next
                precision below (float8 operands) put in the program's
-               place fails the toy limits, and so does the reference
-               with half of the batch left out; the reference with
-               bfloat16 operands passes.
+               place fails the toy limits by ``compare.judge``, and so
+               does the reference with half of the batch left out; the
+               reference with bfloat16 operands passes.
+``compare``    test_compare.py beside this file: ``compare.py`` on trees
+               made there, and the sparse-expert reference's ``bf16``
+               and ``fp8`` modes at a toy size, parted by
+               ``update_gap`` and ``routed_gap``.
 ``faults``     the rest of a run with the timed path broken underneath
                comes out ``correct`` false: a step that returns its
                state unchanged; half of the batch left out and the mean
@@ -65,8 +69,10 @@ def run_cell(workload: str, seed: int, trace: int = 0, seconds: float = 2):
 
 def check_flops():
     import flops
+    import flops_moe
 
     assert flops._self_check() == 0
+    assert flops_moe._self_check() == 0
     config = json.load(open(os.path.join(
         BENCH, "configs", "pythia-1.4b-L6.json")))
     got = flops.train_step_flops(flops.shapes_of(config))
@@ -74,6 +80,9 @@ def check_flops():
 
 
 def check_schema():
+    import compare
+    from harness import load_module
+
     bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     configs = {c["name"]: c for c in bench["configs"]}
     for cell in bench["workloads"]:
@@ -96,6 +105,18 @@ def check_schema():
                     "data": config["data"]["generator"] + ".py",
                     "reference": config["reference"] + ".py"}[kind]
             assert os.path.exists(os.path.join(BENCH, kind, name)), name
+        # The limits name numbers compare.py gives; null says "shown,
+        # not judged". A cell holds its precision by a number of the
+        # update, update_gap or routed_gap; one whose reference names
+        # state limits state_gap.
+        limits = compare.limits_of(workload)
+        assert set(limits) <= set(compare.NUMBERS), limits
+        held = {k: v for k, v in limits.items() if v is not None}
+        assert all(0 < limit < 1 for limit in held.values()), limits
+        has_state = compare.kinds_of(load_module(
+            "reference", config["reference"]))["is_state"] is not None
+        assert ("state_gap" in held) == has_state, cell["name"]
+        assert "update_gap" in held or "routed_gap" in held, cell["name"]
     names = {c["name"] for c in bench["workloads"]}
     end_to_end = {m["name"] for m in bench["end_to_end"]}
     assert "setup_s" in end_to_end
@@ -145,29 +166,39 @@ def check_command():
         assert line["attempted"] >= 1 and line["failed"] == 0
         assert set(line["metrics"]) == end_to_end  # a selftest owes all
         for pair in line["compared"].values():
-            assert pair["value"] <= pair["limit"]
+            assert pair["limit"] is None or pair["value"] <= pair["limit"]
     traced = run_cell("tiny-final", seed=7, trace=1)
     assert traced["metrics"] == {}, traced["metrics"]  # no device metric
     assert traced["correct"] is True
 
 
 def check_control():
+    import compare
     import control
 
     config = json.load(open(os.path.join(HERE, "configs", "tiny-lm.json")))
     workload = json.load(open(os.path.join(
         HERE, "workloads", "tiny-final.json")))
-    limits = workload["limits"]
+    limits = compare.limits_of(workload)
+    assert "update_gap" in limits, limits
     got = control.readings(
         config, workload["job"], 5,
         workload["job"]["fixed"]["learning_rate"],
         variants=(("bf16", ""),) + control.VARIANTS)
-    print("  limits:", limits)
     for variant, numbers in got.items():
-        print(f"  {variant}: {numbers}")
-    assert all(got["bf16"][k] <= limits[k] for k in limits), got["bf16"]
-    for variant in ("fp8", "half_batch"):
-        assert any(got[variant][k] > limits[k] for k in limits), variant
+        compared, correct = compare.judge(numbers, limits)
+        print(f"  {variant}: correct={correct}", compared)
+        assert correct is (variant == "bf16"), variant
+        if variant == "fp8":  # the precision step, held by update_gap
+            assert compared["update_gap"]["value"] \
+                > compared["update_gap"]["limit"]
+
+
+def check_compare():
+    import pytest
+
+    assert pytest.main([os.path.join(HERE, "test_compare.py"), "-q",
+                        "-p", "no:cacheprovider"]) == 0
 
 
 def check_faults():
@@ -315,7 +346,8 @@ def check_no_tpu():
 
 
 CHECKS = {"flops": check_flops, "schema": check_schema,
-          "control": check_control, "trace": check_trace,
+          "control": check_control, "compare": check_compare,
+          "trace": check_trace,
           "no_tpu": check_no_tpu, "command": check_command,
           "faults": check_faults}
 
