@@ -5,6 +5,7 @@ from .densenet import JaxDenseNet
 from .enas import JaxEnas
 from .feedforward import JaxFeedForward
 from .lm import JaxTransformerLM
+from .lm_lfm2 import JaxLfm2MoeLM
 from .lm_moe import JaxLatentMoELM
 from .pos_tagger import JaxPosTagger
 from .sk import SkDt, SkSvm
@@ -15,4 +16,4 @@ from .vit import JaxViT
 __all__ = ["JaxFeedForward", "JaxCnn", "JaxDenseNet", "JaxEnas", "JaxViT",
            "JaxPosTagger", "SkDt", "SkSvm", "JaxTabMlpClf",
            "JaxTabMlpReg", "JaxTransformerTagger", "JaxTransformerLM",
-           "JaxLatentMoELM"]
+           "JaxLatentMoELM", "JaxLfm2MoeLM"]

@@ -46,6 +46,11 @@ did for the frontend:
   steps and sparse blocks. held + absent = tokens x experts a token x
   sparse blocks, exactly; busiest x experts held / held = the held
   experts' load imbalance.
+- ``rafiki_tpu_lm_layers_total{kind=conv|attention,ffn=dense|sparse}``
+  — the layers a hybrid LM's trial really built, by sequence operator
+  and feed-forward (``lm_layers``, once a trial, from the stacks of the
+  parameter tree the train step is handed): a run says which layer
+  pattern it trained.
 - ``rafiki_tpu_trial_dataset_cache_bytes`` /
   ``rafiki_tpu_trial_stage_cache_bytes`` — current cache occupancy
   against the ``RAFIKI_TPU_DATASET_CACHE_BYTES`` /
@@ -144,6 +149,11 @@ def _reg() -> Dict[str, object]:
                 "Sum over train steps and sparse blocks of the busiest "
                 "held expert's assignments (x experts held / held "
                 "assignments = load imbalance)"),
+            "lm_layers": r.counter(
+                "rafiki_tpu_lm_layers_total",
+                "Layers of the stacks a hybrid LM's trials built "
+                "(kind=conv|attention: the sequence operator; "
+                "ffn=dense|sparse)"),
             "dataset_cache_bytes": r.gauge(
                 "rafiki_tpu_trial_dataset_cache_bytes",
                 "Bytes held by the host dataset cache"),
@@ -273,6 +283,21 @@ def moe_counts() -> Dict[str, int]:
     return {"held": 0, "absent": 0,
             "busiest": int(sum(v for _, v in m["moe_busiest"].samples())),
             **_by_label(m["moe_assignments"], "where")}
+
+
+def lm_layers(op: str, ffn: str, n: int) -> None:
+    """One trial's ``n`` layers of one kind: ``op`` is ``conv`` or
+    ``attn``, ``ffn`` ``dense`` or ``sparse`` (models/lm_lfm2.py)."""
+    if metrics.metrics_enabled():
+        # rta: disable=RTA301 kind and ffn are two fixed values each
+        _reg()["lm_layers"].inc(
+            n, kind="conv" if op == "conv" else "attention", ffn=ffn)
+
+
+def lm_layer_counts() -> Dict[tuple, int]:
+    """{(kind, ffn): layers}: this process's cumulative totals."""
+    return {(labels.get("kind", ""), labels.get("ffn", "")): int(value)
+            for labels, value in _reg()["lm_layers"].samples()}
 
 
 def phase_totals() -> Dict[str, Dict[str, float]]:

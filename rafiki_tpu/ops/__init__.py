@@ -15,10 +15,12 @@ from .attention import (batch_sharded_flash_attention,
                         sequence_sharded_attention, ulysses_attention)
 from .moe import held_experts_swiglu, sigmoid_topk_gates, switch_moe
 from .pipeline import pipeline_apply, pipelined
+from .short_conv import gated_short_conv
 
 __all__ = [
     "batch_sharded_flash_attention", "blockwise_attention",
-    "default_attention", "flash_attention", "held_experts_swiglu",
+    "default_attention", "flash_attention", "gated_short_conv",
+    "held_experts_swiglu",
     "naive_attention",
     "pipeline_apply", "pipelined", "ring_attention",
     "sequence_sharded_attention", "sigmoid_topk_gates", "switch_moe",

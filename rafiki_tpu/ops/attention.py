@@ -29,7 +29,9 @@ Three tiers, one numerical scheme (the online-softmax merge):
 
 All take ``(batch, heads, seq, head_dim)`` arrays. ``naive_attention``
 and ``flash_attention`` also take a ``v`` of another width than q and k
-(latent attention: 192 / 128) and return v's width.
+(latent attention: 192 / 128) and return v's width; ``flash_attention``
+takes k and v with fewer heads than q, a divisor of q's (grouped-query
+attention: query head i reads key-value head i // group).
 """
 
 from __future__ import annotations
@@ -294,17 +296,30 @@ def _pad_v(v, t_to, dp, dvp):
     return _pad_to_blocks(v, t_to, lanes).reshape(b * h, t_to, lanes)
 
 
+def _kv_row(group: int):
+    """Grid index batch·q-head -> row batch·kv-head of k and v: with
+    ``h = hk · group``, ``(b·h + i) // group == b·hk + i // group``.
+    At equal head counts the index passes through untouched, so the
+    kernels' block maps, and their programs, are what they always were.
+    A group's key-value block is read through the block map; k and v
+    are never repeated in HBM."""
+    if group == 1:
+        return lambda bh: bh
+    return lambda bh: jax.lax.div(bh, jnp.int32(group))
+
+
 def _flash_forward(q, k, v, bias, causal, block_q, block_kv, interpret,
                    return_lse=False):
     b, h, tq, d = q.shape
-    tkv, d_v = k.shape[2], v.shape[3]
+    hk, tkv, d_v = k.shape[1], k.shape[2], v.shape[3]
+    kv_row = _kv_row(h // hk)
     scale = 1.0 / math.sqrt(d)
     block_q, block_kv, nq, nk, dp, dvp = _flash_blocking(
         q, k, v, bias, block_q, block_kv)
     qp = _pad_to_blocks(q, nq * block_q, dp).reshape(
         b * h, nq * block_q, dp)
     kp = _pad_to_blocks(k, nk * block_kv, dp).reshape(
-        b * h, nk * block_kv, dp)
+        b * hk, nk * block_kv, dp)
     vp = _pad_v(v, nk * block_kv, dp, dvp)
     # p·v runs over all of vp's lanes and only o is cut to dvp: with
     # v's block and acc at 128 lanes under a q of 256 the kernel has
@@ -316,8 +331,10 @@ def _flash_forward(q, k, v, bias, causal, block_q, block_kv, interpret,
 
     in_specs = [
         pl.BlockSpec((1, block_q, dp), lambda bh, i, j: (bh, i, 0)),
-        pl.BlockSpec((1, block_kv, dp), lambda bh, i, j: (bh, j, 0)),
-        pl.BlockSpec((1, block_kv, lanes), lambda bh, i, j: (bh, j, 0)),
+        pl.BlockSpec((1, block_kv, dp),
+                     lambda bh, i, j: (kv_row(bh), j, 0)),
+        pl.BlockSpec((1, block_kv, lanes),
+                     lambda bh, i, j: (kv_row(bh), j, 0)),
     ]
     inputs = [qp, kp, vp]
     if bias is not None:
@@ -432,12 +449,16 @@ def _flash_dq_kernel(*refs, scale, causal, block_q, block_kv, seq_q,
 
 
 def _flash_dkv_kernel(*refs, scale, causal, block_q, block_kv, seq_q,
-                      seq_kv, has_bias):
-    """dk and dv for one (batch·head, kv-block) — q blocks stream
+                      seq_kv, has_bias, group=1, q_blocks=None):
+    """dk and dv for one (batch·kv-head, kv-block) — q blocks stream
     innermost. Same transposed-score layout as ``_flash_dq_kernel``:
 
       dv += pᵀ · do
       dk += dsᵀ · q
+
+    With ``group`` query heads to a key-value head the innermost axis
+    runs the ``q_blocks`` blocks of each of the group's heads in turn,
+    and dk, dv accumulate over all of them before they are written.
     """
     if has_bias:
         (k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, maskt_ref,
@@ -446,10 +467,12 @@ def _flash_dkv_kernel(*refs, scale, causal, block_q, block_kv, seq_q,
         (k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_scr, dv_scr) = refs
         maskt_ref = None
-    j, i = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+    j, y = pl.program_id(1), pl.program_id(2)
+    n_inner = pl.num_programs(2)
+    # i: the q block within its head (all of y at equal head counts)
+    i = y if group == 1 else jax.lax.rem(y, jnp.int32(q_blocks))
 
-    @pl.when(i == 0)
+    @pl.when(y == 0)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -490,7 +513,7 @@ def _flash_dkv_kernel(*refs, scale, causal, block_q, block_kv, seq_q,
             dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # (bkv, dp)
 
-    @pl.when(i == nq - 1)
+    @pl.when(y == n_inner - 1)
     def _():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -500,7 +523,9 @@ def _flash_backward(q, k, v, bias, out, lse, g, causal, block_q,
                     block_kv, interpret):
     """Assemble dq/dk/dv from the two Pallas backward kernels."""
     b, h, tq, d = q.shape
-    tkv, d_v = k.shape[2], v.shape[3]
+    hk, tkv, d_v = k.shape[1], k.shape[2], v.shape[3]
+    group = h // hk
+    kv_row = _kv_row(group)
     scale = 1.0 / math.sqrt(d)
     block_q, block_kv, nq, nk, dp, dvp = _flash_blocking(
         q, k, v, bias, block_q, block_kv)
@@ -514,7 +539,7 @@ def _flash_backward(q, k, v, bias, out, lse, g, causal, block_q,
     qp = _pad_to_blocks(q, nq * block_q, dp).reshape(
         b * h, nq * block_q, dp)
     kp = _pad_to_blocks(k, nk * block_kv, dp).reshape(
-        b * h, nk * block_kv, dp)
+        b * hk, nk * block_kv, dp)
     vp = _pad_v(v, nk * block_kv, dp, dvp)
     dop = _pad_to_blocks(g, nq * block_q, dvp).reshape(
         b * h, nq * block_q, dvp)
@@ -533,20 +558,41 @@ def _flash_backward(q, k, v, bias, out, lse, g, causal, block_q,
     def by_x(rows, lanes):
         return pl.BlockSpec((1, rows, lanes), lambda bh, x, y: (bh, x, 0))
 
-    def by_y(rows, lanes):
-        return pl.BlockSpec((1, rows, lanes), lambda bh, x, y: (bh, y, 0))
+    def kv_by_y(rows, lanes):
+        return pl.BlockSpec((1, rows, lanes),
+                            lambda bh, x, y: (kv_row(bh), y, 0))
+
+    # The dkv grid's innermost axis: a q block of a query head. At
+    # equal head counts that is (bh, y), as it always was; in a group
+    # y counts the nq blocks of each of the key-value head's query
+    # heads in turn.
+    if group == 1:
+        def q_of(bh, y):
+            return bh, y
+    else:
+        def q_of(bh, y):
+            return (bh * group + jax.lax.div(y, jnp.int32(nq)),
+                    jax.lax.rem(y, jnp.int32(nq)))
+
+    def q_by_y(rows, lanes):
+        return pl.BlockSpec((1, rows, lanes),
+                            lambda bh, x, y: (*q_of(bh, y), 0))
 
     row_spec = pl.BlockSpec((1, 1, block_q), lambda bh, x, y: (bh, 0, x))
-    row_spec_t = pl.BlockSpec((1, 1, block_q),
-                              lambda bh, x, y: (bh, 0, y))
+
+    def row_index_t(bh, x, y):
+        row, block = q_of(bh, y)
+        return row, 0, block
+
+    row_spec_t = pl.BlockSpec((1, 1, block_q), row_index_t)
 
     # k, v, q, do: the dq grid is (bh, q, kv); the dkv grid (bh, kv, q)
     # swaps which grid axis feeds which block index.
     inputs = [kp, vp, qp, dop, lse_row, delta]
-    in_specs = [by_y(block_kv, dp), by_y(block_kv, dvp),
+    in_specs = [kv_by_y(block_kv, dp), kv_by_y(block_kv, dvp),
                 by_x(block_q, dp), by_x(block_q, dvp), row_spec, row_spec]
     in_specs_t = [by_x(block_kv, dp), by_x(block_kv, dvp),
-                  by_y(block_q, dp), by_y(block_q, dvp), row_spec_t,
+                  q_by_y(block_q, dp), q_by_y(block_q, dvp), row_spec_t,
                   row_spec_t]
     if bias is not None:
         # kv-side padding mask as a lane-8 COLUMN (the transposed-score
@@ -559,8 +605,10 @@ def _flash_backward(q, k, v, bias, out, lse, g, causal, block_q,
         inputs.append(maskt)
         in_specs.append(pl.BlockSpec((1, block_kv, 8),
                                      lambda bh, x, y: (bh, y, 0)))
-        in_specs_t.append(pl.BlockSpec((1, block_kv, 8),
-                                       lambda bh, x, y: (bh, x, 0)))
+        # (the mask's rows repeat per QUERY head: any of the group's)
+        in_specs_t.append(pl.BlockSpec(
+            (1, block_kv, 8), lambda bh, x, y: (
+                bh if group == 1 else bh * group, x, 0)))
 
     common = dict(scale=scale, causal=causal, block_q=block_q,
                   block_kv=block_kv, seq_q=tq, seq_kv=tkv,
@@ -578,14 +626,15 @@ def _flash_backward(q, k, v, bias, out, lse, g, causal, block_q,
         interpret=interpret,
         metadata={"kernel": "flash_dq"},
     )(*inputs)
+    grouped = {} if group == 1 else {"group": group, "q_blocks": nq}
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, **common),
-        grid=(b * h, nk, nq),
+        functools.partial(_flash_dkv_kernel, **common, **grouped),
+        grid=(b * hk, nk, group * nq),
         in_specs=in_specs_t,
         out_specs=[by_x(block_kv, dp), by_x(block_kv, dvp)],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, nk * block_kv, dp), k.dtype),
-            jax.ShapeDtypeStruct((b * h, nk * block_kv, dvp), v.dtype),
+            jax.ShapeDtypeStruct((b * hk, nk * block_kv, dp), k.dtype),
+            jax.ShapeDtypeStruct((b * hk, nk * block_kv, dvp), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((block_kv, dp), jnp.float32),
                         pltpu.VMEM((block_kv, dvp), jnp.float32)],
@@ -595,8 +644,8 @@ def _flash_backward(q, k, v, bias, out, lse, g, causal, block_q,
         metadata={"kernel": "flash_dkv"},
     )(*inputs)
     dq = dq.reshape(b, h, nq * block_q, dp)[:, :, :tq, :d]
-    dk = dk.reshape(b, h, nk * block_kv, dp)[:, :, :tkv, :d]
-    dv = dv.reshape(b, h, nk * block_kv, dvp)[:, :, :tkv, :d_v]
+    dk = dk.reshape(b, hk, nk * block_kv, dp)[:, :, :tkv, :d]
+    dv = dv.reshape(b, hk, nk * block_kv, dvp)[:, :, :tkv, :d_v]
     return dq, dk, dv
 
 
@@ -640,8 +689,14 @@ def flash_attention(q, k, v, *, causal: bool = False, block_q: int = 1024,
     and §7 (block readings at 1 x 32 x 8192).
     ``kv_mask`` (B, Tkv) bool, True = real token. ``v`` may have
     another width than q and k: the result has v's, the scale is
-    1/sqrt(q's).
+    1/sqrt(q's). ``k`` and ``v`` may have fewer heads than q, a divisor
+    of q's: query head i attends key-value head i // (h / hk), and dk,
+    dv are summed over a group's query heads inside the dkv kernel.
     """
+    if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(
+            f"q's {q.shape[1]} heads are no multiple of k's {k.shape[1]} "
+            f"(v has {v.shape[1]})")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     bias = None if kv_mask is None else jnp.where(

@@ -159,14 +159,16 @@ def switch_moe(x, gate_w, w1, b1, w2, b2, *,
 # in for the other ranks.
 
 
-def sigmoid_topk_gates(x, gate_w, bias, *, k: int, scale: float):
+def sigmoid_topk_gates(x, gate_w, bias, *, k: int, scale: float,
+                       eps: float = 1e-20):
     """Router of a dropless top-k layer, float32 throughout.
 
     ``x`` (N, D), ``gate_w`` (D, E), ``bias`` (E,). Scores are
     ``sigmoid(x @ gate_w)``; the ``k`` experts of a token are the top-k
     of ``scores + bias`` (the bias steers selection only and takes no
     gradient: ``noaux_tc``); its gates are the chosen experts' scores
-    WITHOUT the bias, normalised to sum 1 and multiplied by ``scale``.
+    WITHOUT the bias, divided by their sum + ``eps`` (DeepSeek-V3's
+    1e-20; the LFM2 family adds 1e-6) and multiplied by ``scale``.
 
     Returns ``(gates, chosen)``, both (N, E) and dense over ALL experts:
     ``gates`` is zero off a token's chosen experts. A rank slices its
@@ -180,7 +182,7 @@ def sigmoid_topk_gates(x, gate_w, bias, *, k: int, scale: float):
     _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
     chosen = (idx[..., None] == jnp.arange(e, dtype=idx.dtype)).any(-2)
     picked = jnp.where(chosen, scores, 0.0)
-    gates = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
+    gates = scale * picked / (picked.sum(-1, keepdims=True) + eps)
     return gates, chosen
 
 

@@ -101,17 +101,23 @@ _KERNEL_SHAPES = {
     # passes), v 128 (one); kv blocks of 512 as models/lm_moe.py asks
     "mla": ((1, 32, 8192, 192), jnp.bfloat16,
             {"causal": True, "block_kv": 512}, False),
+    # lfm2-moe-final's grouped-query attention: 32 query heads of 64
+    # lanes (padded to 128) read 8 key-value heads
+    "gqa": ((1, 32, 8192, 64), jnp.bfloat16, {"causal": True}, False),
 }
 _V_LANES = {"mla": 128}
+_KV_HEADS = {"gqa": 8}
 
 
 def _qkv_shapes(shape_name, sharding):
     shape, dtype, _, _ = _KERNEL_SHAPES[shape_name]
     x = jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    kv = (shape[0], _KV_HEADS.get(shape_name, shape[1]), shape[2])
+    k = jax.ShapeDtypeStruct(kv + shape[3:], dtype, sharding=sharding)
     v = jax.ShapeDtypeStruct(
-        shape[:3] + (_V_LANES.get(shape_name, shape[3]),), dtype,
+        kv + (_V_LANES.get(shape_name, shape[3]),), dtype,
         sharding=sharding)
-    return [x, x, v]
+    return [x, k, v]
 
 
 @pytest.mark.parametrize("case", [
@@ -119,7 +125,7 @@ def _qkv_shapes(shape_name, sharding):
     "long_t8192-fwd",
     "masked_197x64-fwd", "masked_197x64-grad",
     "small_blocks-fwd", "small_blocks-grad",
-    "mla-fwd", "mla-grad"])
+    "mla-fwd", "mla-grad", "gqa-fwd", "gqa-grad"])
 def test_flash_kernel_compiles_on_one_chip(one_chip, case):
     shape_name, pass_ = case.split("-")
     grad = pass_ == "grad"
@@ -155,22 +161,35 @@ def test_flash_kernels_carry_their_names_into_the_compiled_program(
     assert set(named) == {"flash_fwd", "flash_dq", "flash_dkv"}
 
 
-def _joyai_knobs():
-    """The knobs of the benchmark's ``joyai-flash-final`` cell."""
+def _cell_knobs(config_name):
+    """The knobs a benchmark configuration pins, as the driver's
+    template gets them."""
     import json
     import os
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
-                           "joyai-llm-flash-L5-E8.json")) as f:
+                           config_name + ".json")) as f:
         config = json.load(f)
     knobs = {knob: config[key] for knob, key in config["knob_of"].items()}
     knobs.update(config["knobs"])
     return knobs
 
 
-def _kernels_the_benchmark_reads(text):
-    """``benchmarks/metrics/mla_attn_fwd_roofline.py:kernels`` over the
+def _joyai_knobs():
+    """The knobs of the benchmark's ``joyai-flash-final`` cell."""
+    return _cell_knobs("joyai-llm-flash-L5-E8")
+
+
+def _lfm2_knobs():
+    """The knobs of the benchmark's ``lfm2-moe-final`` cell."""
+    return _cell_knobs("lfm2-8b-a1b-L5-E8")
+
+
+def _kernels_the_benchmark_reads(text, reader_name="mla_attn_fwd_roofline",
+                                 knobs=_joyai_knobs):
+    """``benchmarks/metrics/<reader_name>.py:kernels`` (the MLA reader,
+    or the grouped-query one with the ``lfm2-moe-final`` knobs) over the
     Mosaic calls of a compiled program: {kernel: calls found}, once with
     the kernels' names in the text and once by signature alone, and the
     results' shapes of each call. The reader takes a profiler's op
@@ -185,7 +204,7 @@ def _kernels_the_benchmark_reads(text):
     sys.path.insert(0, bench)
     try:
         import harness
-        reader = harness.load_module("metrics", "mla_attn_fwd_roofline")
+        reader = harness.load_module("metrics", reader_name)
     finally:
         sys.path.remove(bench)
     shape_of = dict(re.findall(
@@ -205,8 +224,7 @@ def _kernels_the_benchmark_reads(text):
             re.findall(r"\w+\[[\d,]*\]", head.partition(" custom-call")[0])
     counts = []
     for ops in (named, bare):
-        found = reader.kernels({"trace": {"ops": ops},
-                                "knobs": _joyai_knobs()})
+        found = reader.kernels({"trace": {"ops": ops}, "knobs": knobs()})
         counts.append({name: k["n"] for name, k in found.items()})
     assert counts[0] == counts[1], counts
     return counts[0], results
@@ -385,3 +403,59 @@ def test_joyai_cell_step_compiles_and_fits_one_chip(topo, monkeypatch):
     m = compiled.memory_analysis()
     assert m.argument_size_in_bytes > 5.8e9  # params + Adam, resident
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 14.5e9, m
+
+
+def test_lfm2_cell_step_compiles_and_fits_one_chip(topo, monkeypatch):
+    """The train program of the benchmark's ``lfm2-moe-final`` cell
+    (JaxLfm2MoeLM at LFM2-8B-A1B's widths: conv, attention, conv, conv,
+    conv with one leading dense layer, 8 of 32 experts, 1 x 8192
+    tokens, 8 steps a dispatch, remat dots), lowered from shapes through
+    the trainer's own ``_make_train_chunk``: the three flash kernels
+    are in it by name, at 32 query heads over 8 key-value heads (the
+    forward once more under remat) as the benchmark's grouped-query
+    readers know them; the MLA reader, which knows a call by one shape
+    three times, finds none of them; and arguments + temporaries + the
+    previous trial's float32 tree, which a job keeps alive into the
+    next trial's first steps, stay under 14.5 GB."""
+    import re
+
+    import optax
+
+    from rafiki_tpu.models import JaxLfm2MoeLM
+    from rafiki_tpu.models.lm import _weights
+    from rafiki_tpu.models.lm_lfm2 import _jitted_lfm2_init
+
+    model = JaxLfm2MoeLM(**_lfm2_knobs())
+    mesh = build_mesh(topo.devices[:1])
+    model._mesh = mesh
+    rep = replicated(mesh)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=rep), tree)
+
+    s = model._dims()
+    params = on_chip(jax.eval_shape(
+        _jitted_lfm2_init(tuple(sorted(s.items())), mesh),
+        jax.ShapeDtypeStruct((), jnp.int32)))
+    n_params = sum(a.size for a in jax.tree.leaves(params))
+    assert 507e6 < n_params < 509e6
+    tx = optax.adamw(2.2e-4)
+    opt_state = on_chip(jax.eval_shape(tx.init, _weights(params)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    wins = jax.ShapeDtypeStruct((8, 1, s["t"] + 1), jnp.int32,
+                                sharding=rep)
+    compiled = model._make_train_chunk(tx).lower(
+        params, opt_state, wins).compile()
+    text = compiled.as_text()
+    named = re.findall(r'kernel_metadata=\{\s*"kernel":"(\w+)"', text)
+    assert set(named) == {"flash_fwd", "flash_dq", "flash_dkv"}
+    assert _kernels_the_benchmark_reads(
+        text, "lfm2_attn_fwd_roofline", _lfm2_knobs)[0] == {
+            "flash_fwd": 2, "flash_dq": 1, "flash_dkv": 1}
+    assert _kernels_the_benchmark_reads(text)[0] == {
+        "flash_fwd": 0, "flash_dq": 0, "flash_dkv": 0}
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes > 6.0e9  # params + Adam, resident
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + 4 * n_params) < 14.5e9, m

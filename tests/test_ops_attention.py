@@ -301,6 +301,147 @@ def test_flash_backward_holds_do_behind_a_barrier_only_for_a_narrow_v(
     assert ("optimization_barrier" in text) == barrier
 
 
+def _grouped(rng, h, hk, t=160, d=64, d_v=None, dtype=np.float32):
+    q = jnp.asarray(rng.standard_normal((2, h, t, d)).astype(dtype))
+    k = jnp.asarray(rng.standard_normal((2, hk, t, d)).astype(dtype))
+    v = jnp.asarray(rng.standard_normal((2, hk, t, d_v or d)).astype(dtype))
+    return q, k, v
+
+
+@pytest.mark.parametrize("h,hk,masked", [(8, 2, False), (4, 4, False),
+                                         (8, 2, True), (4, 1, False)],
+                         ids=["4-a-group", "1-a-group", "4-a-group-masked",
+                              "one-kv-head"])
+def test_flash_grouped_query_heads_match_naive_with_kv_repeated(
+        rng, h, hk, masked):
+    """k and v with fewer heads than q: query head i reads key-value
+    head i // (h / hk). Forward and all three gradients against
+    ``naive_attention`` over K/V written out once a query head, at
+    nq = nk = 2 with T no block multiple; dk and dv are sums over a
+    group's query heads."""
+    q, k, v = _grouped(rng, h, hk)
+    mask = jnp.asarray(np.arange(160)[None, :] < np.array([[160], [131]])) \
+        if masked else None
+    ct = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
+    group = h // hk
+
+    def flash(q, k, v):
+        return (flash_attention(q, k, v, causal=True, kv_mask=mask,
+                                block_q=128, block_kv=128) * ct).sum()
+
+    def naive(q, k, v):
+        return (naive_attention(q, jnp.repeat(k, group, 1),
+                                jnp.repeat(v, group, 1), causal=True,
+                                kv_mask=mask) * ct).sum()
+
+    got = jax.value_and_grad(flash, argnums=(0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(naive, argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for name, a, b in zip(("dq", "dk", "dv"), got[1], want[1]):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_flash_grouped_equals_the_repeated_equal_head_call_bit_for_bit(
+        rng, dtype):
+    """A group's key-value block is read through the block map, never
+    written out: o and dq are the very bits of the equal-head call on
+    K/V repeated in memory (the program this path had before it knew
+    groups); dk and dv, which that call leaves to a sum over the
+    repeats outside the kernel, agree to rounding."""
+    q, k, v = _grouped(rng, 8, 2, t=300, dtype=dtype)
+    ct = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
+    blocks = {"block_q": 128, "block_kv": 128}
+
+    def run(fn):
+        def f(q, k, v):
+            out = fn(q, k, v)
+            return (out.astype(jnp.float32) * ct).sum(), out
+        (_, out), grads = jax.value_and_grad(
+            f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (out,) + grads
+
+    grouped = run(lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                                  **blocks))
+    repeated = run(lambda q, k, v: flash_attention(
+        q, jnp.repeat(k, 4, 1), jnp.repeat(v, 4, 1), causal=True, **blocks))
+    for name, a, b in zip(("o", "dq"), grouped, repeated):
+        assert np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32)), name
+    tol = 1e-5 if dtype == np.float32 else 3e-2
+    for a, b in zip(grouped[2:], repeated[2:]):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), atol=tol,
+                                   rtol=tol)
+
+
+@pytest.mark.parametrize("hk", [4, 1], ids=["equal", "grouped"])
+def test_flash_grids_and_block_maps_handed_to_pallas_call(monkeypatch, hk):
+    """At equal head counts the three ``pallas_call``s get the grids
+    they always had and block maps that are bare pass-throughs of the
+    grid indices (no operation in their jaxprs: the kernels' programs
+    are the ones from before groups existed, and the dkv kernel is
+    called without a group). With 4 query heads to a key-value head, k
+    and v ride at their own head count, the k / v maps divide the grid
+    index and the dkv grid runs the group's q blocks innermost."""
+    from rafiki_tpu.ops import attention
+
+    calls = {}
+    real = attention.pl.pallas_call
+
+    def spy(kernel, **kw):
+        run = real(kernel, **kw)
+
+        def wrapped(*operands):
+            n_eqns = []
+            for spec in kw["in_specs"]:
+                jaxpr = jax.make_jaxpr(spec.index_map)(
+                    *(jnp.int32(0),) * 3)
+                n_eqns.append(len(jaxpr.eqns))
+            calls[kw["metadata"]["kernel"]] = {
+                "grid": kw["grid"], "map_eqns": n_eqns,
+                "operands": [o.shape for o in operands[:3]],
+                "kernel_kw": sorted(getattr(kernel, "keywords", {}))}
+            return run(*operands)
+        return wrapped
+
+    monkeypatch.setattr(attention.pl, "pallas_call", spy)
+    q = jnp.ones((2, 4, 256, 64), jnp.bfloat16)
+    kv = jnp.ones((2, hk, 256, 64), jnp.bfloat16)
+    jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=128, block_kv=128
+    ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, kv, kv)
+    big, small = (8, 256, 128), (2 * hk, 256, 128)
+    common = ["block_kv", "block_q", "causal", "has_bias", "scale",
+              "seq_kv", "seq_q"]
+    if hk == 4:
+        assert calls["flash_fwd"] == {
+            "grid": (8, 2, 2), "map_eqns": [0, 0, 0],
+            "operands": [big] * 3, "kernel_kw": common}
+        assert calls["flash_dq"] == dict(calls["flash_fwd"],
+                                         map_eqns=[0] * 6)
+        assert calls["flash_dkv"] == calls["flash_dq"]
+    else:
+        assert calls["flash_fwd"]["grid"] == (8, 2, 2)
+        assert calls["flash_fwd"]["operands"] == [big, small, small]
+        assert calls["flash_fwd"]["map_eqns"] == [0, 1, 1]
+        assert calls["flash_dq"]["grid"] == (8, 2, 2)
+        assert calls["flash_dq"]["operands"] == [small, small, big]
+        assert calls["flash_dq"]["map_eqns"][:4] == [1, 1, 0, 0]
+        # 2 kv rows x 2 kv blocks x (4 heads of the group x 2 q blocks)
+        assert calls["flash_dkv"]["grid"] == (2, 2, 8)
+        assert calls["flash_dkv"]["kernel_kw"] == sorted(
+            common + ["group", "q_blocks"])
+
+
+def test_flash_refuses_head_counts_that_do_not_divide(rng):
+    q, k, v = _grouped(rng, 6, 4, t=32)
+    with pytest.raises(ValueError, match="no multiple"):
+        flash_attention(q, k, v, causal=True)
+
+
 @pytest.mark.slow
 def test_kv_mask_all_tiers(rng):
     # Key-padding mask: ragged batch of real lengths; every tier must

@@ -372,13 +372,14 @@ def test_reader_gives_none_where_there_is_nothing_to_read(load_reader,
 
 def test_benchmark_lists_each_reader_for_its_cells():
     """Bare for ``lm14-final``, ``search.`` for ``lm14-search``, ``moe.``
-    for the sparse-expert cell (PR 29)."""
+    for the sparse-expert cell (PR 29), ``lfm2.`` for the hybrid
+    convolution / attention one (PR 36)."""
     import json
 
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {"": "lm14-final", "search.": "lm14-search",
-             "moe.": "joyai-flash-final"}
+             "moe.": "joyai-flash-final", "lfm2.": "lfm2-moe-final"}
     mine = [m for m in bench["per_layer"]
             if m["name"].rsplit(".", 1)[-1] in READERS]
     assert sorted(m["name"] for m in mine) == sorted(
