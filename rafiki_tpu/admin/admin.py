@@ -15,6 +15,7 @@ from typing import Any, Dict, List, Optional
 from ..constants import (BudgetOption, InferenceJobStatus, ModelAccessRight,
                          TrainJobStatus, TrialStatus, UserType)
 from ..model.knobs import knob_config_to_json
+from ..observe import phases
 from ..store import MetaStore, ParamStore
 from ..utils import auth
 from ..utils.model_loader import load_model_class
@@ -127,14 +128,15 @@ class Admin:
                      dependencies: Optional[Dict[str, str]] = None,
                      access_right: str = ModelAccessRight.PRIVATE,
                      ) -> Dict[str, Any]:
-        # Resolve now: a model that doesn't import/declare knobs must be
-        # rejected at upload, not at trial time.
-        cls = load_model_class(model_class, model_source)
-        knob_config = knob_config_to_json(cls.get_knob_config())
-        row = self.meta.create_model(
-            user_id, name, task, model_class, knob_config,
-            model_source=model_source, dependencies=dependencies,
-            access_right=access_right)
+        with phases.span("model_upload"):
+            # Resolve now: a model that doesn't import/declare knobs
+            # must be rejected at upload, not at trial time.
+            cls = load_model_class(model_class, model_source)
+            knob_config = knob_config_to_json(cls.get_knob_config())
+            row = self.meta.create_model(
+                user_id, name, task, model_class, knob_config,
+                model_source=model_source, dependencies=dependencies,
+                access_right=access_right)
         return {"id": row["id"], "name": name, "task": task}
 
     def get_models(self, user_id: str,
@@ -245,26 +247,32 @@ class Admin:
                          train_dataset_path: str, val_dataset_path: str,
                          advisor_type: Optional[str] = None,
                          ) -> Dict[str, Any]:
-        budget = normalize_budget(budget)
-        budget.setdefault(BudgetOption.MODEL_TRIAL_COUNT, 5)
-        if not model_ids:
-            raise ValueError("model_ids must be non-empty")
-        # Validate everything BEFORE inserting rows: a failed validation
-        # must not leave an orphaned STARTED job burning the app-version.
-        for model_id in model_ids:
-            model = self.meta.get_model(model_id)
-            if model is None:
-                raise ValueError(f"unknown model {model_id}")
-            if model["task"] != task:
-                raise ValueError(
-                    f"model {model['name']} is for task {model['task']}, "
-                    f"not {task}")
-        job = self.meta.create_train_job(
-            user_id, app, task, budget, train_dataset_path,
-            val_dataset_path, TrainJobStatus.STARTED)
-        for model_id in model_ids:
-            self.meta.create_sub_train_job(job["id"], model_id, "STARTED",
-                                           advisor_type=advisor_type)
+        # The set-up phase ``job_submit`` ends where the services'
+        # launch begins: the launch starts the worker thread, whose own
+        # span (``worker_start``) would otherwise overlap this one.
+        with phases.span("job_submit"):
+            budget = normalize_budget(budget)
+            budget.setdefault(BudgetOption.MODEL_TRIAL_COUNT, 5)
+            if not model_ids:
+                raise ValueError("model_ids must be non-empty")
+            # Validate everything BEFORE inserting rows: a failed
+            # validation must not leave an orphaned STARTED job burning
+            # the app-version.
+            for model_id in model_ids:
+                model = self.meta.get_model(model_id)
+                if model is None:
+                    raise ValueError(f"unknown model {model_id}")
+                if model["task"] != task:
+                    raise ValueError(
+                        f"model {model['name']} is for task "
+                        f"{model['task']}, not {task}")
+            job = self.meta.create_train_job(
+                user_id, app, task, budget, train_dataset_path,
+                val_dataset_path, TrainJobStatus.STARTED)
+            for model_id in model_ids:
+                self.meta.create_sub_train_job(
+                    job["id"], model_id, "STARTED",
+                    advisor_type=advisor_type)
         self.services.create_train_services(job["id"])
         self.meta.update_train_job(job["id"], status=TrainJobStatus.RUNNING)
         return {"id": job["id"], "app": job["app"],
@@ -843,7 +851,9 @@ class Admin:
         from ..observe import phases as obs_phases
 
         totals = obs_phases.phase_totals()
-        resident = any(v["count"] for v in totals.values())
+        # Only a trial phase says a worker ran here: every process
+        # counts its own set-up and compile phases.
+        resident = any(totals[p]["count"] for p in obs_phases.TRIAL_PHASES)
         phases = {
             p: {"count": int(v["count"]),
                 "total_s": round(v["sum"], 3),

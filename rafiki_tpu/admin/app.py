@@ -50,9 +50,10 @@ route-compatible so reference quickstart scripts port 1:1:
                                      canned-ramp policy-gate simulation
                                      of this node's autoscale policy
                                      (docs/capacity.md)
-- ``GET  /trial_phases``             trial-lifecycle phase breakdown +
-                                     residency-cache counters (resident
-                                     workers only; see docs/training.md)
+- ``GET  /trial_phases``             trial-lifecycle, set-up and jit_*
+                                     phase breakdown + residency-cache
+                                     counters (resident workers only;
+                                     see docs/training.md)
 - ``GET  /metrics``                  Prometheus exposition (auto-wired
                                      by ``JsonHttpServer``; no auth,
                                      like any scrape endpoint)

@@ -121,6 +121,10 @@ def force_cpu_device_count(n: int) -> None:
     jax.config.update("jax_platforms", "cpu")
 
 
+#: Whether ``ensure_platform`` has timed its first resolve.
+_backend_timed = False
+
+
 def ensure_platform(*, n_virtual_devices: Optional[int] = None) -> str:
     """Resolve the JAX platform; returns ``"cpu"`` or ``"tpu"``.
 
@@ -131,8 +135,22 @@ def ensure_platform(*, n_virtual_devices: Optional[int] = None) -> str:
     must be a TPU: no TPU raises ``RuntimeError`` — nothing continues
     on the CPU by accident.
 
-    Idempotent; safe to call from every entry point.
+    Idempotent; safe to call from every entry point. The first call
+    that finds no backend up is the phase ``backend_init`` (the TPU
+    runtime's start); every call installs ``phases.watch_jit`` once.
     """
+    global _backend_timed
+    from .observe import phases
+
+    phases.watch_jit()
+    if _backend_timed or backend_initialized():
+        return _resolve(n_virtual_devices)
+    _backend_timed = True
+    with phases.span("backend_init"):
+        return _resolve(n_virtual_devices)
+
+
+def _resolve(n_virtual_devices: Optional[int]) -> str:
     import jax
 
     if cpu_requested():

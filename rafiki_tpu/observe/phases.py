@@ -1,4 +1,5 @@
-"""Trial-lifecycle phase metrics, folded into the unified registry.
+"""Trial-lifecycle, set-up and compile-stage phase metrics, folded into
+the unified registry.
 
 The trial hot loop (propose -> load -> stage -> train -> eval ->
 persist) is where the training plane's trials/hour lives; where its
@@ -8,15 +9,26 @@ make the breakdown measurable the same way the serving stage histogram
 did for the frontend:
 
 - ``rafiki_tpu_trial_phase_seconds{phase=}`` — wall time per phase,
-  every one timed by ``span`` below (``PHASES`` lists them with their
-  nesting). The TrialRunner records the lifecycle steps of ``trial``;
-  ``load`` (dataset parse from disk) and ``stage`` (full-dataset
-  host->device transfer) are SUB-SPANS recorded inside
-  ``model.train()``/``model.evaluate()`` — they are contained in the
+  every one but the ``jit_*`` ones timed by ``span`` below (``PHASES``
+  lists them with their nesting). The TrialRunner records the
+  lifecycle steps of ``trial``; ``load`` (dataset parse from disk) and
+  ``stage`` (full-dataset host->device transfer) are SUB-SPANS recorded
+  inside ``model.train()``/``model.evaluate()`` — they are contained in the
   train/eval phases, not additive with them. With the residency caches
   warm, load+stage collapse to ~0 for trial 2..N of a sub-train-job.
   The count of ``step_wait`` is a trial's progress from outside:
   dispatches completed (times ``steps_per_dispatch`` = steps).
+  The same histogram holds the set-up phases, timed by ``span`` where
+  the work happens, once a process or once a job: the TPU runtime's
+  start (``backend_init``), the platform's (``platform_start``), a
+  model's upload, a job's submission and its worker's start. Between
+  them and the warm-up trial's ``trial`` and ``persist`` they split a
+  job's time to its first completed trial, with no overlap. And it
+  holds jax's compile stages as jax itself times them (``watch_jit``):
+  ``jit_trace``, ``jit_lower``, and the backend compile as
+  ``jit_compile`` (a real XLA compile) or ``jit_cache_load`` (a read
+  from the persistent cache), so the two counts are the process's
+  cache misses and hits. Those are nested in whatever span was open.
 - ``rafiki_tpu_trial_dataset_cache_total{event=hit|miss|evict}`` and
   ``rafiki_tpu_trial_stage_cache_total{event=hit|miss|evict}`` — the
   host dataset cache (``model/dataset.py``) and device staging cache
@@ -68,7 +80,9 @@ cumulative sums across a whole window.
 
 from __future__ import annotations
 
+import collections
 import sys
+import threading
 import time
 from typing import Any, Dict, Optional
 
@@ -100,10 +114,59 @@ from . import trace as _trace
 #:                    persist stage (pipeline off: contains persist)
 #:   persist          the tail: log flush, the device leaves' copy to
 #:                    the host, param save, meta commit (persist
-#:                    thread; overlaps the NEXT trial)
+#:                    thread; starts once its trial's span has ended,
+#:                    overlaps the NEXT trial)
+#:
+#: Set-up phases, once a process or once a job, not per trial. In a
+#: job's first seconds they follow one another and never overlap, and
+#: the warm-up trial's ``trial`` and ``persist`` come after them:
+#:
+#:   backend_init     jaxenv.ensure_platform's first resolve: the
+#:                    compile cache placed, jax.devices() (the TPU
+#:                    runtime's start); once a process
+#:   platform_start   the whole of LocalPlatform.__init__
+#:   model_upload     Admin.create_model
+#:   job_submit       Admin.create_train_job up to the services'
+#:                    launch (validation, rows); the launch starts the
+#:                    worker thread, so it is left to worker_start
+#:   worker_start     TrainWorker.run from entry to the TrialRunner
+#:                    built (meta reads, model class, chip binding,
+#:                    advisor); the runner's first ``trial`` follows
+#:
+#: jax's compile stages (``watch_jit``), observed as jax reports them,
+#: wherever they happen (set-up, a trial's ``step_dispatch``, a serving
+#: worker): each stage's sum is the union of its intervals on a thread.
+#:
+#:   jit_trace        Python tracing to a jaxpr
+#:   jit_lower        jaxpr to an MLIR module
+#:   jit_compile      an XLA compile (no persistent-cache hit inside)
+#:   jit_cache_load   a read from the persistent compile cache
 PHASES = ("trial", "propose", "open", "init", "train", "load", "stage",
           "step_setup", "step_dispatch", "step_wait", "eval", "dump",
-          "feedback", "handover", "persist")
+          "feedback", "handover", "persist",
+          "backend_init", "platform_start", "model_upload", "job_submit",
+          "worker_start",
+          "jit_trace", "jit_lower", "jit_compile", "jit_cache_load")
+
+#: The phases of a trial (``PHASES`` up to ``persist``): they count only
+#: in a process that runs trials.
+TRIAL_PHASES = PHASES[:PHASES.index("persist") + 1]
+
+#: jax's compile-stage events (``jax._src.dispatch``) and the phase
+#: each becomes; a backend compile with a persistent-cache hit inside
+#: it on its thread becomes ``jit_cache_load``.
+JIT_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit_lower",
+    "/jax/core/compile/backend_compile_duration": "jit_compile",
+}
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+#: Intervals a thread remembers a stage by, to take an inner event's
+#: time out of the outer one that contains it: the newest top-level
+#: ones. An outer event over more inner ones than this counts the
+#: oldest twice.
+JIT_REMEMBERED = 256
 
 #: The counted caches (``cache_event`` / ``cache_counts``): the host
 #: dataset cache, the device staging cache, the compiled-step cache.
@@ -124,9 +187,11 @@ def _reg() -> Dict[str, object]:
         _m = {
             "phase": r.histogram(
                 "rafiki_tpu_trial_phase_seconds",
-                "Wall time of one trial-lifecycle phase (phase=" +
-                "|".join(PHASES) + "; nesting: observe/phases.py "
-                "PHASES)", buckets=PHASE_BUCKETS),
+                "Wall time of one trial-lifecycle phase, of one set-up "
+                "phase (once a process or job) or of one of jax's "
+                "compile stages (jit_*) (phase=" + "|".join(PHASES) +
+                "; nesting: observe/phases.py PHASES)",
+                buckets=PHASE_BUCKETS),
             "dataset_cache": r.counter(
                 "rafiki_tpu_trial_dataset_cache_total",
                 "Host dataset cache events (event=hit|miss|evict)"),
@@ -230,6 +295,64 @@ class span:
                                 [self._ctx], self._wall, seconds,
                                 attrs=self.attrs or None)
         return False
+
+
+_jit_lock = threading.Lock()
+_jit_watched = False
+#: Per thread: ``hit_at``, the instant of its last persistent-cache hit;
+#: ``counted``, {event: intervals already observed} (``JIT_REMEMBERED``).
+_jit_local = threading.local()
+
+
+def watch_jit() -> None:
+    """Turn jax's own compile-stage events into the ``jit_*`` phases:
+    one ``jax.monitoring`` time-span listener and one event listener,
+    registered once a process (``jaxenv.ensure_platform`` calls this on
+    both its branches). Inert under ``RAFIKI_TPU_METRICS=0``. jax emits
+    these events only when it traces, lowers or compiles, never on a
+    dispatch of a compiled program."""
+    global _jit_watched
+    if not metrics.metrics_enabled():
+        return
+    with _jit_lock:
+        if _jit_watched:
+            return
+        import jax.monitoring
+
+        jax.monitoring.register_event_listener(_on_jit_event)
+        jax.monitoring.register_event_time_span_listener(_on_jit_span)
+        _jit_watched = True
+
+
+def _on_jit_event(event: str, **_: Any) -> None:
+    if event == CACHE_HIT_EVENT:
+        _jit_local.hit_at = time.time()  # jax's spans are on this clock
+
+
+def _on_jit_span(event: str, start: float, end: float, **_: Any) -> None:
+    """One stage's event, reported as it ends, on the thread that did
+    the work. jax reports an inner jit's trace before its caller's,
+    which contains it: the intervals of this stage already observed on
+    this thread that start inside this one lie inside it (events nest
+    on a thread), so their time comes off this one's and the stage's
+    sum is the union of its intervals."""
+    phase = JIT_EVENTS.get(event)
+    if phase is None:
+        return
+    if phase == "jit_compile" and \
+            start <= getattr(_jit_local, "hit_at", start - 1.0) <= end:
+        phase = "jit_cache_load"
+    counted = getattr(_jit_local, "counted", None)
+    if counted is None:
+        counted = _jit_local.counted = {}
+    seen = counted.setdefault(
+        event, collections.deque(maxlen=JIT_REMEMBERED))
+    inner = 0.0
+    while seen and seen[-1][0] >= start:
+        s, e = seen.pop()
+        inner += e - s
+    seen.append((start, end))
+    observe_phase(phase, max(0.0, end - start - inner))
 
 
 def cache_event(cache: str, event: str, n: int = 1) -> None:

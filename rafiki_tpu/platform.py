@@ -21,6 +21,7 @@ from .admin import Admin, ServicesManager
 from .admin.app import AdminApp
 from .bus import BusServer, MemoryBus, connect
 from .container import SystemContext, ThreadContainerManager
+from .observe import phases as observe_phases
 from .observe import trace as observe_trace
 from .observe import workload as observe_workload
 from .parallel.chips import ChipAllocator
@@ -43,6 +44,18 @@ class LocalPlatform:
                  supervise_interval: float = 10.0,
                  stop_jobs_on_shutdown: bool = True,
                  node_id: str = "", adopt_unowned: bool = True):
+        with observe_phases.span("platform_start"):
+            self._start(workdir, n_chips, http, admin_port, bus_uri,
+                        supervise_interval, stop_jobs_on_shutdown,
+                        node_id, adopt_unowned)
+
+    def _start(self, workdir: Optional[str], n_chips: Optional[int],
+               http: bool, admin_port: int, bus_uri: str,
+               supervise_interval: float, stop_jobs_on_shutdown: bool,
+               node_id: str, adopt_unowned: bool) -> None:
+        """``__init__``'s body, timed as the set-up phase
+        ``platform_start``: stores, bus, services manager, admin, HTTP
+        app, supervisor threads."""
         # A secondary (join) node sharing another node's meta store must
         # not stop the cluster's jobs when it leaves.
         self.stop_jobs_on_shutdown = stop_jobs_on_shutdown

@@ -67,6 +67,7 @@ class _PersistStage:
         self._pool = ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="trial-persist")
         self._last: Optional[Future] = None
+        self._held: Optional[Callable[[Callable], None]] = None
         self._lock = threading.Lock()
         self._pending = 0
         self._failures = 0
@@ -110,13 +111,23 @@ class _PersistStage:
             return self._commits
 
     def submit(self, fn: Callable[[Callable], None]) -> None:
-        """``fn(commit)`` runs on the persist thread; it must call
-        ``commit(meta_write)`` at most once — the meta write and the
-        pending-count decrement happen atomically."""
+        """``fn(commit)`` runs on the persist thread once ``release``
+        is called; it must call ``commit(meta_write)`` at most once —
+        the meta write and the pending-count decrement happen
+        atomically. Waits for the previous tail first (single slot)."""
         if self._last is not None:
             self._last.result()  # single slot; tails don't raise
         with self._lock:
             self._pending += 1
+        self._held = fn
+
+    def release(self) -> None:
+        """Start the submitted tail: the trial thread calls this once
+        the trial's own span has ended, so that span and the tail's
+        ``persist`` never overlap. No-op with nothing submitted."""
+        fn, self._held = self._held, None
+        if fn is None:
+            return
 
         def run() -> None:
             committed = [False]
@@ -146,6 +157,7 @@ class _PersistStage:
     def drain(self) -> None:
         """Block until the in-flight tail (if any) has finished — after
         this, no trial row of a submitted tail is left RUNNING."""
+        self.release()
         if self._last is not None:
             self._last.result()
 
@@ -345,13 +357,18 @@ class TrialRunner:
         ctx = _trace.current() or _trace.TraceContext(trial_id)
         span = functools.partial(_phases.span, ctx=ctx,
                                  service=self.worker_id)
-        with metrics.label_context(trial=trial_id[:12]), span("trial"):
-            if proposal is None:
-                with span("propose"):
-                    proposal = self.advisor.propose()
-            if proposal is None:  # advisor side says: search is over
-                return None
-            return self._run_trial(trial_id, proposal, span)
+        try:
+            with metrics.label_context(trial=trial_id[:12]), \
+                    span("trial"):
+                if proposal is None:
+                    with span("propose"):
+                        proposal = self.advisor.propose()
+                if proposal is None:  # advisor side says: search is over
+                    return None
+                return self._run_trial(trial_id, proposal, span)
+        finally:
+            if self._persist is not None:
+                self._persist.release()  # the tail starts after ``trial``
 
     def _run_trial(self, trial_id: str, proposal: Proposal,
                    span: Callable[..., Any]) -> Dict[str, Any]:

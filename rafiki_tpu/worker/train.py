@@ -23,6 +23,7 @@ from ..advisor.worker import RemoteAdvisor
 from ..bus import BaseBus, connect
 from ..config import _parse_bool
 from ..constants import EnvVars, ServiceStatus, TrialStatus
+from ..observe import phases
 from ..parallel.chips import ChipGroup
 from ..store import MetaStore, ParamStore
 from ..utils.model_loader import load_model_class
@@ -94,35 +95,39 @@ class TrainWorker:
         from ..utils.service_logs import bind_service_log
 
         bind_service_log(getattr(self, "log_path", None))
-        sub = self.meta.get_sub_train_job(self.sub_id)
-        if sub is None:
-            raise ValueError(f"unknown sub_train_job {self.sub_id}")
-        job = self.meta.get_train_job(sub["train_job_id"])
-        model_row = self.meta.get_model(sub["model_id"])
-        model_class = load_model_class(model_row["model_class"],
-                                       model_row.get("model_source"))
-        # Pin this service's chip group for every Mesh built by models on
-        # this thread (thread-local, so resident-runner workers sharing a
-        # process never race on the env var).
-        if self.chips is not None:
-            self.chips.bind_to_thread()
-        self.meta.update_service(self.service_id,
-                                 status=ServiceStatus.RUNNING)
-        # Pipeline the advisor by default (opt-out via
-        # RAFIKI_TPU_ADVISOR_PREFETCH=0): the next proposal computes
-        # while the current trial trains. close() runs on EVERY exit
-        # path — stop flag, budget exhaustion, crash — so the dangling
-        # prefetched proposal is always forget-ed back to the strategy.
-        advisor = self.advisor
-        prefetch: Optional[PrefetchAdvisor] = None
-        if _parse_bool(os.environ.get(ADVISOR_PREFETCH_ENV, "1")):
-            advisor = prefetch = PrefetchAdvisor(advisor)
-        runner = TrialRunner(
-            model_class, advisor, job["train_dataset_path"],
-            job["val_dataset_path"], self.meta, self.params, self.sub_id,
-            model_id=sub["model_id"], worker_id=self.service_id,
-            budget=job["budget"], stop_flag=self.stop_flag,
-            pipeline_persist=True)
+        # The set-up phase ``worker_start``: up to the runner's loop,
+        # whose first ``trial`` span follows.
+        with phases.span("worker_start"):
+            sub = self.meta.get_sub_train_job(self.sub_id)
+            if sub is None:
+                raise ValueError(f"unknown sub_train_job {self.sub_id}")
+            job = self.meta.get_train_job(sub["train_job_id"])
+            model_row = self.meta.get_model(sub["model_id"])
+            model_class = load_model_class(model_row["model_class"],
+                                           model_row.get("model_source"))
+            # Pin this service's chip group for every Mesh built by
+            # models on this thread (thread-local, so resident-runner
+            # workers sharing a process never race on the env var).
+            if self.chips is not None:
+                self.chips.bind_to_thread()
+            self.meta.update_service(self.service_id,
+                                     status=ServiceStatus.RUNNING)
+            # Pipeline the advisor by default (opt-out via
+            # RAFIKI_TPU_ADVISOR_PREFETCH=0): the next proposal computes
+            # while the current trial trains. close() runs on EVERY exit
+            # path — stop flag, budget exhaustion, crash — so the
+            # dangling prefetched proposal is always forget-ed back to
+            # the strategy.
+            advisor = self.advisor
+            prefetch: Optional[PrefetchAdvisor] = None
+            if _parse_bool(os.environ.get(ADVISOR_PREFETCH_ENV, "1")):
+                advisor = prefetch = PrefetchAdvisor(advisor)
+            runner = TrialRunner(
+                model_class, advisor, job["train_dataset_path"],
+                job["val_dataset_path"], self.meta, self.params,
+                self.sub_id, model_id=sub["model_id"],
+                worker_id=self.service_id, budget=job["budget"],
+                stop_flag=self.stop_flag, pipeline_persist=True)
         try:
             runner.run()
             # The job is truly over (budget spent, not a mid-job stop

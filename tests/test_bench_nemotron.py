@@ -379,7 +379,10 @@ def test_benchmark_json_config_and_workload_hold_what_the_issue_asks(cell):
     mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
     assert [m["name"] for m in mine] == list(READERS) + [
         "nemo." + name for name in LIFECYCLE] + list(KERNEL_READERS)
-    assert bench["per_layer"][-len(mine):] == mine
+    first = bench["per_layer"].index(mine[0])
+    assert bench["per_layer"][first:first + len(mine)] == mine
+    assert all(CELL not in m["workloads"]
+               for m in bench["per_layer"][:first])
     assert all(m["moves"] == "trials_per_hour" for m in mine)
     for m in mine:
         assert os.path.exists(os.path.join(

@@ -615,7 +615,8 @@ def test_admin_trace_route_and_metrics(tmp_path):
                               timeout=10).json()
         assert "mfu" in status
         # /trial_phases feeds the dashboard's phase-breakdown panel:
-        # every phase present (zero-count here — no resident trials),
+        # every phase present (zero-count trial phases here — no
+        # resident trials),
         # in PHASES' order (parents before their children, which is the
         # order the panel's rows come out in), and authenticated like
         # every other admin read.
@@ -627,7 +628,11 @@ def test_admin_trace_route_and_metrics(tmp_path):
         assert set(tp["phases"]) == {
             "trial", "propose", "open", "init", "train", "load",
             "stage", "step_setup", "step_dispatch", "step_wait", "eval",
-            "dump", "feedback", "handover", "persist"}
+            "dump", "feedback", "handover", "persist",
+            # set-up, once a process or job, and jax's compile stages
+            "backend_init", "platform_start", "model_upload",
+            "job_submit", "worker_start",
+            "jit_trace", "jit_lower", "jit_compile", "jit_cache_load"}
         assert set(tp["caches"]) == {"dataset", "stage", "step"}
         assert set(tp["dump_leaves"]) == {"device", "host"}
         assert "resident" in tp and "enabled" in tp

@@ -168,11 +168,11 @@ def test_annotations_on_the_host_plane_nest_and_name_their_trial(
             for child in of(name):
                 assert train[1] <= child[1] <= child[2] <= train[2], name
         # the tail runs on the persist thread and names the trial that
-        # caused it; it starts inside that trial's hand-over
+        # caused it; it starts once that trial's span has ended
         (persist,) = of("persist")
         (handover,) = of("handover")
         assert persist[4] != trial[4]
-        assert handover[1] <= persist[1]
+        assert handover[1] <= trial[2] <= persist[1]
 
 
 def test_phase_totals_hold_every_phase_and_count_dispatches(traced_run):
